@@ -239,17 +239,7 @@ def init(ranks: Optional[Sequence[int]] = None, devices: Optional[Sequence] = No
             return
 
         import jax
-
-        # Launcher-driven platform selection (horovod_tpu.run --cpu): the
-        # env var JAX_PLATFORMS alone can be preempted by pre-registered
-        # plugins, so apply it through jax.config while the backend is
-        # still uninitialized.
-        plat = os.environ.get("HVD_PLATFORM")
-        if plat:
-            try:
-                jax.config.update("jax_platforms", plat)
-            except Exception:
-                pass  # backend already up; leave the platform as-is
+        from jax._src import distributed as _jax_dist
 
         # Multi-host: if the user (or launcher) provided coordination env,
         # bring up the JAX distributed client so jax.devices() is global.
@@ -258,8 +248,6 @@ def init(ranks: Optional[Sequence[int]] = None, devices: Optional[Sequence] = No
         # distributed.initialize refuses to run), hence the client check.
         coord = os.environ.get("HVD_COORDINATOR_ADDRESS")
         if coord and os.environ.get("HVD_NUM_PROCESSES"):
-            from jax._src import distributed as _jax_dist
-
             if _jax_dist.global_state.client is None:
                 from horovod_tpu.core import elastic as _elastic
 
@@ -280,27 +268,15 @@ def init(ranks: Optional[Sequence[int]] = None, devices: Optional[Sequence] = No
                                                       "0")),
                     )
 
-        # Multi-controller on the CPU platform: current jaxlib executes
+        # Multi-controller on the CPU platform: jaxlib executes
         # cross-process CPU collectives only through a CPU collectives
         # backend — without one, the first collective dies with
         # "Multiprocess computations aren't implemented on the CPU
-        # backend". Select gloo while the backend is still uninitialized
-        # (works before or after jax.distributed.initialize; an env var
-        # alone is preempted the same way JAX_PLATFORMS is). No-op for
-        # single-process and for real TPU platforms.
-        try:
-            from jax._src import distributed as _jax_dist
-
-            multiproc = _jax_dist.global_state.client is not None
-        except Exception:
-            multiproc = False
-        if multiproc and (plat == "cpu"
-                          or jax.config.jax_platforms == "cpu"):
-            try:
-                jax.config.update(
-                    "jax_cpu_collectives_implementation", "gloo")
-            except Exception:
-                pass  # older jaxlib without the knob / backend already up
+        # backend". Select gloo while the backend is still uninitialized.
+        # No-op for single-process and for real TPU platforms.
+        if (_jax_dist.global_state.client is not None
+                and jax.config.jax_platforms == "cpu"):
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
         if devices is None:
             devices = list(jax.devices())

@@ -471,13 +471,7 @@ class JaxExecutor:
         if arr.dtype.itemsize == 8 and arr.dtype.kind in "fiuc":
             import jax
 
-            if hasattr(jax, "enable_x64"):
-                return jax.enable_x64()
-            # jax versions without the top-level alias keep the
-            # experimental spelling.
-            from jax.experimental import enable_x64
-
-            return enable_x64()
+            return jax.enable_x64()
         return contextlib.nullcontext()
 
     def _stage(self, arr: np.ndarray):

@@ -5,19 +5,28 @@ Mirrors the reference's loader role (reference: horovod/common/__init__.py:
 library is a single translation unit built on demand with g++ — no MPI, no
 framework headers — so it compiles anywhere in seconds and is cached next
 to the source.
+
+A built library is reused only when it was built from the source and the
+flags at hand: the hash of both is part of its file name
+(``libhvdcore.<key>.so``), and a file is published under that name only
+by an atomic rename after a successful build. File times play no part — a
+tree copied to another machine (ignored files included, mtimes not
+necessarily kept) can never load a library built from an older
+``hvdcore.cc`` against today's ctypes mirrors.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import re
 import subprocess
 import threading
 from typing import Optional
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "hvdcore.cc")
-_LIB = os.path.join(_DIR, "libhvdcore.so")
 
 _lock = threading.Lock()
 _lib = None
@@ -28,17 +37,17 @@ _lib = None
 _BASE_FLAGS = ["-std=c++17", "-fPIC", "-shared", "-pthread",
                "-Wall", "-Wextra", "-Werror"]
 
-# HVD_SANITIZE={thread,address} rebuild modes. Each mode publishes its
-# own artifact next to the source (the default lib is never clobbered
-# by a sanitized build, so flipping the env var back costs nothing).
-# -O1 -fno-omit-frame-pointer is the sanitizer-recommended pairing:
-# usable stacks, tolerable slowdown.
+# HVD_SANITIZE={thread,address} rebuild modes: (sanitizer flags, artifact
+# stem, optimization flags). Each mode publishes its own artifact next to
+# the source (the default lib is never clobbered by a sanitized build, so
+# flipping the env var back costs nothing). -O1 -fno-omit-frame-pointer
+# is the sanitizer-recommended pairing: usable stacks, tolerable slowdown.
 _SANITIZE_MODES = {
-    "": ([], _LIB, ["-O2", "-g"]),
+    "": ([], "libhvdcore", ["-O2", "-g"]),
     "thread": (["-fsanitize=thread", "-fno-omit-frame-pointer"],
-               os.path.join(_DIR, "libhvdcore.tsan.so"), ["-O1", "-g"]),
+               "libhvdcore.tsan", ["-O1", "-g"]),
     "address": (["-fsanitize=address", "-fno-omit-frame-pointer"],
-                os.path.join(_DIR, "libhvdcore.asan.so"), ["-O1", "-g"]),
+                "libhvdcore.asan", ["-O1", "-g"]),
 }
 
 # TSan suppressions for the Python-hosted run (tests + LD_PRELOAD
@@ -79,31 +88,71 @@ def sanitizer_runtime(mode: str = "thread") -> str:
     return os.path.realpath(path)
 
 
-def build_library(force: bool = False, mode: Optional[str] = None) -> str:
-    """Compile the engine library if missing or stale; returns the path.
-    ``mode`` overrides HVD_SANITIZE ('' = the plain production build)."""
-    mode = sanitize_mode() if mode is None else mode
-    san_flags, out, opt_flags = _SANITIZE_MODES[mode]
-    with _lock:
-        if (not force and os.path.exists(out)
-                and os.path.getmtime(out) >= os.path.getmtime(_SRC)):
-            return out
-        # pid-suffixed temp: concurrent processes (multi-controller first
-        # run on a shared filesystem) must not compile into the same file;
-        # os.replace makes the final publish atomic whoever wins.
-        tmp = f"{out}.tmp.{os.getpid()}.so"
-        cmd = (["g++"] + opt_flags + _BASE_FLAGS + san_flags
-               + [_SRC, "-o", tmp])
-        proc = subprocess.run(cmd, capture_output=True, text=True)
-        if proc.returncode != 0:
-            raise NativeBuildError(
-                f"failed to build libhvdcore: {proc.stderr[-2000:]}")
-        os.replace(tmp, out)
+def _keyed_path(src: str, stem: str, flags) -> str:
+    """``<dir of src>/<stem>.<key>.so`` where key hashes the source bytes
+    and the compiler flags."""
+    h = hashlib.sha256()
+    with open(src, "rb") as fh:
+        h.update(fh.read())
+    h.update("\0".join(flags).encode())
+    return os.path.join(os.path.dirname(src),
+                        f"{stem}.{h.hexdigest()[:16]}.so")
+
+
+def _build_keyed(src: str, stem: str, flags, force: bool = False,
+                 libs=()) -> str:
+    """Return the library for (src, flags, libs), compiling it unless the
+    keyed file is already there. ``libs`` follow the source on the
+    command line (link order). Caller holds ``_lock``."""
+    out = _keyed_path(src, stem, list(flags) + list(libs))
+    if not force and os.path.exists(out):
         return out
+    # pid-suffixed temp: concurrent processes (multi-controller first
+    # run on a shared filesystem) must not compile into the same file;
+    # os.replace makes the final publish atomic whoever wins.
+    tmp = f"{out}.tmp.{os.getpid()}"
+    proc = subprocess.run(
+        ["g++"] + list(flags) + [src, "-o", tmp] + list(libs),
+        capture_output=True, text=True)
+    if proc.returncode != 0:
+        raise NativeBuildError(
+            f"failed to build {stem}: {proc.stderr[-2000:]}")
+    os.replace(tmp, out)
+    # Libraries of other sources/flags under this stem (and the unkeyed
+    # name older trees used) can never be loaded again: drop them so a
+    # long-lived checkout does not grow one .so per edit.
+    stale = re.compile(re.escape(stem) + r"(\.[0-9a-f]{16})?\.so$")
+    for name in os.listdir(os.path.dirname(out)):
+        if stale.match(name) and name != os.path.basename(out):
+            try:
+                os.unlink(os.path.join(os.path.dirname(out), name))
+            except OSError:
+                pass
+    return out
+
+
+def _engine_build(mode: str):
+    san_flags, stem, opt_flags = _SANITIZE_MODES[mode]
+    return stem, opt_flags + _BASE_FLAGS + san_flags
+
+
+def library_path(mode: Optional[str] = None) -> str:
+    """Where the engine library for the source on disk lives (whether or
+    not it has been built yet)."""
+    stem, flags = _engine_build(sanitize_mode() if mode is None else mode)
+    return _keyed_path(_SRC, stem, flags)
+
+
+def build_library(force: bool = False, mode: Optional[str] = None) -> str:
+    """Compile the engine library unless one built from this source and
+    these flags exists; returns the path. ``mode`` overrides HVD_SANITIZE
+    ('' = the plain production build)."""
+    stem, flags = _engine_build(sanitize_mode() if mode is None else mode)
+    with _lock:
+        return _build_keyed(_SRC, stem, flags, force)
 
 
 _SHIELD_SRC = os.path.join(_DIR, "termshield.cc")
-_SHIELD_LIB = os.path.join(_DIR, "libtermshield.so")
 _shield_lib = None
 
 
@@ -115,18 +164,9 @@ def load_termshield():
     with _lock:
         if _shield_lib is not None:
             return _shield_lib
-        if not (os.path.exists(_SHIELD_LIB)
-                and os.path.getmtime(_SHIELD_LIB)
-                >= os.path.getmtime(_SHIELD_SRC)):
-            tmp = f"{_SHIELD_LIB}.tmp.{os.getpid()}.so"
-            cmd = (["g++", "-O2"] + _BASE_FLAGS
-                   + [_SHIELD_SRC, "-o", tmp, "-ldl"])
-            proc = subprocess.run(cmd, capture_output=True, text=True)
-            if proc.returncode != 0:
-                raise NativeBuildError(
-                    f"failed to build termshield: {proc.stderr[-2000:]}")
-            os.replace(tmp, _SHIELD_LIB)
-        lib = ctypes.CDLL(_SHIELD_LIB)
+        path = _build_keyed(_SHIELD_SRC, "libtermshield",
+                            ["-O2"] + _BASE_FLAGS, libs=["-ldl"])
+        lib = ctypes.CDLL(path)
         lib.hvd_termshield_install.argtypes = []
         lib.hvd_termshield_install()
         _shield_lib = lib

@@ -19,6 +19,7 @@ import jax as _jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+from jax import shard_map as _shard_map
 
 from horovod_tpu.common.topology import (  # noqa: F401
     init,
@@ -64,8 +65,6 @@ from horovod_tpu.jax.sharded import (  # noqa: F401
     sharded_state_specs,
     unwrap_error_feedback,
 )
-
-from horovod_tpu.common.compat import shard_map as _shard_map
 from horovod_tpu.jax import mpi_ops  # noqa: F401  — engine-path async
 # verbs (allreduce_async/synchronize/... with zero-copy donate=True)
 from horovod_tpu.core import numerics as _num

@@ -175,6 +175,8 @@ chunked_softmax_cross_entropy.defvjp(_fwd, _bwd)
 import jax.experimental.pallas as pl  # noqa: E402
 from jax.experimental.pallas import tpu as pltpu  # noqa: E402
 
+from horovod_tpu.ops.pallas_mode import resolve_interpret  # noqa: E402
+
 _STAT = 128  # lane width for (block_n, 128) row-stat scratch tiles
 
 
@@ -395,12 +397,6 @@ def _ce_bwd_call(h2d, kernel, bias, lab, lse, g, block_n, block_v,
     return dx[:n0], dw[:, :v], db[0, :v]
 
 
-def _resolve_interpret(interpret):
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def fused_softmax_cross_entropy(hidden, kernel, bias, labels,
                                 block_n: int = 512, block_v: int = 1024,
@@ -408,7 +404,7 @@ def fused_softmax_cross_entropy(hidden, kernel, bias, labels,
     """Pallas-kernel LM-head loss: same contract as
     :func:`chunked_softmax_cross_entropy`, but the per-tile logits never
     leave VMEM in either direction. Off-TPU the kernels run in pallas
-    interpret mode (tests/CPU)."""
+    interpret mode (tests/CPU; logged once, ops/pallas_mode.py)."""
     losses, _ = _fused_fwd_rule(hidden, kernel, bias, labels, block_n,
                                 block_v, interpret)
     return losses
@@ -419,8 +415,9 @@ def _fused_fwd_rule(hidden, kernel, bias, labels, block_n, block_v,
     lead = hidden.shape[:-1]
     h2d = hidden.reshape(-1, hidden.shape[-1])
     lab = labels.reshape(-1)
-    losses, lse = _ce_fwd_call(h2d, kernel, bias, lab, block_n, block_v,
-                               _resolve_interpret(interpret))
+    losses, lse = _ce_fwd_call(
+        h2d, kernel, bias, lab, block_n, block_v,
+        resolve_interpret(interpret, "fused_softmax_cross_entropy"))
     return losses.reshape(lead), (hidden, kernel, bias, labels, lse)
 
 
@@ -429,7 +426,8 @@ def _fused_bwd_rule(block_n, block_v, interpret, residuals, g):
     h2d = hidden.reshape(-1, hidden.shape[-1])
     dx, dw, db = _ce_bwd_call(
         h2d, kernel, bias, labels.reshape(-1), lse, g.reshape(-1),
-        block_n, block_v, _resolve_interpret(interpret))
+        block_n, block_v,
+        resolve_interpret(interpret, "fused_softmax_cross_entropy"))
     return (dx.astype(hidden.dtype).reshape(hidden.shape),
             dw.astype(kernel.dtype), db.astype(bias.dtype), None)
 

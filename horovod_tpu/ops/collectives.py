@@ -33,13 +33,11 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from horovod_tpu.common import topology as _topo
 from horovod_tpu.common.topology import HVD_AXIS
-
-from horovod_tpu.common.compat import shard_map as _shard_map
 from horovod_tpu.core import numerics as _num
 from horovod_tpu.core import telemetry as _tele
 

@@ -29,7 +29,13 @@ kernels the per-head full-sequence attention, and ring attention's
 per-block math is the same online-softmax update this kernel runs locally.
 
 Off-TPU (tests, CPU debugging) the kernels run in pallas interpret mode —
-same code path, scalar semantics.
+same code path, scalar semantics; the fallback is logged once
+(:mod:`horovod_tpu.ops.pallas_mode`).
+
+Matmul precision follows the input dtype: bf16 operands take the MXU's
+native single pass, f32 inputs ask Mosaic for full f32 contraction — on
+the chip the default would round f32 operands to bf16 and miss the f32
+reference by ~1e-2.
 
 (Reference parity note: kuroko1t/horovod contains no attention ops — this
 is TPU-native scope beyond the reference, serving its examples' model
@@ -45,8 +51,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from horovod_tpu.ops.pallas_mode import resolve_interpret
+
 NEG_INF = -1e30
 _STAT = 128  # lane width for the (block_q, 128) row-stat scratch tiles
+
+
+def _precision(dtype):
+    """In-kernel matmul precision for inputs of ``dtype`` (module doc)."""
+    return jax.lax.Precision.HIGHEST if dtype == jnp.float32 else None
+
+
+def _mm(a, b, precision):
+    """(m, k) @ (k, n) on the MXU, f32 accumulation."""
+    return jnp.dot(a, b, precision=precision,
+                   preferred_element_type=jnp.float32)
 
 
 def _mask_block(sblk, qi, ki, block_q, block_k):
@@ -69,6 +88,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 block_q: int, block_k: int):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    precision = _precision(q_ref.dtype)
 
     @pl.when(ki == 0)
     def _init():
@@ -83,7 +103,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         q = q_ref[0].astype(jnp.float32) * scale
         kb = k_ref[0].astype(jnp.float32)
         vb = v_ref[0].astype(jnp.float32)
-        sblk = q @ kb.T  # (bq, bk) on the MXU
+        sblk = _mm(q, kb.T, precision)  # (bq, bk) on the MXU
         if causal:
             sblk = _mask_block(sblk, qi, ki, block_q, block_k)
         m_prev = m_ref[:, :1]
@@ -92,7 +112,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         p = jnp.exp(sblk - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + p @ vb
+        acc_ref[...] = acc_ref[...] * alpha + _mm(p, vb, precision)
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
@@ -170,6 +190,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, dq_ref,
                block_q: int, block_k: int):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
+    precision = _precision(q_ref.dtype)
 
     @pl.when(ki == 0)
     def _init():
@@ -183,13 +204,13 @@ def _dq_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, dq_ref,
         kb = k_ref[0].astype(jnp.float32)
         vb = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        sblk = q @ kb.T
+        sblk = _mm(q, kb.T, precision)
         if causal:
             sblk = _mask_block(sblk, qi, ki, block_q, block_k)
         p = jnp.exp(sblk - lse_ref[0])  # lse block is (bq, 1)
-        dp = do @ vb.T
+        dp = _mm(do, vb.T, precision)
         ds = p * (dp - delta_ref[0])
-        acc_ref[...] += ds @ kb * scale
+        acc_ref[...] += _mm(ds, kb, precision) * scale
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -201,6 +222,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
                 scale: float, nq: int, block_q: int, block_k: int):
     ki = pl.program_id(1)
     qi = pl.program_id(2)
+    precision = _precision(q_ref.dtype)
 
     @pl.when(qi == 0)
     def _init():
@@ -215,14 +237,14 @@ def _dkv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
         kb = k_ref[0].astype(jnp.float32)
         vb = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        sblk = q @ kb.T
+        sblk = _mm(q, kb.T, precision)
         if causal:
             sblk = _mask_block(sblk, qi, ki, block_q, block_k)
         p = jnp.exp(sblk - lse_ref[0])  # lse block is (bq, 1)
-        dv_acc[...] += p.T @ do
-        dp = do @ vb.T
+        dv_acc[...] += _mm(p.T, do, precision)
+        dp = _mm(do, vb.T, precision)
         ds = p * (dp - delta_ref[0])
-        dk_acc[...] += ds.T @ q  # q already carries `scale`
+        dk_acc[...] += _mm(ds.T, q, precision)  # q already carries `scale`
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -309,16 +331,24 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 # ---------------------------------------------------------------------------
 
 def _auto_block(s: int, cap: int = 512) -> int:
-    """Largest block <= cap that divides s, preferring multiples of the
-    128-wide MXU tile (512 measured fastest on v5e; see docs/benchmarks.md)."""
-    for cand in range(min(cap, s) - min(cap, s) % 128, 0, -128):
+    """Largest block <= cap that divides s and that Mosaic accepts as the
+    second-to-last block dim: a multiple of the 128-wide MXU tile when
+    there is one (512 measured fastest on v5e; see docs/benchmarks.md),
+    else the whole sequence, else a multiple of the 8-row sublane tile.
+    A sequence with none of these has no legal tiling: raise."""
+    top = min(cap, s)
+    for cand in range(top - top % 128, 0, -128):
         if s % cand == 0:
             return cand
-    best = 1
-    for cand in range(2, min(cap, s) + 1):
+    if s <= cap:
+        return s
+    for cand in range(top - top % 8, 0, -8):
         if s % cand == 0:
-            best = cand
-    return best
+            return cand
+    raise ValueError(
+        f"flash_attention: seq len {s} has no divisor <= {cap} that is a "
+        "multiple of 8, so it cannot be tiled for the TPU; pad the "
+        "sequence to a multiple of 8 (128 for full MXU tiles)")
 
 
 def flash_attention(q, k, v, bias=None, causal: bool = False,
@@ -327,9 +357,8 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
     """Exact attention, flash-style, differentiable. Shapes
     (batch, seq, heads, head_dim) — the model zoo's ``attention_fn``
     contract. ``bias`` is not supported by the kernel (use the stock
-    attention for biased variants). Block sizes default to the largest
-    divisor of ``seq`` <= 512 that is a multiple of 128; explicit block
-    sizes must divide ``seq``."""
+    attention for biased variants). Block sizes default to
+    :func:`_auto_block`; explicit block sizes must divide ``seq``."""
     if bias is not None:
         raise NotImplementedError(
             "flash_attention does not take a bias; use "
@@ -341,8 +370,12 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
         raise ValueError(
             f"seq len {s} must be divisible by block sizes "
             f"({block_q}, {block_k})")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret, "flash_attention")
+    if not interpret and any(blk % 8 and blk != s
+                             for blk in (block_q, block_k)):
+        raise ValueError(
+            f"block sizes ({block_q}, {block_k}) must be multiples of 8 "
+            f"or the whole sequence ({s}) to compile for the TPU")
 
     def to_bhsd(t):
         return jnp.transpose(t, (0, 2, 1, 3)).reshape(b * h, s, d)

@@ -27,7 +27,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import optax
-from jax import lax
+from jax import lax, shard_map as _shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from horovod_tpu.parallel.mesh import hybrid_mesh
@@ -39,8 +39,6 @@ from horovod_tpu.parallel.tensor_parallel import (
     ParallelMLP,
     RowParallelDense,
 )
-
-from horovod_tpu.common.compat import shard_map as _shard_map
 
 
 @dataclasses.dataclass(frozen=True)

@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import argparse
 import json
+import math
 import os
 import signal
 import socket
@@ -74,9 +75,77 @@ def _exit_status(code: int) -> int:
 
 
 # Keep in sync with horovod_tpu.core.elastic.RESTART_EXIT_CODE (pinned by
-# tests/test_world_elastic.py); importing the module here would drag jax
-# into the launcher process.
+# tests/test_world_elastic.py). The launcher imports nothing that could
+# initialise a jax backend: jax itself is already imported (the package
+# __init__ pulls it in), which is harmless, but a parent that holds the
+# chip starves every child it spawns.
 RESTART_EXIT_CODE = 77
+
+
+# Chip grids of the multi-chip TPU hosts this launcher can divide, by
+# chip count, for hosts that do not say it themselves in
+# TPU_CHIPS_PER_HOST_BOUNDS (v5e/v4 hosts carry 2x2 or 2x4 chips).
+_TPU_HOST_SHAPES = {4: (2, 2, 1), 8: (2, 4, 1)}
+
+# libtpu's whole-host spellings of what the per-process variables below
+# say per child; a child that inherited both would be told two things.
+_TPU_HOST_WIDE_VARS = ("TPU_CHIPS_PER_HOST_BOUNDS", "TPU_HOST_BOUNDS",
+                       "TPU_WORKER_HOSTNAMES", "TPU_WORKER_ID")
+
+
+def _host_tpu_chips() -> int:
+    """TPU chips this host exposes, counted from their device nodes (vfio
+    groups on v5e and later, accel nodes before) — never through jax:
+    asking jax would initialise the backend, and a launcher that holds
+    the chips starves its children."""
+    import glob
+
+    return (len(glob.glob("/dev/vfio/[0-9]*"))
+            or len(glob.glob("/dev/accel[0-9]*")))
+
+
+def _tpu_child_envs(num_proc: int, chips: int, environ) -> list:
+    """One environment update per child that gives each controller on
+    this host ONE of the host's ``chips`` TPU chips, through libtpu's
+    per-process visibility variables (ranks are chips: the ``mpirun -np
+    N`` shape). Without them every child initialises libtpu for all the
+    chips and all but the first die on its lockfile. Empty updates when
+    there is nothing to divide (no chips, or one process that takes the
+    whole host); ``SystemExit`` naming the cause otherwise.
+
+    Blocks of several chips per process are refused, not guessed: libtpu
+    accepts a block only if its chips are neighbours in the chip grid
+    ("Chip 0x0x0 not on Host 0x1x0" otherwise), and the device-node
+    numbers the launcher can see neither follow the grid nor stay the
+    same across machines (two 2x2 v5e hosts: nodes 0-3 at (1,0), (1,1),
+    (0,1), (0,0) on one, at (1,1), (0,1), (0,0), (1,0) on the other)."""
+    if chips == 0 or num_proc == 1:
+        return [{} for _ in range(num_proc)]
+    grid = None
+    if environ.get("TPU_CHIPS_PER_HOST_BOUNDS"):
+        grid = tuple(int(v) for v in
+                     environ["TPU_CHIPS_PER_HOST_BOUNDS"].split(","))
+    if grid is None or math.prod(grid) != chips:
+        grid = _TPU_HOST_SHAPES.get(chips)
+    if num_proc != chips or grid is None:
+        raise SystemExit(
+            f"horovod_tpu.run: -np {num_proc} cannot be placed on this "
+            f"host's {chips} TPU chip(s). The launcher gives each process "
+            f"exactly one chip (-np {chips}"
+            + ("" if grid else ", with TPU_CHIPS_PER_HOST_BOUNDS set: "
+               "this host's chip grid is not known")
+            + ") or runs one controller over the whole host (-np 1); "
+            "--cpu simulates any world. libtpu does not take the "
+            "several-chip blocks a launcher can name (docs/tpus.md).")
+    ports = [_free_port() for _ in range(num_proc)]
+    return [{
+        "TPU_VISIBLE_CHIPS": str(i),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": ",".join(map(str, grid)),
+        "TPU_PROCESS_ADDRESSES": ",".join(f"localhost:{p}" for p in ports),
+        "TPU_PROCESS_PORT": str(ports[i]),
+        "CLOUD_TPU_TASK_ID": str(i),
+    } for i in range(num_proc)]
 
 
 def _graceful_stop(procs, grace_s: float, signum: int) -> int:
@@ -212,8 +281,8 @@ def _parse_faults(entries) -> dict:
     Specs are validated HERE, before any child spawns: a typo'd site or
     mode must fail the launch, not crash-loop every relaunched
     generation through an import-time FaultSpecError in the child.
-    (core.faultline is stdlib-only — importing it does not drag jax
-    into the launcher process.)"""
+    (core.faultline touches no jax API, so the launcher still
+    initialises no backend.)"""
     from horovod_tpu.core import faultline as _faultline
 
     out: dict = {}
@@ -308,10 +377,10 @@ def _supervise_elastic(args, spawn_world) -> int:
     signal.signal(signal.SIGINT, _on_signal)
     signal.signal(signal.SIGTERM, _on_signal)
 
-    # Read the knob from env directly — importing core.elastic here
-    # would drag jax (and the TPU plugin) into the supervisor process,
-    # the same reason RESTART_EXIT_CODE is duplicated above. Keep the
-    # default in sync with core/elastic.py blacklist_s().
+    # Read the knob from env directly — core.elastic brings up the jax
+    # distributed client, and the supervisor must never initialise a
+    # backend (the same reason RESTART_EXIT_CODE is duplicated above).
+    # Keep the default in sync with core/elastic.py blacklist_s().
     try:
         blacklist = float(os.environ.get("HVD_ELASTIC_BLACKLIST_S", "5"))
     except ValueError:
@@ -613,11 +682,22 @@ def main(argv=None):
                 f"{args.num_proc} processes need a directory "
                 "(per-rank traces + auto-merge)")
 
+    # One block of this host's TPU chips per child, unless the world is
+    # on the CPU. Decided once, before anything is spawned, so a layout
+    # that cannot work is refused at once instead of dying in libtpu.
+    on_cpu = args.cpu or os.environ.get("JAX_PLATFORMS") == "cpu"
+    tpu_envs = _tpu_child_envs(
+        args.num_proc, 0 if on_cpu else _host_tpu_chips(), os.environ)
+
     def _spawn_world(extra_env: dict):
         port = _free_port()
         procs, threads = [], []
         for i in range(args.num_proc):
             env = dict(os.environ)
+            if tpu_envs[i]:
+                for name in _TPU_HOST_WIDE_VARS:
+                    env.pop(name, None)
+                env.update(tpu_envs[i])
             env["HVD_COORDINATOR_ADDRESS"] = f"127.0.0.1:{port}"
             env["HVD_NUM_PROCESSES"] = str(args.num_proc)
             env["HVD_PROCESS_ID"] = str(i)
@@ -637,9 +717,6 @@ def main(argv=None):
                 env["HVD_TELEMETRY_PORT"] = str(
                     args.telemetry_port_base + i)
             if args.cpu:
-                # HVD_PLATFORM is applied via jax.config inside hvd.init()
-                # (plain JAX_PLATFORMS can be preempted by plugins).
-                env["HVD_PLATFORM"] = "cpu"
                 env["JAX_PLATFORMS"] = "cpu"
                 env["XLA_FLAGS"] = (
                     env.get("XLA_FLAGS", "") +

@@ -31,11 +31,18 @@ PEAK_HBM_BW = {
 
 
 def _by_device_kind(device, table) -> float:
-    kind = getattr(device, "device_kind", "").lower()
+    kind = getattr(device, "device_kind", "")
     for key, val in table.items():
-        if key in kind:
+        if key in kind.lower():
             return val
-    return 0.0  # unknown platform (e.g. CPU) -> callers report null
+    platform = getattr(device, "platform", "cpu")
+    if platform == "cpu":
+        return 0.0  # no peaks for a host CPU -> callers report null
+    # An accelerator the table does not know must not read as "no peak":
+    # MFU and bandwidth shares would silently print null.
+    raise ValueError(
+        f"unknown device_kind {kind!r} on platform {platform!r}: add its "
+        "peaks to horovod_tpu/utils/hardware.py")
 
 
 def peak_flops(device) -> float:

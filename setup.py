@@ -2,19 +2,18 @@
 of MPI/CUDA/NCCL feature detection; here the native engine needs only a
 C++17 toolchain, so the build reduces to one g++ invocation).
 
-    pip install .           # builds libhvdcore.so into the wheel
+    pip install .           # builds libhvdcore.<key>.so into the wheel
     HVD_SKIP_NATIVE=1 pip install .   # python-engine-only install
 """
 
 import os
-import subprocess
 
 from setuptools import Command, find_packages, setup
 from setuptools.command.build_py import build_py
 
 
 class BuildNative(Command):
-    """Compile libhvdcore.so next to its source (the runtime also builds
+    """Compile libhvdcore next to its source (the runtime also builds
     on demand, so failure here degrades to the python engine rather than
     failing the install — the reference instead hard-fails without MPI)."""
 
@@ -30,13 +29,20 @@ class BuildNative(Command):
     def run(self):  # noqa: D102
         if os.environ.get("HVD_SKIP_NATIVE"):
             return
-        src = os.path.join("horovod_tpu", "core", "native", "hvdcore.cc")
-        out = os.path.join("horovod_tpu", "core", "native", "libhvdcore.so")
-        cmd = ["g++", "-O2", "-std=c++17", "-fPIC", "-shared", "-pthread",
-               "-Wall", src, "-o", out]
+        # One build recipe, the runtime loader's: it names the library
+        # after a hash of source + flags, so what the wheel ships is
+        # what the loader accepts. Loaded by file path — importing the
+        # package would pull in jax.
+        import importlib.util
+
+        spec = importlib.util.spec_from_file_location(
+            "_hvd_native", os.path.join("horovod_tpu", "core", "native",
+                                        "__init__.py"))
+        native = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(native)
         try:
-            subprocess.run(cmd, check=True)
-        except (OSError, subprocess.CalledProcessError) as e:
+            native.build_library()
+        except (OSError, native.NativeBuildError) as e:
             print(f"WARNING: native engine build failed ({e}); "
                   "the python engine will be used (HVD_ENGINE=python)")
 

@@ -1,6 +1,6 @@
 """Worker for the launcher test: relies entirely on the env wiring that
 ``python -m horovod_tpu.run`` provides (HVD_COORDINATOR_ADDRESS /
-HVD_NUM_PROCESSES / HVD_PROCESS_ID / HVD_PLATFORM)."""
+HVD_NUM_PROCESSES / HVD_PROCESS_ID, and JAX_PLATFORMS under --cpu)."""
 
 import numpy as np
 import jax.numpy as jnp

@@ -114,7 +114,7 @@ def test_spmd_reducescatter_allgather_roundtrip_non_divisible(hvd):
     round trip)."""
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.common.compat import shard_map
+    from jax import shard_map
 
     n = hvd.size()
     rows = 2 * n + 3
@@ -179,7 +179,7 @@ def test_in_spmd_collectives(hvd):
     """Collectives inside shard_map over the world mesh — the hot path."""
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.common.compat import shard_map
+    from jax import shard_map
 
     mesh = hvd.mesh()
     n = hvd.size()
@@ -242,7 +242,7 @@ def test_spmd_int_average_preserves_dtype(hvd):
     """Traced and eager integer averaging must agree (floor-div, same dtype)."""
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.common.compat import shard_map
+    from jax import shard_map
 
     n = hvd.size()
     xs = jnp.full((n, 4), 3, dtype=jnp.int32)

@@ -166,7 +166,7 @@ def test_flash_in_ulysses():
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from horovod_tpu.common.compat import shard_map
+    from jax import shard_map
 
     from horovod_tpu import parallel
 
@@ -186,3 +186,61 @@ def test_flash_in_ulysses():
                     out_specs=spec, check_vma=False)(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
+
+
+def test_auto_block_is_always_a_legal_tpu_tile():
+    """Mosaic takes a second-to-last block dim only if it is a multiple
+    of 8 or the whole array dim: _auto_block returns nothing else, and
+    raises for a sequence that has no such divisor."""
+    from horovod_tpu.ops.flash_attention import _auto_block
+
+    for s in range(1, 2100):
+        try:
+            blk = _auto_block(s)
+        except ValueError as exc:
+            assert "multiple of 8" in str(exc)
+            assert s > 512 and not any(
+                s % c == 0 for c in range(8, 513, 8)), s
+            continue
+        assert s % blk == 0 and blk <= 512, (s, blk)
+        assert blk % 8 == 0 or blk == s, (s, blk)
+    assert _auto_block(512) == 512 and _auto_block(2048) == 512
+    assert _auto_block(96) == 96 and _auto_block(1000) == 200
+    with pytest.raises(ValueError, match="pad the sequence"):
+        _auto_block(514)
+
+
+def test_unaligned_explicit_blocks_rejected_when_compiling():
+    q, k, v = _qkv(s=24)
+    with pytest.raises(ValueError, match="multiples of 8"):
+        flash_attention(q, k, v, block_q=12, block_k=12, interpret=False)
+
+
+def test_interpret_fallback_is_recorded_once(caplog):
+    import logging
+
+    from horovod_tpu.ops import pallas_mode
+
+    pallas_mode.INTERPRETED.discard("probe_kernel")
+    with caplog.at_level(logging.WARNING, logger="horovod_tpu"):
+        assert pallas_mode.resolve_interpret(None, "probe_kernel") is True
+        assert pallas_mode.resolve_interpret(None, "probe_kernel") is True
+        # An explicit choice is the caller's and is not reported.
+        assert pallas_mode.resolve_interpret(False, "other") is False
+    assert "probe_kernel" in pallas_mode.INTERPRETED
+    assert "other" not in pallas_mode.INTERPRETED
+    assert sum("probe_kernel" in r.getMessage()
+               for r in caplog.records) == 1
+    pallas_mode.INTERPRETED.discard("probe_kernel")
+
+
+def test_kernel_check_runs_at_toy_shapes_in_interpret_mode():
+    """The on-chip kernel check (python -m horovod_tpu.ops.kernel_check)
+    keeps working: same comparisons, toy shapes, interpret mode."""
+    from horovod_tpu.ops import kernel_check
+
+    kernel_check.check_flash(1, 32, 2, 8, True, interpret=True,
+                             block_q=8, block_k=16)
+    kernel_check.check_fused_loss(16, 8, 70, interpret=True,
+                                  block_n=8, block_v=32)
+    assert kernel_check.main() == 1  # the real run needs a TPU

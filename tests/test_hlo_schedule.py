@@ -26,7 +26,7 @@ import numpy as np
 import pytest
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from horovod_tpu.common.compat import shard_map
+from jax import shard_map
 
 from horovod_tpu.parallel.hierarchical import hierarchical_allreduce
 

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from horovod_tpu.common.compat import shard_map
+from jax import shard_map
 
 from horovod_tpu import parallel
 from horovod_tpu.models.transformer import (

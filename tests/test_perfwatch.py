@@ -1,6 +1,8 @@
 """The performance sentinel (core/sentinel.py) and the perf regression
-gate (utils/perfwatch.py): gate arithmetic pinned against the checked-in
-BENCH_r*.json history, watchdog anomaly semantics (fire-once, cooldown,
+gate (utils/perfwatch.py): gate arithmetic pinned against a SYNTHETIC
+BENCH_r*.json history (tests/data/bench_history — made-up numbers in the
+shape a driver records; the repo root holds no history, where ``bench.py
+--check`` reports ``skip``), watchdog anomaly semantics (fire-once, cooldown,
 attribution), flight-dump retention, the /metrics + /healthz endpoint,
 and the unified stats --json envelope."""
 
@@ -19,6 +21,8 @@ from horovod_tpu.core import telemetry as tele
 from horovod_tpu.utils import perfwatch as pw
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+HISTORY = os.path.join(REPO, "tests", "data", "bench_history")
+R05 = os.path.join(HISTORY, "BENCH_r05.json")
 
 
 @pytest.fixture()
@@ -57,21 +61,25 @@ def test_perfwatch_is_stdlib_only():
 
 
 def test_load_history_fixtures():
-    hist = pw.load_history(REPO)
+    hist = pw.load_history(HISTORY)
     labels = [r["label"] for r in hist]
     assert labels[:5] == ["r01", "r02", "r03", "r04", "r05"]
     r05 = hist[labels.index("r05")]
-    assert r05["value"] == 2938.4
-    assert r05["hbm_gb_per_step"] == 7.81
-    # The recorded iteration spread (2919-2951 over median 2938.4).
-    assert r05["spread_frac"] == pytest.approx((2951 - 2919) / 2938.4)
+    assert r05["value"] == 1200.0
+    assert r05["hbm_gb_per_step"] == 8.0
+    # The recorded iteration spread (1194-1206 over median 1200).
+    assert r05["spread_frac"] == pytest.approx((1206 - 1194) / 1200.0)
     # BASELINE.json is metadata-only today: no numeric record.
     assert pw.load_record(os.path.join(REPO, "BASELINE.json")) is None
+    # The repo root carries no history: the gate has nothing to judge
+    # against and says so.
+    assert pw.load_history(REPO) == []
+    assert pw.gate(r05, pw.pick_reference([], r05))["status"] == "skip"
 
 
 def test_gate_passes_on_r05_against_history():
-    hist = pw.load_history(REPO)
-    cur = pw.load_record(os.path.join(REPO, "BENCH_r05.json"))
+    hist = pw.load_history(HISTORY)
+    cur = pw.load_record(R05)
     ref = pw.pick_reference(hist, cur)
     assert ref["label"] == "r05"  # newest same-metric record
     result = pw.gate(cur, ref)
@@ -84,28 +92,28 @@ def test_gate_passes_on_r05_against_history():
 
 
 def test_gate_fails_on_doctored_img_per_sec_drop():
-    hist = pw.load_history(REPO)
-    cur = pw.load_record(os.path.join(REPO, "BENCH_r05.json"))
+    hist = pw.load_history(HISTORY)
+    cur = pw.load_record(R05)
     cur["value"] = round(cur["value"] * 0.90, 2)  # -10%
     result = pw.gate(cur, pw.pick_reference(hist, cur))
     assert result["status"] == "fail"
     bad = [c for c in result["checks"] if not c["ok"]]
     assert [c["field"] for c in bad] == ["value"]
-    # The bound is noise-aware: spread (~1.1%) below the 2% floor, so
+    # The bound is noise-aware: spread (1.0%) below the 2% floor, so
     # the floor rules -> reference * (1 - 0.02 * 1.5).
     assert bad[0]["bound"] == pytest.approx(
-        2938.4 * (1 - pw.MIN_NOISE * pw.NOISE_MULT), abs=0.01)
+        1200.0 * (1 - pw.MIN_NOISE * pw.NOISE_MULT), abs=0.01)
 
 
 def test_gate_fails_on_hbm_traffic_creep():
-    hist = pw.load_history(REPO)
-    cur = pw.load_record(os.path.join(REPO, "BENCH_r05.json"))
+    hist = pw.load_history(HISTORY)
+    cur = pw.load_record(R05)
     cur["hbm_gb_per_step"] = round(cur["hbm_gb_per_step"] * 1.10, 3)
     result = pw.gate(cur, pw.pick_reference(hist, cur))
     assert result["status"] == "fail"
     bad = [c for c in result["checks"] if not c["ok"]]
     assert [c["field"] for c in bad] == ["hbm_gb_per_step"]
-    assert bad[0]["bound"] == pytest.approx(7.81 * (1 + pw.HBM_TOL),
+    assert bad[0]["bound"] == pytest.approx(8.0 * (1 + pw.HBM_TOL),
                                             abs=1e-3)
 
 
@@ -113,13 +121,13 @@ def test_gate_skips_cleanly():
     # No history at all.
     assert pw.gate({"value": 1.0}, None)["status"] == "skip"
     # Metric mismatch: a vgg run must not gate against the resnet line.
-    hist = pw.load_history(REPO)
+    hist = pw.load_history(HISTORY)
     other = {"metric": "vgg16_train_images_per_sec_per_chip_bs32",
              "value": 100.0}
     assert pw.pick_reference(hist, other) is None
     # Null fields skip their check, not the whole gate: a CPU record
     # with no measured HBM still gates on throughput.
-    cur = pw.load_record(os.path.join(REPO, "BENCH_r05.json"))
+    cur = pw.load_record(R05)
     cur["hbm_gb_per_step"] = None
     result = pw.gate(cur, pw.pick_reference(hist, cur))
     assert result["status"] == "pass"
@@ -128,9 +136,9 @@ def test_gate_skips_cleanly():
 
 def test_perfwatch_cli_trend_and_check(tmp_path, capsys):
     # Trend table over the checked-in history.
-    assert pw.main(["--history", REPO]) == 0
+    assert pw.main(["--history", HISTORY]) == 0
     out = capsys.readouterr().out
-    assert "r05" in out and "2938" in out
+    assert "r05" in out and "1200" in out
     # The byte-diet delta column (HBM diet round 2): hbm_gb_per_step
     # movement is visible next to the headline Δ%.
     assert "hbmΔ%" in out
@@ -159,15 +167,15 @@ def test_perfwatch_cli_gate(tmp_path, capsys):
     good = tmp_path / "good.json"
     good.write_text(json.dumps(
         {"metric": "resnet50_train_images_per_sec_per_chip_bs32",
-         "value": 2940.0, "hbm_gb_per_step": 7.8, "spread_pct": 1.1}))
-    assert pw.main([str(good), "--history", REPO, "--check"]) == 0
+         "value": 1202.0, "hbm_gb_per_step": 7.9, "spread_pct": 1.1}))
+    assert pw.main([str(good), "--history", HISTORY, "--check"]) == 0
     # ...a doctored one exits 2 with the failing field named.
     bad = tmp_path / "bad.json"
     bad.write_text(json.dumps(
         {"metric": "resnet50_train_images_per_sec_per_chip_bs32",
-         "value": 2644.0, "hbm_gb_per_step": 8.6}))
+         "value": 1080.0, "hbm_gb_per_step": 8.8}))
     capsys.readouterr()
-    assert pw.main([str(bad), "--history", REPO, "--check"]) == 2
+    assert pw.main([str(bad), "--history", HISTORY, "--check"]) == 2
     out = capsys.readouterr().out
     assert "FAIL" in out and "hbm_gb_per_step" in out
     # perf.jsonl loads line-per-record; the last record gates.
@@ -181,8 +189,8 @@ def test_perfwatch_cli_gate(tmp_path, capsys):
     # Unnamed capture records gate against the log's EARLIER captures —
     # never against the named bench history (pick_reference refuses the
     # cross): 9.9 GB vs the log's own 7.5 GB is a creep -> exit 2.
-    assert pw.pick_reference(pw.load_history(REPO), recs[-1]) is None
-    assert pw.main([str(pj), "--history", REPO, "--check"]) == 2
+    assert pw.pick_reference(pw.load_history(HISTORY), recs[-1]) is None
+    assert pw.main([str(pj), "--history", HISTORY, "--check"]) == 2
 
 
 # ---------------------------------------------------------------------------

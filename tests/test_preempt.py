@@ -131,17 +131,27 @@ def _clean_env(extra=None):
     return env
 
 
-def _launch_and_sigterm(child_script, grace_s, settle_s=2.0):
+def _launch_and_sigterm(child_script, grace_s):
+    """Start a 2-child world whose children print 'child up' once their
+    own SIGTERM handling is in place, and SIGTERM the launcher only
+    then: how long the launcher takes to import and spawn depends on the
+    installation, so a fixed sleep either races it or wastes time."""
     proc = subprocess.Popen(
         [sys.executable, "-m", "horovod_tpu.run", "-np", "2",
          "--grace-s", str(grace_s), "--",
          sys.executable, "-c", child_script],
         stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
         env=_clean_env(), cwd=_REPO)
-    time.sleep(settle_s)  # children spawned (plain python, no jax)
+    head = []
+    while sum("child up" in ln for ln in head) < 2:
+        line = proc.stdout.readline()
+        if not line:
+            break  # launcher died early: the asserts below will say how
+        head.append(line)
+    time.sleep(0.3)  # the launcher installs its handlers right after spawn
     proc.send_signal(signal.SIGTERM)
     out, err = proc.communicate(timeout=120)
-    return proc.returncode, out, err
+    return proc.returncode, "".join(head) + out, err
 
 
 def test_launcher_sigterm_forwards_and_reports_clean_drain():
@@ -153,6 +163,7 @@ def test_launcher_sigterm_forwards_and_reports_clean_drain():
              "    print('child drained clean', flush=True)\n"
              "    sys.exit(0)\n"
              "signal.signal(signal.SIGTERM, bye)\n"
+             "print('child up', flush=True)\n"
              "time.sleep(120)\n")
     rc, out, err = _launch_and_sigterm(child, grace_s=20)
     assert rc == 0, (rc, err[-2000:])
@@ -171,6 +182,7 @@ def test_launcher_sigterm_escalates_stragglers():
              "else:\n"
              "    signal.signal(signal.SIGTERM,\n"
              "                  lambda s, f: sys.exit(0))\n"
+             "print('child up', flush=True)\n"
              "time.sleep(120)\n")
     rc, out, err = _launch_and_sigterm(child, grace_s=2)
     assert rc == 128 + signal.SIGTERM, (rc, err[-2000:])

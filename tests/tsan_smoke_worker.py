@@ -2,7 +2,7 @@
 
 Spawned by tests/test_analysis.py (opt-in HVD_SLOW_TESTS tier) with
 ``LD_PRELOAD=<libtsan>`` and ``HVD_SANITIZE=thread`` so load_library
-picks the instrumented ``libhvdcore.tsan.so``. The executor is pure
+picks the instrumented ``libhvdcore.tsan.<key>.so``. The executor is pure
 numpy — no jax backend initialization, no devices — which keeps the run
 about the ENGINE's concurrency: multi-threaded submits, fusion batches,
 donated buffers, waiter wakeups, stats reads, and shutdown-drain, all
