@@ -1,0 +1,192 @@
+"""``BENCHMARK.json`` against the files it names, the command's refusals,
+and the promise that a later PR adds a cell with data files alone."""
+
+import json
+import os
+import re
+import shutil
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path.insert(0, REPO)
+
+from benchmark.harness import spec, step  # noqa: E402
+
+BENCH = spec.load_benchmark()
+NAME_RE = re.compile(r"^[A-Za-z0-9][A-Za-z0-9_.-]{0,63}$")
+CELLS = [w["name"] for w in BENCH["workloads"]]
+
+
+def _run(root, *args, env=None):
+    full = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
+    full.pop("JAX_COMPILATION_CACHE_DIR", None)
+    full.update(env or {})
+    return subprocess.run(
+        [sys.executable, os.path.join(root, "benchmark", "run.py"), *args],
+        capture_output=True, text=True, timeout=300, env=full, cwd=root)
+
+
+def test_top_level_keys_are_the_contracts():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert BENCH["command"] == ["python3", "benchmark/run.py"]
+    assert 1 <= BENCH["run_seconds"] <= 51
+    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 64 * 1024
+
+
+def test_names_are_plain_and_used_once():
+    names = [x["name"] for key in ("configs", "workloads", "end_to_end",
+                                   "per_layer") for x in BENCH[key]]
+    assert all(NAME_RE.match(n) for n in names), names
+    assert len(names) == len(set(names))
+    for x in BENCH["configs"] + BENCH["workloads"]:
+        assert len(x["why"]) <= 200, (x["name"], len(x["why"]))
+    # A pair of config and traffic appears once, whatever the chips.
+    pairs = [(w["config"], w["traffic"]) for w in BENCH["workloads"]]
+    assert len(pairs) == len(set(pairs))
+
+
+def test_the_four_chip_cell_runs_the_one_chip_cells_traffic():
+    """``bert_base_s512_x4`` has a traffic file of its own only because a
+    pair appears once; scaling efficiency is its throughput over
+    ``bert_base_s512``'s, so the parameters stay the same."""
+    one = dict(spec.load_cell("bert_base_s512").traffic)
+    four = dict(spec.load_cell("bert_base_s512_x4").traffic)
+    four.pop("note")
+    assert one == four
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_cell_resolves_to_its_files(name):
+    cell = spec.load_cell(name)
+    assert cell.chips in (1, 4)
+    assert not [k for k in step.TRAFFIC_KEYS if k not in cell.traffic]
+    for kind in ("families", "reference"):
+        assert spec.load_module(kind, cell.family)
+    family = spec.load_module("families", cell.family)
+    assert family.ITEM in ("images", "tokens")
+    assert family.model_flops_per_item(cell.config, cell.traffic) > 0
+    assert callable(spec.load_module("reference", cell.family).loss)
+    # Every cell reports set-up, another end-to-end metric and a
+    # per-layer metric.
+    assert "setup_s" in {m["name"] for m in cell.end_to_end}
+    assert len(cell.end_to_end) >= 2 and cell.per_layer
+    assert cell.config["loss_tolerance"]["why"]
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+def test_config_file_is_under_paths_and_used(name):
+    entry = next(c for c in BENCH["configs"] if c["name"] == name)
+    assert any(entry["file"].startswith(p + "/") for p in BENCH["paths"])
+    with open(os.path.join(REPO, entry["file"])) as f:
+        config = json.load(f)
+    assert config["reduced"] == entry["reduced"]
+    assert entry["source"].startswith("https://")
+    assert name in {w["config"] for w in BENCH["workloads"]}
+    # No width is ever cut.
+    assert not [k for k in entry["reduced"]
+                if re.search(r"hidden_size|intermediate|_dim$|_rank$|head",
+                             k)]
+
+
+@pytest.mark.parametrize("name", [m["name"] for m in BENCH["per_layer"]])
+def test_per_layer_metric_has_a_reader(name):
+    entry = next(m for m in BENCH["per_layer"] if m["name"] == name)
+    assert callable(spec.load_module("metrics", name).read)
+    assert entry["moves"] in {m["name"] for m in BENCH["end_to_end"]}
+    assert entry["source"] in ("device_trace", "program_span",
+                               "program_counter", "host_clock")
+    assert set(entry.get("workloads", CELLS)) <= set(CELLS)
+
+
+def test_end_to_end_metrics_and_the_share_of_four_chip_cells():
+    for m in BENCH["end_to_end"]:
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.1
+    setup = next(m for m in BENCH["end_to_end"] if m["name"] == "setup_s")
+    assert setup["bound"] == 0.1
+    four = [w for w in BENCH["workloads"] if w["chips"] == 4]
+    assert len(four) <= max(1, len(BENCH["workloads"]) // 4)
+
+
+def test_peaks_name_their_source_and_an_unknown_kind_is_an_error():
+    with open(os.path.join(REPO, "benchmark", "peaks.json")) as f:
+        peaks = json.load(f)
+    assert "Google Cloud" in peaks["source"]
+    v5e = spec.load_peaks("TPU v5 lite")
+    assert v5e["bf16_flops_per_s"] == 197e12
+    assert v5e["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(spec.SpecError, match="not in benchmark/peaks.json"):
+        spec.load_peaks("TPU v9 imaginary")
+
+
+def test_importing_the_benchmark_initialises_no_backend():
+    code = ("import benchmark.harness.loop, benchmark.harness.layers\n"
+            "import benchmark.harness.hlo, benchmark.aot_rehearsal\n"
+            "from benchmark.harness import spec\n"
+            "for k, n in [('families', 'resnet'), ('reference', 'resnet'),\n"
+            "             ('families', 'transformer_lm'),\n"
+            "             ('metrics', 'flash_roofline')]:\n"
+            "    spec.load_module(k, n)\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized()\n"
+            "print('NO BACKEND')\n")
+    proc = subprocess.run(
+        [sys.executable, "-c", code], capture_output=True, text=True,
+        timeout=120, cwd=REPO, env=dict(os.environ, PYTHONPATH=REPO))
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "NO BACKEND" in proc.stdout
+
+
+def test_command_refuses_to_run_off_a_tpu():
+    proc = _run(REPO, "--workload", CELLS[0], "--seed", "0",
+                "--seconds", "1", "--trace", "0")
+    assert proc.returncode not in (0, 2), proc.stderr[-2000:]
+    assert "no TPU" in proc.stderr and "platform='cpu'" in proc.stderr
+    assert '"correct"' not in proc.stdout  # no result line
+
+
+def test_unknown_workload_is_an_error_that_lists_the_cells():
+    proc = _run(REPO, "--workload", "no_such_cell", "--seed", "0",
+                "--seconds", "1", "--trace", "0")
+    assert proc.returncode == 2
+    assert "unknown workload" in proc.stderr and CELLS[0] in proc.stderr
+    assert not proc.stdout.strip()
+
+
+def test_a_fifth_cell_is_one_json_file_and_one_entry(tmp_path):
+    """In a temporary copy: a new traffic file from an existing one, one
+    new entry in ``workloads``, no other edit. The copy's command then
+    finds the cell (it gets as far as looking for the chip), and a cell
+    whose traffic file is missing is refused by name."""
+    root = str(tmp_path / "copy")
+    shutil.copytree(os.path.join(REPO, "benchmark"),
+                    os.path.join(root, "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    traffic = os.path.join(root, "benchmark", "traffic")
+    with open(os.path.join(traffic, "seq512_bs16.json")) as f:
+        mix = json.load(f)
+    mix.update(compression="int8", sharded_update=True)
+    with open(os.path.join(traffic, "seq512_bs16_int8_sharded.json"),
+              "w") as f:
+        json.dump(mix, f)
+    bench = json.loads(json.dumps(BENCH))
+    bench["workloads"] += [
+        {"name": "fifth", "config": "bert_base",
+         "traffic": "seq512_bs16_int8_sharded", "chips": 1, "why": "test"},
+        {"name": "sixth", "config": "bert_base",
+         "traffic": "never_written", "chips": 1, "why": "test"}]
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+
+    args = ("--seed", "0", "--seconds", "1", "--trace", "0")
+    found = _run(root, "--workload", "fifth", *args)
+    assert found.returncode not in (0, 2), found.stderr[-2000:]
+    assert "no TPU" in found.stderr
+    missing = _run(root, "--workload", "sixth", *args)
+    assert missing.returncode == 2
+    assert "benchmark/traffic/never_written.json" in missing.stderr
