@@ -1,0 +1,11 @@
+"""``flash_dkv_ms_per_step`` (layer: kernels): device milliseconds a step
+spends in the flash-attention backward kernel for dk and dv (pallas name
+``flash_dkv_bwd_bhsd``; in a program whose pallas calls have no names,
+the ``_bwd_bhsd`` call that returns a pair). ``None`` where no flash
+kernel ran."""
+
+from benchmark.harness import phases
+
+
+def read(context):
+    return phases.flash_ms(context, "flash_dkv")

@@ -629,6 +629,30 @@ def check_entrypoint_imports(root: str,
 
 
 # ---------------------------------------------------------------------------
+# Rule: phase-names
+# ---------------------------------------------------------------------------
+
+def check_phase_names(tree: ast.AST, rel: str) -> List[Finding]:
+    """Every literal handed to ``phase(...)`` is a name of
+    ``common/phases.py``'s vocabulary. ``phase`` refuses another name
+    when the call is traced; a path that no test traces (a two-tier
+    route, an error-feedback branch) would refuse in a user's step."""
+    from horovod_tpu.common.phases import PHASES
+
+    return [
+        Finding("phase-names", rel, node.lineno,
+                f"phase({node.args[0].value!r}) is not in the vocabulary "
+                f"{PHASES}: use one of them or add the name to "
+                "horovod_tpu/common/phases.py")
+        for node in ast.walk(tree)
+        if isinstance(node, ast.Call)
+        and _attr_name(node.func) in ("phase", "_phase")
+        and len(node.args) == 1 and isinstance(node.args[0], ast.Constant)
+        and isinstance(node.args[0].value, str)
+        and node.args[0].value not in PHASES]
+
+
+# ---------------------------------------------------------------------------
 # Entry
 # ---------------------------------------------------------------------------
 
@@ -655,6 +679,7 @@ def check(root: str,
         findings.extend(check_engine_lifecycle(tree, rel))
         findings.extend(check_donate_mutate(tree, rel))
         findings.extend(check_eager_drain(tree, rel))
+        findings.extend(check_phase_names(tree, rel))
     lock_trees: Dict[str, ast.AST] = {}
     for rel in lock_files or LOCK_SCOPE:
         path = os.path.join(root, rel)

@@ -67,6 +67,8 @@ RULE_CATALOG: Dict[str, str] = {
                        "submit-all-then-wait)",
     "engine-lifecycle": "never destroy the C++ engine; abandon paths "
                         "must not join a wedged engine",
+    "phase-names": "a literal handed to phase(...) must be a name of "
+                   "the vocabulary in common/phases.py",
     "donate-mutate": "a buffer handed over with donate=True must not "
                      "be mutated before synchronize in the same scope",
     "eager-drain": "trainer broadcast_state methods must pull state to "

@@ -1,0 +1,61 @@
+"""Phase names inside the compiled step.
+
+A step under :func:`horovod_tpu.jax.jit` is one XLA program; the engine's
+timeline and telemetry stop at its dispatch. What the framework issues
+*inside* it goes under one of a few fixed names, so that a device trace
+can be read by what the program asked for and not by what XLA made of it.
+``phase(name)`` is ``jax.named_scope(name)``: the name becomes part of
+``metadata.op_name`` of every HLO instruction traced under it (xprof and
+Perfetto show it with the op), and costs nothing at run time. There is no
+switch. A new code path inside the step goes under one of these names or
+adds one to the tuple (docs/observability.md, "Phase names in the
+compiled step").
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+from jax.experimental.xla_metadata import set_xla_metadata
+
+#: pack: gradient tree -> the collective's operand (ravel, per-dtype
+#: concatenate, pad, compress / quantize). allreduce: the collective(s).
+#: unpack: slices and reshapes back to leaves, decompress, the average.
+#: numerics: the in-step gradient health statistics. optimizer: the inner
+#: optax update.
+PHASES = ("hvd_pack", "hvd_allreduce", "hvd_unpack", "hvd_numerics",
+          "hvd_optimizer")
+
+#: Pallas kernels' ``name=``. The flash names keep the ``_fwd_bhsd`` /
+#: ``_bwd_bhsd`` of the jitted functions round them, which readers of
+#: device traces already match.
+KERNELS = ("flash_fwd_bhsd", "flash_dq_bwd_bhsd", "flash_dkv_bwd_bhsd",
+           "xent_fwd", "xent_dx", "xent_dw")
+
+#: Stamped on every op of a ``hvd.jax.jit`` step as the frontend
+#: attribute ``hvd_phases``. jax's persistent compile cache keys on the
+#: program without its debug info, so a program that differs from a
+#: cached one only in names would be served the cached executable, old
+#: names and all; the attribute is in the key. Bump it with ``PHASES``,
+#: ``KERNELS`` or a move of where a name is emitted.
+VOCABULARY_VERSION = "1"
+
+
+def phase(name: str):
+    """``jax.named_scope(name)`` for a name of :data:`PHASES`."""
+    if name not in PHASES:
+        raise ValueError(f"unknown phase {name!r}: the vocabulary is "
+                         f"{PHASES} (horovod_tpu/common/phases.py)")
+    return jax.named_scope(name)
+
+
+def stamped(fn):
+    """``fn``, traced with :data:`VOCABULARY_VERSION` on every op."""
+
+    @functools.wraps(fn)
+    def traced(*args, **kwargs):
+        with set_xla_metadata(hvd_phases=VOCABULARY_VERSION):
+            return fn(*args, **kwargs)
+
+    return traced

@@ -21,6 +21,7 @@ import numpy as np
 import optax
 from jax import shard_map as _shard_map
 
+from horovod_tpu.common import phases as _phases
 from horovod_tpu.common.topology import (  # noqa: F401
     init,
     shutdown,
@@ -119,7 +120,8 @@ def allreduce(
         data = allgather(tensor.data)
         indices = allgather(tensor.indices)
         if average:
-            data = data / _world_size_like(data)
+            with _phases.phase("hvd_unpack"):
+                data = data / _world_size_like(data)
         return _BCOO((data, indices), shape=tensor.shape)
     if _C._topo._require_init().size == 1:
         # Single-rank world: the reduction is identity; skip the wire
@@ -157,9 +159,11 @@ def allreduce(
         # tripping the quantized compressor's deliberate
         # NotImplementedError.
         return _C.allreduce(tensor, average=average, name=name)
-    tensor, ctx = compression.compress(tensor)
+    with _phases.phase("hvd_pack"):
+        tensor, ctx = compression.compress(tensor)
     out = _C.allreduce(tensor, average=average, name=name)
-    return compression.decompress(out, ctx)
+    with _phases.phase("hvd_unpack"):
+        return compression.decompress(out, ctx)
 
 
 def _world_size_like(x):
@@ -199,10 +203,12 @@ def allreduce_pytree(tree, average: bool = True, compression=Compression.none,
         for i, r in zip(dense_idx, reduced):
             out[i] = r
     elif dense_idx:
-        comp = [compression.compress(leaves[i]) for i in dense_idx]
+        with _phases.phase("hvd_pack"):
+            comp = [compression.compress(leaves[i]) for i in dense_idx]
         reduced = _C.grouped_allreduce([c[0] for c in comp], average=average)
-        for i, r, (_, ctx) in zip(dense_idx, reduced, comp):
-            out[i] = compression.decompress(r, ctx)
+        with _phases.phase("hvd_unpack"):
+            for i, r, (_, ctx) in zip(dense_idx, reduced, comp):
+                out[i] = compression.decompress(r, ctx)
     for i in sparse_idx:
         out[i] = allreduce(leaves[i], average, None, compression, sparse_as_dense)
     return _jax.tree_util.tree_unflatten(treedef, out)
@@ -404,7 +410,8 @@ def DistributedOptimizer(
                 sparse_as_dense=sparse_as_dense,
             )
             if pol == "off":
-                return optimizer.update(grads, state, params, **kwargs)
+                with _phases.phase("hvd_optimizer"):
+                    return optimizer.update(grads, state, params, **kwargs)
             leaves = _jax.tree_util.tree_leaves(grads)
             ax = (_C.rank_axes()
                   if leaves and _C.in_spmd(leaves[0]) else None)
@@ -413,16 +420,20 @@ def DistributedOptimizer(
             # survives the reduction, so the nonfinite counts see it;
             # the per-rank vector (pre-reduction local counts,
             # all_gathered) names the offender.
-            stats = _jnum.tree_stats(grads)
-            per_rank = (_jnum.per_rank_nonfinite(local, ax)
-                        if ax is not None else None)
-            upd, new_state = optimizer.update(grads, state, params,
-                                              **kwargs)
-            if pol == "halt":
-                finite = _jnum.all_finite(stats)
-                upd = _jnum.guard_updates(finite, upd)
-                new_state = _jnum.guard_state(finite, new_state, state)
-            health = _jnum.health_of(stats, per_rank)
+            with _phases.phase("hvd_numerics"):
+                stats = _jnum.tree_stats(grads)
+                per_rank = (_jnum.per_rank_nonfinite(local, ax)
+                            if ax is not None else None)
+            with _phases.phase("hvd_optimizer"):
+                upd, new_state = optimizer.update(grads, state, params,
+                                                  **kwargs)
+            with _phases.phase("hvd_numerics"):
+                if pol == "halt":
+                    finite = _jnum.all_finite(stats)
+                    upd = _jnum.guard_updates(finite, upd)
+                    new_state = _jnum.guard_state(finite, new_state,
+                                                  state)
+                health = _jnum.health_of(stats, per_rank)
             if leaves and _C.in_spmd(leaves[0]):
                 _jnum.stash_traced(health)
             else:
@@ -606,6 +617,7 @@ def jit(fn: Callable = None, *, in_specs, out_specs, static_argnums=(),
     hierarchical hot path (operations.cc:1194-1346) at compile time."""
 
     def wrap(f):
+        f = _phases.stamped(f)
         if _C._hier_allreduce_active():
             sm = _shard_map(
                 f, mesh=_C._topo.two_tier(),

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from horovod_tpu.common import phases as _phases
+
 DEFAULT_THRESHOLD_ELEMS = 4096
 
 # Spellings accepted for the state_dtype policy knob. None / f32 mean
@@ -293,10 +295,15 @@ def fuse(optimizer: optax.GradientTransformation,
         # Small grads join the parameter-dtype buffers (bf16 compute
         # grads meet f32 master weights here, like the reference's fp16
         # compression decompressing into f32 before apply).
-        pgrads = _pack(grads, layout, cast_small=True)
-        pparams = None if params is None else _pack(params, layout)
+        with _phases.phase("hvd_pack"):
+            pgrads = _pack(grads, layout, cast_small=True)
+            pparams = None if params is None else _pack(params, layout)
+        # The optax math itself is the caller's hvd_optimizer phase
+        # (DistributedOptimizer.update); only the ravel and unravel
+        # round it are this wrapper's own.
         pupd, new_state = optimizer.update(pgrads, state, pparams,
                                            **extra_args)
-        return _unpack(pupd, layout), new_state
+        with _phases.phase("hvd_unpack"):
+            return _unpack(pupd, layout), new_state
 
     return optax.GradientTransformationExtraArgs(init, update)

@@ -85,29 +85,40 @@ def _jnp():
     return jnp
 
 
+def _phase(name: str):
+    # jax stays a lazy import here, as in _jnp (the numpy twins below
+    # serve the engines' host-side data plane).
+    from horovod_tpu.common import phases
+
+    return phases.phase(name)
+
+
 def quantize(flat, policy):
     """1-D float array (length % block == 0) -> (payload wire-dtype,
     scales f32 of length n/block). Zero blocks get scale 1.0 so their
     payload is exactly zero (padding neutrality)."""
     jnp = _jnp()
-    x = flat.astype(jnp.float32).reshape(-1, policy.block)
-    amax = jnp.max(jnp.abs(x), axis=1)
-    scale = jnp.where(amax > 0, amax / policy.qmax, 1.0).astype(jnp.float32)
-    y = x / scale[:, None]
-    if policy.round_to_int:
-        payload = jnp.clip(jnp.round(y), -policy.qmax, policy.qmax).astype(
-            jnp.int8)
-    else:
-        payload = y.astype(jnp.dtype(policy.wire_dtype_name))
-    return payload.reshape(flat.shape[0]), scale
+    with _phase("hvd_pack"):
+        x = flat.astype(jnp.float32).reshape(-1, policy.block)
+        amax = jnp.max(jnp.abs(x), axis=1)
+        scale = jnp.where(amax > 0, amax / policy.qmax,
+                          1.0).astype(jnp.float32)
+        y = x / scale[:, None]
+        if policy.round_to_int:
+            payload = jnp.clip(jnp.round(y), -policy.qmax,
+                               policy.qmax).astype(jnp.int8)
+        else:
+            payload = y.astype(jnp.dtype(policy.wire_dtype_name))
+        return payload.reshape(flat.shape[0]), scale
 
 
 def dequantize(payload, scales, policy, out_dtype=None):
     """Inverse of :func:`quantize`; f32 math, optionally cast."""
     jnp = _jnp()
-    x = (payload.astype(jnp.float32).reshape(-1, policy.block)
-         * scales.reshape(-1)[:, None]).reshape(payload.shape[0])
-    return x if out_dtype is None else x.astype(out_dtype)
+    with _phase("hvd_unpack"):
+        x = (payload.astype(jnp.float32).reshape(-1, policy.block)
+             * scales.reshape(-1)[:, None]).reshape(payload.shape[0])
+        return x if out_dtype is None else x.astype(out_dtype)
 
 
 def spmd_exchange_accumulate(payload, scales, ax, policy):
@@ -122,13 +133,16 @@ def spmd_exchange_accumulate(payload, scales, ax, policy):
     jnp = _jnp()
     world = lax.psum(1, ax)
     nb = scales.shape[0]
-    p = lax.all_to_all(payload.reshape(world, -1), ax,
-                       split_axis=0, concat_axis=0)
-    s = lax.all_to_all(scales.reshape(world, -1), ax,
-                       split_axis=0, concat_axis=0)
-    contrib = (p.astype(jnp.float32).reshape(world, nb // world, policy.block)
-               * s[:, :, None])
-    return contrib.sum(axis=0).reshape(payload.shape[0] // world)
+    with _phase("hvd_allreduce"):
+        p = lax.all_to_all(payload.reshape(world, -1), ax,
+                           split_axis=0, concat_axis=0)
+        s = lax.all_to_all(scales.reshape(world, -1), ax,
+                           split_axis=0, concat_axis=0)
+    with _phase("hvd_unpack"):
+        contrib = (p.astype(jnp.float32).reshape(world, nb // world,
+                                                 policy.block)
+                   * s[:, :, None])
+        return contrib.sum(axis=0).reshape(payload.shape[0] // world)
 
 
 def spmd_reduce_scatter(flat, ax, policy):
@@ -148,8 +162,9 @@ def spmd_gather_dequantize(payload, scales, ax, policy, out_dtype=None):
     gathered state is identical everywhere."""
     from jax import lax
 
-    p = lax.all_gather(payload, ax, axis=0, tiled=True)
-    s = lax.all_gather(scales, ax, axis=0, tiled=True)
+    with _phase("hvd_allreduce"):
+        p = lax.all_gather(payload, ax, axis=0, tiled=True)
+        s = lax.all_gather(scales, ax, axis=0, tiled=True)
     return dequantize(p, s, policy, out_dtype)
 
 
@@ -170,17 +185,20 @@ def spmd_allreduce(tensor, ax, average: bool, policy):
 
     jnp = _jnp()
     world = lax.psum(1, ax)
-    flat = tensor.reshape(-1)
-    n = flat.shape[0]
-    npad = padded_len(n, world * policy.block)
-    if npad != n:
-        flat = jnp.concatenate(
-            [flat, jnp.zeros((npad - n,), flat.dtype)])
+    with _phase("hvd_pack"):
+        flat = tensor.reshape(-1)
+        n = flat.shape[0]
+        npad = padded_len(n, world * policy.block)
+        if npad != n:
+            flat = jnp.concatenate(
+                [flat, jnp.zeros((npad - n,), flat.dtype)])
     shard = spmd_reduce_scatter(flat, ax, policy)
     if average:
-        shard = shard / world
+        with _phase("hvd_unpack"):
+            shard = shard / world
     out = spmd_all_gather(shard, ax, policy)
-    return out[:n].reshape(tensor.shape).astype(tensor.dtype)
+    with _phase("hvd_unpack"):
+        return out[:n].reshape(tensor.shape).astype(tensor.dtype)
 
 
 def eager_exchange_accumulate(payload, scales, policy, world):

@@ -56,6 +56,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from horovod_tpu.common import topology as _topo
+from horovod_tpu.common import phases as _phases
 from horovod_tpu.common.topology import HVD_AXIS
 from horovod_tpu.core import numerics as _num
 from horovod_tpu.jax import numerics as _jnum
@@ -177,15 +178,25 @@ def shard_update(
         return world * qpol.block if qpol is not None else world
 
     def _pack_padded(tree, layout, multiple, cast_small=False):
-        packed = _pack(tree, layout, cast_small=cast_small)
-        # Same zero-pad-to-multiple contract as reducescatter's.
-        return {k: _C._pad_dim0(v, multiple)
-                for k, v in packed["buf"].items()}
+        with _phases.phase("hvd_pack"):
+            packed = _pack(tree, layout, cast_small=cast_small)
+            # Same zero-pad-to-multiple contract as reducescatter's.
+            return {k: _C._pad_dim0(v, multiple)
+                    for k, v in packed["buf"].items()}
 
     def _unpack_padded(bufs, layout):
         # _unpack indexes [off:off+n] per leaf, so trailing padding is
         # simply never read.
-        return _unpack({"buf": bufs, "big": []}, layout)
+        with _phases.phase("hvd_unpack"):
+            return _unpack({"buf": bufs, "big": []}, layout)
+
+    def _inner_update(g, state, p, extra_args):
+        """The wrapped transform on one block of per-dtype buffers."""
+        with _phases.phase("hvd_optimizer"):
+            u, new_state = optimizer.update(
+                {"buf": g, "big": []}, state,
+                None if p is None else {"buf": p, "big": []}, **extra_args)
+        return u["buf"], new_state
 
     def init(params):
         world = _world()
@@ -233,21 +244,22 @@ def shard_update(
         the trajectory — the scale must ride into the epilogue."""
         lr_scale = extra_args.pop("lr_scale", None)
         master = state["master"]
-        inner = load_state(state["inner"], sdt)
-        ushard, new_inner = optimizer.update(
-            {"buf": g32, "big": []}, inner, {"buf": master, "big": []},
-            **extra_args)
-        if lr_scale is not None:
-            # Skipped entirely when absent: a *1.0 would still be exact,
-            # but the bitwise-equivalence pins deserve an untouched path.
-            ushard = {"buf": {k: v * lr_scale
-                              for k, v in ushard["buf"].items()},
-                      "big": ushard["big"]}
-        new_master = {k: master[k] + ushard["buf"][k] for k in master}
-        ures = {k: (new_master[k] - resbufs[k].astype(jnp.float32))
-                .astype(resbufs[k].dtype) for k in new_master}
-        return ures, {"master": new_master,
-                      "inner": store_state(new_inner, sdt)}
+        with _phases.phase("hvd_optimizer"):
+            inner = load_state(state["inner"], sdt)
+            ushard, new_inner = optimizer.update(
+                {"buf": g32, "big": []}, inner,
+                {"buf": master, "big": []}, **extra_args)
+            ushard = ushard["buf"]
+            if lr_scale is not None:
+                # Skipped entirely when absent: a *1.0 would still be
+                # exact, but the bitwise-equivalence pins deserve an
+                # untouched path.
+                ushard = {k: v * lr_scale for k, v in ushard.items()}
+            new_master = {k: master[k] + ushard[k] for k in master}
+            ures = {k: (new_master[k] - resbufs[k].astype(jnp.float32))
+                    .astype(resbufs[k].dtype) for k in new_master}
+            return ures, {"master": new_master,
+                          "inner": store_state(new_inner, sdt)}
 
     def update(grads, state, params=None, **extra_args):
         world = _world()
@@ -286,13 +298,20 @@ def shard_update(
         # this block lowers nothing (HLO pinned identical).
         pol = _num.policy()
 
-        def _observe(stats, per_rank=None):
-            health = _jnum.health_of(stats, per_rank)
+        def _guard_and_observe(stats, ures, new_state, state,
+                               per_rank=None):
+            with _phases.phase("hvd_numerics"):
+                if pol == "halt":
+                    finite = _jnum.all_finite(stats)
+                    ures = _jnum.guard_updates(finite, ures)
+                    new_state = _jnum.guard_state(finite, new_state, state)
+                health = _jnum.health_of(stats, per_rank)
             if traced:
                 _jnum.stash_traced(health)
             else:
                 _num.note_step_health(jax.device_get(health),
                                       origin="eager")
+            return ures, new_state
 
         if (ax is None and world == 1) or (
                 ax is not None and lax.psum(1, ax) == 1):
@@ -304,24 +323,19 @@ def shard_update(
             # whole-tree packing — fuse() semantics, a measured NEGATIVE
             # on one chip (module docstring); kept so the flag is
             # runnable anywhere.
-            stats = (_jnum.bucket_stats(gbufs) if pol != "off" else None)
+            with _phases.phase("hvd_numerics"):
+                stats = (_jnum.bucket_stats(gbufs) if pol != "off"
+                         else None)
             if sdt is not None:
                 g32 = {k: v.astype(jnp.float32) for k, v in gbufs.items()}
                 ures, new_state = _master_step(g32, state, pbufs,
                                                extra_args)
             else:
-                ufull, new_state = optimizer.update(
-                    {"buf": gbufs, "big": []}, state,
-                    None if pbufs is None else {"buf": pbufs, "big": []},
-                    **extra_args)
-                ures = ufull["buf"]
+                ures, new_state = _inner_update(gbufs, state, pbufs,
+                                                extra_args)
             if stats is not None:
-                if pol == "halt":
-                    finite = _jnum.all_finite(stats)
-                    ures = _jnum.guard_updates(finite, ures)
-                    new_state = _jnum.guard_state(finite, new_state,
-                                                  state)
-                _observe(stats)
+                ures, new_state = _guard_and_observe(
+                    stats, ures, new_state, state)
             return _unpack_padded(ures, layout), wrap(new_state)
         if ax is not None:
             # --- compiled SPMD path: scatter, update 1/N, gather -------
@@ -355,10 +369,12 @@ def shard_update(
                     dax, iax = ax
                     d_sz, i_sz = lax.psum(1, dax), lax.psum(1, iax)
                     sub = flat.shape[0] // (d_sz * i_sz)
-                    xp = (flat.reshape(d_sz, i_sz, sub).swapaxes(0, 1)
-                          .reshape(flat.shape[0]))
-                    chunk = lax.psum_scatter(xp, iax, scatter_dimension=0,
-                                             tiled=True)
+                    with _phases.phase("hvd_pack"):
+                        xp = (flat.reshape(d_sz, i_sz, sub).swapaxes(0, 1)
+                              .reshape(flat.shape[0]))
+                    with _phases.phase("hvd_allreduce"):
+                        chunk = lax.psum_scatter(
+                            xp, iax, scatter_dimension=0, tiled=True)
                     payload, scales = _Q.quantize(
                         chunk.astype(jnp.float32), qpol)
                     shard = _Q.spmd_exchange_accumulate(payload, scales,
@@ -370,9 +386,11 @@ def shard_update(
                     # dequantize-accumulate in f32 (jax/quantize.py).
                     # The residual is this rank's un-transmitted error,
                     # recorded for the NEXT step.
-                    x = flat.astype(jnp.float32)
-                    if ef:
-                        x = x + qres["g"][k][0]
+                    # (quantize / dequantize name their own phases.)
+                    with _phases.phase("hvd_pack"):
+                        x = flat.astype(jnp.float32)
+                        if ef:
+                            x = x + qres["g"][k][0]
                     payload, scales = _Q.quantize(x, qpol)
                     if ef:
                         new_qres["g"][k] = (
@@ -380,58 +398,60 @@ def shard_update(
                     shard = _Q.spmd_exchange_accumulate(payload, scales,
                                                         ax, qpol)
                 else:
-                    wire, ctx = compression.compress(flat)
-                    shard = lax.psum_scatter(wire, ax, scatter_dimension=0,
-                                             tiled=True)
-                    shard = compression.decompress(shard, ctx)
-                if sdt is not None:
-                    # Fused epilogue: the collective runs at the wire
-                    # (reduced) width; ONLY the 1/N shard upcasts to f32
-                    # — averaging included — so no full-width f32
-                    # gradient buffer exists between the reduce-scatter
-                    # and the update.
-                    shard = shard.astype(jnp.float32)
-                    return shard / n_axis if average else shard
-                if average:
-                    shard = (shard / n_axis).astype(flat.dtype)
-                elif qpol is not None:
-                    shard = shard.astype(flat.dtype)
-                return shard
+                    with _phases.phase("hvd_pack"):
+                        wire, ctx = compression.compress(flat)
+                    with _phases.phase("hvd_allreduce"):
+                        shard = lax.psum_scatter(
+                            wire, ax, scatter_dimension=0, tiled=True)
+                    with _phases.phase("hvd_unpack"):
+                        shard = compression.decompress(shard, ctx)
+                with _phases.phase("hvd_unpack"):
+                    if sdt is not None:
+                        # Fused epilogue: the collective runs at the wire
+                        # (reduced) width; ONLY the 1/N shard upcasts to
+                        # f32 — averaging included — so no full-width f32
+                        # gradient buffer exists between the
+                        # reduce-scatter and the update.
+                        shard = shard.astype(jnp.float32)
+                        return shard / n_axis if average else shard
+                    if average:
+                        shard = (shard / n_axis).astype(flat.dtype)
+                    elif qpol is not None:
+                        shard = shard.astype(flat.dtype)
+                    return shard
 
             gshard = {k: scatter(k, v) for k, v in gbufs.items()}
             # Health on the REDUCED 1/N shards (psum'd = whole-buffer
             # figures; NaN from any rank survives the reduction) plus
             # the pre-scatter local counts for per-rank attribution.
-            stats = (_jnum.bucket_stats(gshard, ax=ax)
-                     if pol != "off" else None)
-            pshard = None if pbufs is None else {
-                k: lax.dynamic_slice(
-                    v, (idx * (v.shape[0] // n_axis),),
-                    (v.shape[0] // n_axis,))
-                for k, v in pbufs.items()}
+            with _phases.phase("hvd_numerics"):
+                stats = (_jnum.bucket_stats(gshard, ax=ax)
+                         if pol != "off" else None)
+            with _phases.phase("hvd_pack"):
+                pshard = None if pbufs is None else {
+                    k: lax.dynamic_slice(
+                        v, (idx * (v.shape[0] // n_axis),),
+                        (v.shape[0] // n_axis,))
+                    for k, v in pbufs.items()}
             if sdt is not None:
                 # params are guaranteed under the policy, so pshard is
                 # never None here.
                 ures, new_state = _master_step(gshard, state, pshard,
                                                extra_args)
             else:
-                ushard, new_state = optimizer.update(
-                    {"buf": gshard, "big": []}, state,
-                    None if pshard is None else {"buf": pshard,
-                                                 "big": []},
-                    **extra_args)
-                ures = ushard["buf"]
+                ures, new_state = _inner_update(gshard, state, pshard,
+                                                extra_args)
             if stats is not None:
-                if pol == "halt":
-                    finite = _jnum.all_finite(stats)
-                    ures = _jnum.guard_updates(finite, ures)
-                    new_state = _jnum.guard_state(finite, new_state,
-                                                  state)
-                _observe(stats, _jnum.per_rank_nonfinite(gbufs, ax))
+                with _phases.phase("hvd_numerics"):
+                    per_rank = _jnum.per_rank_nonfinite(gbufs, ax)
+                ures, new_state = _guard_and_observe(
+                    stats, ures, new_state, state, per_rank)
 
             def gather(k, ushard):
                 if qpol is None:
-                    return lax.all_gather(ushard, ax, axis=0, tiled=True)
+                    with _phases.phase("hvd_allreduce"):
+                        return lax.all_gather(ushard, ax, axis=0,
+                                              tiled=True)
                 if hier_q:
                     # Inverse of the two-phase scatter: requantize the
                     # 1/N shard, quantized all-gather over DCN (the only
@@ -445,17 +465,21 @@ def shard_update(
                     chunk = _Q.spmd_gather_dequantize(payload, scales,
                                                       dax, qpol,
                                                       ushard.dtype)
-                    out = lax.all_gather(chunk, iax, axis=0, tiled=True)
-                    return (out.reshape(i_sz, d_sz, ushard.shape[0])
-                            .swapaxes(0, 1).reshape(out.shape[0]))
+                    with _phases.phase("hvd_allreduce"):
+                        out = lax.all_gather(chunk, iax, axis=0,
+                                             tiled=True)
+                    with _phases.phase("hvd_unpack"):
+                        return (out.reshape(i_sz, d_sz, ushard.shape[0])
+                                .swapaxes(0, 1).reshape(out.shape[0]))
                 # Requantize → quantized all-gather: the update delta
                 # ships at the wire width too; everyone (owner included)
                 # applies the dequantized values so state stays
                 # identical. Gather-side error feedback carries the
                 # shard's un-transmitted delta error to next step.
-                y = ushard.astype(jnp.float32)
-                if ef:
-                    y = y + qres["u"][k][0]
+                with _phases.phase("hvd_pack"):
+                    y = ushard.astype(jnp.float32)
+                    if ef:
+                        y = y + qres["u"][k][0]
                 payload, scales = _Q.quantize(y, qpol)
                 if ef:
                     new_qres["u"][k] = (
@@ -509,17 +533,10 @@ def shard_update(
         if sdt is not None:
             ures, new_state = _master_step(gfull, state, pbufs, extra_args)
         else:
-            ufull, new_state = optimizer.update(
-                {"buf": gfull, "big": []}, state,
-                None if pbufs is None else {"buf": pbufs, "big": []},
-                **extra_args)
-            ures = ufull["buf"]
+            ures, new_state = _inner_update(gfull, state, pbufs, extra_args)
         if stats is not None:
-            if pol == "halt":
-                finite = _jnum.all_finite(stats)
-                ures = _jnum.guard_updates(finite, ures)
-                new_state = _jnum.guard_state(finite, new_state, state)
-            _observe(stats)
+            ures, new_state = _guard_and_observe(stats, ures, new_state,
+                                                 state)
         if qpol is not None:
             # Mirror the SPMD gather phase: blockwise-quantize the full
             # update buffer (== the concatenation of the per-shard

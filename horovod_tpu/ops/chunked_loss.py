@@ -321,6 +321,7 @@ def _ce_fwd_call(h2d, kernel, bias, lab, block_n, block_v, interpret):
                         pltpu.VMEM((block_n, _STAT), jnp.float32),
                         pltpu.VMEM((block_n, _STAT), jnp.float32)],
         interpret=interpret,
+        name="xent_fwd",
     )(x, kernel_p, bias_p[None, :], labs)
     return (lse[:n0, 0] - lbl[:n0, 0]), lse[:, 0]
 
@@ -363,6 +364,7 @@ def _ce_bwd_call(h2d, kernel, bias, lab, lse, g, block_n, block_v,
         out_shape=jax.ShapeDtypeStruct((n, hdim), h2d.dtype),
         scratch_shapes=[pltpu.VMEM((block_n, hdim), jnp.float32)],
         interpret=interpret,
+        name="xent_dx",
     )(*inputs)
     # The dW kernel carries three (hdim, vocab-tile) buffers — the
     # double-buffered W input, the f32 scratch accumulator, and the
@@ -393,6 +395,7 @@ def _ce_bwd_call(h2d, kernel, bias, lab, lse, g, block_n, block_v,
         scratch_shapes=[pltpu.VMEM((hdim, block_v), jnp.float32),
                         pltpu.VMEM((8, block_v), jnp.float32)],
         interpret=interpret,
+        name="xent_dw",
     )(*inputs)
     return dx[:n0], dw[:, :v], db[0, :v]
 

@@ -37,6 +37,7 @@ from jax import lax, shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from horovod_tpu.common import topology as _topo
+from horovod_tpu.common import phases as _phases
 from horovod_tpu.common.topology import HVD_AXIS
 from horovod_tpu.core import numerics as _num
 from horovod_tpu.core import telemetry as _tele
@@ -136,12 +137,14 @@ def _require_axis(opname: str):
 def _psum_avg(x, world: int, average: bool, axis=HVD_AXIS):
     """psum, optionally averaged, preserving integer dtypes (floor-divide)
     so traced and eager calls agree."""
-    r = lax.psum(x, axis)
+    with _phases.phase("hvd_allreduce"):
+        r = lax.psum(x, axis)
     if average:
-        if jnp.issubdtype(x.dtype, jnp.floating) or jnp.issubdtype(x.dtype, jnp.complexfloating):
-            r = (r / world).astype(x.dtype)
-        else:
-            r = r // world
+        with _phases.phase("hvd_unpack"):
+            if jnp.issubdtype(x.dtype, jnp.floating) or jnp.issubdtype(x.dtype, jnp.complexfloating):
+                r = (r / world).astype(x.dtype)
+            else:
+                r = r // world
     return r
 
 
@@ -191,9 +194,11 @@ def _root_select_psum(x, root: int, axis=HVD_AXIS):
     through an integer cast since psum is undefined for them."""
     idx = lax.axis_index(axis)
     asbool = x.dtype == jnp.bool_
-    v = x.astype(jnp.int8) if asbool else x
-    v = jnp.where(idx == root, v, jnp.zeros_like(v))
-    r = lax.psum(v, axis)
+    with _phases.phase("hvd_pack"):
+        v = x.astype(jnp.int8) if asbool else x
+        v = jnp.where(idx == root, v, jnp.zeros_like(v))
+    with _phases.phase("hvd_allreduce"):
+        r = lax.psum(v, axis)
     return r.astype(jnp.bool_) if asbool else r
 
 
@@ -515,7 +520,8 @@ def allgather(tensor, name: Optional[str] = None):
             _require_axis("allgather")
         if lax.psum(1, ax) == 1:
             return tensor
-        return lax.all_gather(tensor, ax, axis=0, tiled=True)
+        with _phases.phase("hvd_allreduce"):
+            return lax.all_gather(tensor, ax, axis=0, tiled=True)
     tensor = jnp.asarray(tensor)
     if tensor.ndim == 0:
         raise ValueError("allgather requires a tensor with at least one dimension")
@@ -603,8 +609,11 @@ def reducescatter(tensor, name: Optional[str] = None):
         world = lax.psum(1, ax)
         if world == 1:
             return tensor
-        return lax.psum_scatter(_pad_dim0(tensor, world), ax,
-                                scatter_dimension=0, tiled=True)
+        with _phases.phase("hvd_pack"):
+            tensor = _pad_dim0(tensor, world)
+        with _phases.phase("hvd_allreduce"):
+            return lax.psum_scatter(tensor, ax, scatter_dimension=0,
+                                    tiled=True)
     tensor = jnp.asarray(tensor)
     if tensor.ndim == 0:
         raise ValueError(
@@ -627,7 +636,9 @@ def alltoall(tensor, name: Optional[str] = None):
             _require_axis("alltoall")
         if lax.psum(1, ax) == 1:
             return tensor
-        return lax.all_to_all(tensor, ax, split_axis=0, concat_axis=0, tiled=True)
+        with _phases.phase("hvd_allreduce"):
+            return lax.all_to_all(tensor, ax, split_axis=0, concat_axis=0,
+                                  tiled=True)
     tensor = jnp.asarray(tensor)
     if _topo._require_init().size == 1:
         _record_eager("alltoall", tensor, elided=True)
@@ -670,9 +681,12 @@ def _grouped_apply(fn, tensors: Sequence):
     results = [None] * len(tensors)
     for idxs in by_dtype.values():
         group = [tensors[i] for i in idxs]
-        flat, shapes, sizes = _flatten_group(group)
-        out = fn(flat)
-        for i, r in zip(idxs, _unflatten_group(out, shapes, sizes)):
+        with _phases.phase("hvd_pack"):
+            flat, shapes, sizes = _flatten_group(group)
+        out = fn(flat)  # the collective: names itself
+        with _phases.phase("hvd_unpack"):
+            leaves = _unflatten_group(out, shapes, sizes)
+        for i, r in zip(idxs, leaves):
             results[i] = r
     return results
 

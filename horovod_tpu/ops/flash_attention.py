@@ -178,6 +178,7 @@ def _fwd_bhsd(q, k, v, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, _STAT), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd_bhsd",
     )(q, k, v)
 
 
@@ -277,6 +278,7 @@ def _bwd_bhsd(q, k, v, lse, do, out, causal, block_q, block_k, interpret):
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dq_bwd_bhsd",
     )(q, k, v, lse, delta, do)
 
     # dK/dV: grid over K tiles, Q innermost.
@@ -296,6 +298,7 @@ def _bwd_bhsd(q, k, v, lse, do, out, causal, block_q, block_k, interpret):
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dkv_bwd_bhsd",
     )(q, k, v, lse, delta, do)
     return dq, dk, dv
 

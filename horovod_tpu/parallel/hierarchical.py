@@ -18,6 +18,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 from jax import lax
 
+from horovod_tpu.common import phases as _phases
 from horovod_tpu.parallel.mesh import DCN_AXIS, ICI_AXIS
 
 
@@ -56,25 +57,32 @@ def hierarchical_allreduce(
     to shrink, and the digest stays on the unquantized path).
     """
     inner = lax.psum(1, inner_axis)  # static at trace time
-    flat, pad = _padded_flat(x, inner)
-    chunk = lax.psum_scatter(flat, inner_axis, scatter_dimension=0, tiled=True)
+    with _phases.phase("hvd_pack"):
+        flat, pad = _padded_flat(x, inner)
+    with _phases.phase("hvd_allreduce"):
+        chunk = lax.psum_scatter(flat, inner_axis, scatter_dimension=0,
+                                 tiled=True)
     if dcn_policy is not None and lax.psum(1, outer_axis) > 1:
         from horovod_tpu.jax import quantize as _Q
 
+        # The quantized wire names its own pack / exchange / unpack.
         chunk = _Q.spmd_allreduce(chunk, outer_axis, False, dcn_policy)
     else:
-        chunk = lax.psum(chunk, outer_axis)
-    out = lax.all_gather(chunk, inner_axis, axis=0, tiled=True)
-    if pad:
-        out = out[:-pad]
-    if average:
-        world = inner * lax.psum(1, outer_axis)
-        if (jnp.issubdtype(out.dtype, jnp.floating)
-                or jnp.issubdtype(out.dtype, jnp.complexfloating)):
-            out = (out / world).astype(x.dtype)
-        else:
-            out = out // world
-    return out.reshape(x.shape)
+        with _phases.phase("hvd_allreduce"):
+            chunk = lax.psum(chunk, outer_axis)
+    with _phases.phase("hvd_allreduce"):
+        out = lax.all_gather(chunk, inner_axis, axis=0, tiled=True)
+    with _phases.phase("hvd_unpack"):
+        if pad:
+            out = out[:-pad]
+        if average:
+            world = inner * lax.psum(1, outer_axis)
+            if (jnp.issubdtype(out.dtype, jnp.floating)
+                    or jnp.issubdtype(out.dtype, jnp.complexfloating)):
+                out = (out / world).astype(x.dtype)
+            else:
+                out = out // world
+        return out.reshape(x.shape)
 
 
 def hierarchical_allgather(x, inner_axis: str = ICI_AXIS,
@@ -88,13 +96,15 @@ def hierarchical_allgather(x, inner_axis: str = ICI_AXIS,
     explicit control. Result ordering is outer-major, matching a flat
     gather over a (outer, inner)-ordered mesh.
     """
-    outer = lax.all_gather(x, outer_axis, axis=0, tiled=True)
-    both = lax.all_gather(outer, inner_axis, axis=1,
-                          tiled=False)  # (outer*n, inner, ...)
+    with _phases.phase("hvd_allreduce"):
+        outer = lax.all_gather(x, outer_axis, axis=0, tiled=True)
+        both = lax.all_gather(outer, inner_axis, axis=1,
+                              tiled=False)  # (outer*n, inner, ...)
     # Reorder to global rank order: outer-major, inner-minor.
     o = lax.psum(1, outer_axis)
     i = lax.psum(1, inner_axis)
     n = x.shape[0]
-    both = both.reshape((o, n, i) + x.shape[1:])
-    both = jnp.swapaxes(both, 1, 2)
-    return both.reshape((o * i * n,) + x.shape[1:])
+    with _phases.phase("hvd_unpack"):
+        both = both.reshape((o, n, i) + x.shape[1:])
+        both = jnp.swapaxes(both, 1, 2)
+        return both.reshape((o * i * n,) + x.shape[1:])

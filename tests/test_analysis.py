@@ -496,6 +496,27 @@ class Trainer:
     assert _findings_for(good, invariants.check_eager_drain) == []
 
 
+def test_rule_phase_names_catches_a_name_outside_the_vocabulary():
+    bad = '''
+def exchange(x):
+    with _phases.phase("hvd_exchange"):
+        return psum(x)
+'''
+    findings = _findings_for(bad, invariants.check_phase_names)
+    assert len(findings) == 1 and findings[0].rule == "phase-names"
+    assert "'hvd_exchange'" in findings[0].message
+
+
+def test_rule_phase_names_allows_the_vocabulary_and_other_calls():
+    good = '''
+def exchange(x, name):
+    with _phases.phase("hvd_allreduce"), _phase("hvd_pack"):
+        log(phase="start")          # a keyword, not the scope
+        return phase(name)          # not a literal: refused at trace time
+'''
+    assert _findings_for(good, invariants.check_phase_names) == []
+
+
 def test_rule_lock_order_catches_inversion():
     bad = '''
 class BufferPool:
