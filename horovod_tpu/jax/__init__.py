@@ -173,9 +173,14 @@ def _world_size_like(x):
 
 def allreduce_pytree(tree, average: bool = True, compression=Compression.none,
                      sparse_as_dense: bool = False):
-    """Fused allreduce over a pytree with per-leaf compression. The fusion
-    (per-dtype flat buffers) is the compile-time analogue of the reference's
-    64 MB fusion buffer (reference: operations.cc:2035-2074)."""
+    """Allreduce over a pytree with per-leaf compression. Dense leaves go
+    through :func:`horovod_tpu.ops.collectives.grouped_allreduce`: inside a
+    traced step the small ones (under ``FUSION_THRESHOLD_ELEMS``) share a
+    per-dtype flat buffer — the compile-time analogue of the reference's
+    64 MB fusion buffer (reference: operations.cc:2035-2074) — and every
+    larger leaf is compressed, reduced and decompressed as itself; eager
+    calls pack every leaf. A quantized policy is a pipeline over a flat
+    buffer and still packs the whole tree per dtype."""
     if _C._topo._require_init().size == 1:
         # Identity at world size 1 — per-leaf allreduce (which itself
         # short-circuits before the compression round trip) elides the

@@ -18,7 +18,12 @@ buffer severs that producer-consumer fusion and adds a full extra
 HBM round-trip per step (measured: whole-tree packing REGRESSED ResNet-50
 bs32 from 12.1 to 13.5 ms/step; small-only packing is the win). The
 ``threshold_elems`` knob is the compile-time analogue of the reference's
-runtime fusion-threshold byte knob.
+runtime fusion-threshold byte knob. Its default,
+``ops.collectives.FUSION_THRESHOLD_ELEMS``, has a second user: the
+gradient exchange inside a traced step
+(``ops.collectives.grouped_allreduce``) packs the same leaves and
+reduces every larger one as itself, for the same reason on the other
+side of the wire.
 
 Correctness domain: any *elementwise* gradient transformation — one where
 the update for element ``i`` depends only on gradient/state element ``i``
@@ -37,8 +42,9 @@ import jax.numpy as jnp
 import optax
 
 from horovod_tpu.common import phases as _phases
-
-DEFAULT_THRESHOLD_ELEMS = 4096
+from horovod_tpu.ops.collectives import (
+    FUSION_THRESHOLD_ELEMS as DEFAULT_THRESHOLD_ELEMS,
+)
 
 # Spellings accepted for the state_dtype policy knob. None / f32 mean
 # "off" (full-width f32 state, the pre-r7 behavior).

@@ -653,6 +653,14 @@ def alltoall(tensor, name: Optional[str] = None):
 # fusion_buffer_manager.cc + operations.cc:2035-2074 — done at trace time)
 # ---------------------------------------------------------------------------
 
+#: Leaves with fewer elements than this share a per-dtype buffer inside a
+#: traced step; a larger one is already bandwidth-bound and keeps its own
+#: op. One rule for both sides of the exchange: :func:`grouped_allreduce`
+#: here and the fused optimizer update (``jax/fused.py``, which has the
+#: measurement behind the number).
+FUSION_THRESHOLD_ELEMS = 4096
+
+
 def _flatten_group(tensors):
     shapes = [t.shape for t in tensors]
     sizes = [int(np.prod(s)) if s else 1 for s in shapes]
@@ -692,26 +700,46 @@ def _grouped_apply(fn, tensors: Sequence):
 
 
 def grouped_allreduce(tensors: Sequence, average: bool = True):
-    """Allreduce many tensors as one fused buffer — the compile-time
-    equivalent of the reference's 64 MB fusion buffer (reference:
-    operations.cc:2035-2074, fusion_buffer_manager.cc). One collective per
-    dtype group instead of one per tensor.
+    """Allreduce many tensors — the compile-time equivalent of the
+    reference's 64 MB fusion buffer (reference: operations.cc:2035-2074,
+    fusion_buffer_manager.cc), with the buffer's membership decided by
+    what each tensor is.
+
+    Inside a traced step only the leaves under
+    :data:`FUSION_THRESHOLD_ELEMS` share a buffer (one collective per
+    dtype group); every larger leaf is reduced as itself, and XLA's
+    all-reduce combiner groups those collectives and its scheduler
+    places them in the backward pass. Packing a large leaf buys nothing
+    there and costs a concatenate, an average and a slice pass over the
+    whole buffer and one all-reduce that waits for the last gradient
+    (measured on four chips, PERF.md, PR 25: 14.9 ms of BERT-base's
+    86.2 ms step). The small leaves keep their buffer because not every
+    route's collectives are combined: under the hierarchical route each
+    would pay an all-gather of its own. Eager calls on concrete arrays
+    keep one buffer per dtype group: each collective there is a dispatch
+    of its own.
 
     World size 1 short-circuits BEFORE the packing: the concatenate ->
     all-reduce -> slice chain survives XLA simplification even with one
     participant, costing a full extra HBM round trip of the tensor set
     per step (measured on the one-chip bench — docs/benchmarks.md)."""
+    tensors = [jnp.asarray(t) for t in tensors]
     if _topo._require_init().size == 1:
-        out = [jnp.asarray(t) for t in tensors]
-        for t in out:
+        for t in tensors:
             if not in_spmd(t):  # tracers: trace-time, not a per-step event
                 _record_eager("allreduce", t, elided=True)
-        return out
-    return _grouped_apply(lambda flat: allreduce(flat, average=average), tensors)
+        return tensors
+    alone = [in_spmd(t) and t.size >= FUSION_THRESHOLD_ELEMS for t in tensors]
+    packed = iter(_grouped_apply(
+        lambda flat: allreduce(flat, average=average),
+        [t for t, a in zip(tensors, alone) if not a]))
+    return [allreduce(t, average=average) if a else next(packed)
+            for t, a in zip(tensors, alone)]
 
 
 def allreduce_pytree(tree, average: bool = True):
-    """Fused allreduce over every leaf of a pytree (grad pytrees, metrics)."""
+    """Allreduce every leaf of a pytree (grad pytrees, metrics), fused as
+    :func:`grouped_allreduce` fuses."""
     leaves, treedef = jax.tree_util.tree_flatten(tree)
     return jax.tree_util.tree_unflatten(treedef, grouped_allreduce(leaves, average))
 

@@ -258,3 +258,102 @@ def test_spmd_int_average_preserves_dtype(hvd):
     out = f(xs)
     assert out.dtype == jnp.int32
     np.testing.assert_array_equal(np.asarray(out), np.full((n, 4), 3))
+
+
+# -- the exchange inside a traced step: small leaves share a buffer, a
+# -- large one is reduced as itself (grouped_allreduce) ---------------------
+
+_BIG = C.FUSION_THRESHOLD_ELEMS  # a leaf of this many elements is large
+
+
+def _mixed_tree(n, dtype, other):
+    """Per-rank values (leading axis ``n``): a large and two small leaves
+    in ``dtype``, a large and a small one in ``other``; one leaf exactly
+    at the threshold, one just under it."""
+    rng = np.random.default_rng(25)
+
+    def leaf(shape, dt):
+        if jnp.issubdtype(dt, jnp.integer):
+            return jnp.asarray(rng.integers(-999, 999, (n, *shape)), dt)
+        return jnp.asarray(rng.standard_normal((n, *shape)), dt)
+
+    return {"big": leaf((8, _BIG // 8), dtype), "small": leaf((7, 3), dtype),
+            "under": leaf((_BIG - 1,), dtype), "bias": leaf((5,), other),
+            "other_big": leaf((2 * _BIG + 3,), other)}
+
+
+_VERBS = ["grouped_allreduce", "allreduce_pytree", "jax.allreduce_pytree"]
+
+
+def _exchange(hvd, verb, tree, average):
+    """``tree`` through one of the three public spellings of the dense
+    exchange."""
+    import horovod_tpu.jax as hvd_jax
+
+    if verb == "grouped_allreduce":
+        leaves, treedef = jax.tree.flatten(tree)
+        return jax.tree.unflatten(
+            treedef, hvd.grouped_allreduce(leaves, average=average))
+    fn = (hvd_jax if verb.startswith("jax.") else hvd).allreduce_pytree
+    return fn(tree, average=average)
+
+
+@pytest.mark.parametrize("average", [False, True], ids=["sum", "average"])
+@pytest.mark.parametrize("dtype,other", [
+    (jnp.float32, jnp.int32), (jnp.bfloat16, jnp.float32),
+    (jnp.int32, jnp.float32)], ids=["f32+s32", "bf16+f32", "s32+f32"])
+@pytest.mark.parametrize("verb", _VERBS)
+def test_traced_exchange_equals_per_leaf_psum_bit_for_bit(
+        hvd, verb, dtype, other, average):
+    """Whichever leaf shares a buffer and whichever goes alone, each
+    result is that leaf's own ``psum`` (then ``/ n``, or ``// n`` for an
+    integer), to the bit."""
+    from jax import lax, shard_map
+    from jax.sharding import PartitionSpec as P
+
+    n = hvd.size()
+    tree = _mixed_tree(n, dtype, other)
+
+    def reference(x):
+        s = lax.psum(x, C.HVD_AXIS)
+        if not average:
+            return s
+        if jnp.issubdtype(x.dtype, jnp.integer):
+            return s // n
+        return (s / n).astype(x.dtype)
+
+    def run(fn):
+        return jax.jit(shard_map(
+            lambda t: fn(jax.tree.map(lambda x: x[0], t)), mesh=hvd.mesh(),
+            in_specs=P(C.HVD_AXIS), out_specs=P(), check_vma=False))(tree)
+
+    got = run(lambda t: _exchange(hvd, verb, t, average))
+    want = run(lambda t: jax.tree.map(reference, t))
+    for key in tree:
+        assert got[key].dtype == tree[key].dtype, key
+        assert got[key].shape == tree[key].shape[1:], key
+        np.testing.assert_array_equal(
+            np.asarray(got[key].astype(jnp.float32)),
+            np.asarray(want[key].astype(jnp.float32)), err_msg=key)
+
+
+@pytest.mark.parametrize("verb", _VERBS)
+def test_eager_exchange_still_issues_one_collective_per_dtype(
+        hvd, verb, monkeypatch):
+    """On concrete arrays every collective is a dispatch of its own, so a
+    large leaf still rides the per-dtype buffer."""
+    issued = []
+    ranked = C.ranked_allreduce
+    monkeypatch.setattr(
+        C, "ranked_allreduce",
+        lambda stacked, **kw: issued.append(
+            (stacked.dtype, stacked.shape[1:])) or ranked(stacked, **kw))
+    leaves = [jnp.ones((2 * _BIG,)), jnp.ones((3,)),
+              jnp.ones((_BIG + 1,), jnp.int32), jnp.ones((2, 2), jnp.int32)]
+    out = _exchange(hvd, verb, leaves, average=False)
+    assert sorted(issued, key=str) == sorted(
+        [(jnp.dtype(jnp.float32), (2 * _BIG + 3,)),
+         (jnp.dtype(jnp.int32), (_BIG + 5,))], key=str)
+    for leaf, reduced in zip(leaves, out):
+        np.testing.assert_array_equal(np.asarray(reduced),
+                                      np.asarray(leaf) * hvd.size())

@@ -165,3 +165,176 @@ def test_flat_vs_hierarchical_same_result(hvd):
         lambda v: lax.psum(v, "hvd"), mesh=flat_mesh,
         in_specs=P(), out_specs=P(), check_vma=False))(x)
     np.testing.assert_allclose(np.asarray(hier), np.asarray(flat))
+
+
+# -- the exchange inside a traced step packs only the small leaves ----------
+
+def _shape_elems(shape_literal: str):
+    """[(dtype, elements)] of every array shape in an HLO shape literal."""
+    return [(dt, int(np.prod([int(d) for d in dims.split(",") if d] or [1])))
+            for dt, dims in re.findall(r"\b([a-z]+\d+)\[([\d,]*)\]",
+                                       shape_literal)]
+
+
+_ITEMSIZE = {"f32": 4, "bf16": 2, "s32": 4}
+
+
+def _reduce_tree(hvd, verb, average):
+    """A ``hvd.jax.jit`` program that takes a replicated tree through one
+    of the public spellings of the dense exchange."""
+    import horovod_tpu.jax as hvd_jax
+
+    @hvd_jax.jit(in_specs=(P(),), out_specs=P())
+    def reduce_tree(t):
+        if verb == "grouped_allreduce":
+            leaves, treedef = jax.tree.flatten(t)
+            return jax.tree.unflatten(
+                treedef, hvd.grouped_allreduce(leaves, average=average))
+        fn = (hvd_jax if verb.startswith("jax.") else hvd).allreduce_pytree
+        return fn(t, average=average)
+
+    return reduce_tree
+
+
+def _exchange_tree():
+    from horovod_tpu.ops.collectives import FUSION_THRESHOLD_ELEMS as big
+
+    return big, {
+        "f32": [jnp.ones((8, big // 4)), jnp.ones((7,)), jnp.ones((big,)),
+                jnp.ones((3, 5)), jnp.ones((big - 1,))],
+        # (not bf16: the CPU backend widens it before it reduces)
+        "s32": [jnp.ones((3 * big,), jnp.int32), jnp.ones((6,), jnp.int32),
+                jnp.ones((3, 3), jnp.int32)],
+    }
+
+
+@pytest.mark.parametrize("verb", ["allreduce_pytree", "jax.allreduce_pytree",
+                                  "grouped_allreduce"])
+def test_traced_exchange_copies_no_large_leaf(hvd, verb):
+    """The compiled exchange of a tree of large and small leaves: the
+    only ``concatenate`` results are the small buffers, one a dtype (no
+    large leaf is among any concatenate's operands); what the collectives
+    carry is the tree's bytes and nothing more; each spans the world."""
+    big, tree = _exchange_tree()
+    hlo = _reduce_tree(hvd, verb, True).lower(tree).compile().as_text()
+    small = {dt: sum(x.size for x in leaves if x.size < big)
+             for dt, leaves in tree.items()}
+    concats = [_shape_elems(m.group(1)) for m in re.finditer(
+        r"= (\S+) concatenate\(", hlo)]
+    assert sorted(c for (c,) in concats) == sorted(small.items()), concats
+    ars = _collectives(hlo, "all-reduce")
+    carried = sum(_ITEMSIZE[dt] * n for _, shape in ars
+                  for dt, n in _shape_elems(shape))
+    assert carried == sum(x.nbytes for x in jax.tree.leaves(tree)), ars
+    # The large leaves are reduced in their own shapes, never flattened
+    # into a buffer: nothing the collectives carry is larger than a leaf.
+    assert max(n for _, shape in ars for _, n in _shape_elems(shape)) \
+        == 3 * big, ars
+    for groups, _ in ars:
+        assert _group_sizes(groups) == [8], groups
+
+
+@pytest.mark.parametrize("compression,wire", [("none", "f32"),
+                                              ("bf16", "bf16")])
+def test_distributed_optimizer_exchanges_leaf_by_leaf(hvd, compression, wire):
+    """``DistributedOptimizer(adamw, fused_update=True)`` over the mesh:
+    the updates of plain ``lax.pmean`` + optax, and on the wire every
+    large gradient in its own shape and in the compressor's dtype."""
+    import optax
+
+    import horovod_tpu.jax as hvd_jax
+    from horovod_tpu.ops.collectives import FUSION_THRESHOLD_ELEMS as big
+
+    params = {"w": jnp.linspace(-1.0, 1.0, 2 * big).reshape(2, big),
+              "v": jnp.linspace(0.5, 1.5, big + 8), "b": jnp.ones((5,)),
+              "g": jnp.full((3, 3), 0.25)}
+    x = jnp.arange(8.0)[:, None] + 1.0
+    inner = optax.adamw(1e-2, weight_decay=0.01)
+    opt = hvd_jax.DistributedOptimizer(
+        inner, fused_update=True,
+        compression=hvd_jax.Compression.resolve(compression))
+
+    def grads_of(p, xi):
+        return jax.grad(lambda q: sum(
+            jnp.sum(jnp.sin(leaf * xi)) for leaf in jax.tree.leaves(q)))(p)
+
+    @hvd_jax.jit(in_specs=(P(), P(), P(hvd_jax.HVD_AXIS)), out_specs=P())
+    def step(p, state, xs):
+        return opt.update(grads_of(p, xs[0, 0]), state, p)[0]
+
+    def plain(p, state, xs):
+        g = grads_of(p, xs[0, 0])
+        if compression == "bf16":
+            g = jax.tree.map(lambda t: lax.pmean(
+                t.astype(jnp.bfloat16), "hvd").astype(t.dtype), g)
+        else:
+            g = lax.pmean(g, "hvd")
+        return inner.update(g, state, p)[0]
+
+    want = jax.jit(shard_map(
+        plain, mesh=hvd.mesh(), in_specs=(P(), P(), P("hvd")),
+        out_specs=P(), check_vma=False))(params, inner.init(params), x)
+    got = step(params, opt.init(params), x)
+    for key in params:
+        np.testing.assert_allclose(np.asarray(got[key]),
+                                   np.asarray(want[key]), rtol=1e-6,
+                                   atol=1e-9, err_msg=key)
+
+    # The wire, as the program asks for it (the CPU backend may widen a
+    # bf16 all-reduce afterwards): one all_reduce a large leaf, in its
+    # shape and the wire dtype, and one small buffer.
+    text = step.lower(params, opt.init(params), x).as_text()
+    operands = re.findall(
+        r'"stablehlo\.all_reduce"\(.*?\(tensor<([\dx]+)x(\w+)>\)', text,
+        flags=re.S)
+    assert sorted(operands) == sorted(
+        [(f"2x{big}", wire), (f"{big + 8}", wire), ("14", wire)]), operands
+
+
+@pytest.fixture
+def two_tier_world(monkeypatch):
+    """The 8-device world as 2 slices of 4 with the hierarchical knob on
+    (tests/test_hierarchical_wiring.py has the same world)."""
+    import horovod_tpu as hvd
+
+    monkeypatch.setenv("HVD_TWO_TIER_SHAPE", "2,4")
+    monkeypatch.setenv("HVD_HIERARCHICAL_ALLREDUCE", "1")
+    hvd.shutdown()
+    hvd.init()
+    yield hvd
+    monkeypatch.undo()
+    hvd.shutdown()
+    hvd.init()
+
+
+@pytest.mark.parametrize("verb", ["allreduce_pytree", "jax.allreduce_pytree"])
+def test_large_leaf_takes_the_hierarchical_route_by_itself(
+        two_tier_world, verb):
+    """Under the two-tier mesh with the knob on, a large leaf is
+    reduce-scattered over ICI, all-reduced over DCN at a quarter of its
+    size and all-gathered over ICI, as itself: the small leaves' buffer
+    makes the same trip beside it, not with it."""
+    from horovod_tpu.ops.collectives import FUSION_THRESHOLD_ELEMS as big
+
+    n = 2 * big
+    tree = {"w": jnp.arange(n, dtype=jnp.float32).reshape(2, big),
+            "b": jnp.ones((8,)), "g": jnp.ones((2, 2))}
+    reduce_tree = _reduce_tree(two_tier_world, verb, False)
+    hlo = reduce_tree.lower(tree).compile().as_text()
+
+    def carried(op, group_sizes):
+        found = _collectives(hlo, op)
+        assert found, (op, hlo[-2000:])
+        for groups, _ in found:
+            assert sorted(_group_sizes(groups)) == group_sizes, (op, groups)
+        return sorted(k for _, shape in found for _, k in _shape_elems(shape))
+
+    assert carried("reduce-scatter", [4, 4]) == [12 // 4, n // 4]
+    assert carried("all-reduce", [2, 2, 2, 2]) == [12 // 4, n // 4]
+    assert carried("all-gather", [4, 4]) == [12, n]
+    assert [_shape_elems(m.group(1)) for m in re.finditer(
+        r"= (\S+) concatenate\(", hlo)] == [[("f32", 12)]]
+    out = reduce_tree(tree)
+    for key in tree:
+        np.testing.assert_array_equal(np.asarray(out[key]),
+                                      np.asarray(tree[key]) * 8)
