@@ -79,25 +79,33 @@ def reference_losses(cell, reference, system, device) -> Dict:
 
 def verdict(cell, system, first_losses: List[float],
             window_losses: List[float], ref_losses: List[float],
-            interpreted_kernels, on_tpu: bool) -> Dict[str, bool]:
-    """Every check by name; ``correct`` is their conjunction. The one
-    about pallas kernels holds on a TPU only: off it (the tests' tiny
-    cells) the kernels are interpreted by design."""
+            interpreted_kernels, on_tpu: bool) -> Dict[str, Dict]:
+    """Every check by name, as the number compared beside its limit:
+    ``{"value", "limit", "ok"}``, where ``ok`` is ``value <= limit``
+    (the loss has to fall, so there ``<``). ``correct`` is the
+    conjunction of the ``ok``. The one about pallas kernels holds on a
+    TPU only: off it (the tests' tiny cells) the kernels are interpreted
+    by design."""
     n = system.n_chips
-    tolerance = cell.config["loss_tolerance"]["abs"]
     fetched = first_losses + window_losses
-    checks = {
-        "losses_finite": all(math.isfinite(x) for x in fetched),
-        "loss_fell": fetched[-1] < fetched[0],
-        "reference": all(abs(a - b) <= tolerance for a, b in
-                         zip(first_losses[:REFERENCE_STEPS], ref_losses)),
-        "batch_on_every_chip": len(
-            {s.device for s in system.batch[0].addressable_shards}) == n,
-        "mean_rank": system.mean_rank == (n - 1) / 2,
+    gaps = [abs(a - b) if math.isfinite(a - b) else math.inf for a, b in
+            zip(first_losses[:REFERENCE_STEPS], ref_losses)]
+    numbers = {
+        # counts of what must not be there
+        "losses_finite": (sum(not math.isfinite(x) for x in fetched), 0),
+        "loss_fell": (fetched[-1] - fetched[0], 0.0),
+        # the widest of the first losses' gaps to the reference's
+        "reference": (max(gaps), cell.config["loss_tolerance"]["abs"]),
+        "batch_on_every_chip": (n - len(
+            {s.device for s in system.batch[0].addressable_shards}), 0),
+        "mean_rank": (abs(system.mean_rank - (n - 1) / 2), 0.0),
     }
-    if n > 1:
-        checks["all_reduce_spans_world"] = (
-            hlo.all_reduce_group(system.hlo_text) == n)
+    if n > 1:  # chips the widest all-reduce of the step leaves out
+        numbers["all_reduce_spans_world"] = (
+            n - hlo.all_reduce_group(system.hlo_text), 0)
     if on_tpu:
-        checks["kernels_compiled"] = not interpreted_kernels
-    return checks
+        numbers["kernels_compiled"] = (len(interpreted_kernels), 0)
+    return {name: {"value": value, "limit": limit,
+                   "ok": value < limit if name == "loss_fell"
+                   else value <= limit}
+            for name, (value, limit) in numbers.items()}

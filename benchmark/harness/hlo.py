@@ -14,6 +14,9 @@ _COLLECTIVE_RE = re.compile(
     r"=\s*(?P<shape>\([^=]*?\)|\S+)\s+"
     r"(?P<op>all-reduce|all-gather|reduce-scatter|all-to-all|"
     r"collective-permute)(?:-start)?\((?P<operands>[^)]*)\)(?P<attrs>.*)$")
+# XLA numbers the elements of a tuple shape of more than five ("/*index=5*/");
+# the "=" inside would end the shape group early.
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
 
 
 def _bytes_in(text: str) -> int:
@@ -24,10 +27,11 @@ def _bytes_in(text: str) -> int:
 def collectives(hlo_text: str) -> List[Tuple[str, int, int]]:
     """(op, operand bytes, largest replica group) for every collective
     instruction of the module. Operand bytes are those of the shapes
-    printed with the operands; where the text prints none, the result's."""
+    printed with the operands; where the text prints none, the result's.
+    A variadic instruction counts all its operands."""
     out = []
     for line in hlo_text.splitlines():
-        m = _COLLECTIVE_RE.search(line)
+        m = _COLLECTIVE_RE.search(_COMMENT_RE.sub("", line))
         if not m:
             continue
         nbytes = _bytes_in(m.group("operands")) or _bytes_in(m.group("shape"))

@@ -7,6 +7,9 @@ where a user would log the loss. It ends on the first such barrier after
 ``--seconds``. Everything before the first measured dispatch is
 ``setup_s``; the reference runs after the window and after the memory
 peak is read, so neither its buffers nor its seconds are in a metric.
+Before it runs the system's state and executable are given back
+(``release``): the check holds the reference beside the resident batch
+and nothing else, so a cell may fill the chip in its window.
 """
 
 from __future__ import annotations
@@ -16,6 +19,7 @@ import json
 import math
 import os
 import shutil
+import sys
 import time
 from typing import Dict, List
 
@@ -105,6 +109,27 @@ def peak_bytes(devices) -> List[int]:
     return [sum(int(s.get(k, 0)) for k in keys) for s in stats]
 
 
+def release(system, device) -> Dict:
+    """Delete the arrays of ``system.state`` and drop the executable; what
+    ``device`` held before and after, where the runtime says (XLA:CPU
+    keeps no statistics). The deleted arrays stay in ``system.state``:
+    they hold nothing, and a test can ask each whether it went. The
+    batch, the HLO text and ``remake_weights`` stay for the reference
+    and the verdict."""
+    import jax
+
+    def in_use(key):
+        stats = device.memory_stats() or {}
+        return {key: int(stats["bytes_in_use"])} \
+            if "bytes_in_use" in stats else {}
+
+    held = in_use("bytes_in_use_before")
+    for leaf in jax.tree.leaves(system.state):
+        leaf.delete()
+    system.compiled = None
+    return {**held, **in_use("bytes_in_use_after")}
+
+
 def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
              t_start: float, require_tpu: bool = True) -> Dict:
     """Run ``cell`` once and return the result line's object.
@@ -178,23 +203,32 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     else:
         declared = cell.end_to_end
 
-    # The reference last: its buffers must not enter the peak above.
+    # The reference last: its buffers must not enter the peak above, and
+    # the system's must not stand beside it.
     import horovod_tpu as hvd
     from horovod_tpu.ops import pallas_mode
 
+    log(phase="released", **release(system, devices[0]))
     ref = check.reference_losses(cell, reference, system, devices[0])
-    checks = check.verdict(cell, system, first_losses, window["losses"],
-                           ref["losses"], pallas_mode.INTERPRETED,
-                           on_tpu=platform == "tpu")
+    compared = check.verdict(cell, system, first_losses, window["losses"],
+                             ref["losses"], pallas_mode.INTERPRETED,
+                             on_tpu=platform == "tpu")
     hvd.shutdown()
     log(phase="checked", reference_s=ref["seconds"],
         system_losses=first_losses, reference_losses=ref["losses"],
-        checks=checks)
+        checks={name: c["ok"] for name, c in compared.items()})
+    # Each number compared beside its limit: the end of standard error
+    # is what the driver keeps of a run that is not correct.
+    for name, c in compared.items():
+        print(f"compared {name}: {c['value']!r} limit {c['limit']!r} "
+              f"{'ok' if c['ok'] else 'FAILED'}", file=sys.stderr, flush=True)
 
     return {
-        "correct": all(checks.values()),
+        "correct": all(c["ok"] for c in compared.values()),
         "attempted": window["steps"], "failed": window["failed_steps"],
         "metrics": {
             m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
             for m in declared if values.get(m["name"]) is not None},
-        "device": device, **breakdown}
+        "device": device, **breakdown,
+        "compared": {name: [c["value"], c["limit"]]
+                     for name, c in compared.items()}}

@@ -68,6 +68,19 @@ def _for_cell(metrics, cell_name):
                  if cell_name in m.get("workloads", [cell_name]))
 
 
+def _stem(path: str) -> str:
+    return os.path.splitext(os.path.basename(path))[0]
+
+
+def _cell(bench, name, chips, config_name, config_file, traffic_file) -> Cell:
+    return Cell(name=name, chips=int(chips), config_name=config_name,
+                config=_load_json(config_file),
+                traffic_name=_stem(traffic_file),
+                traffic=_load_json(traffic_file),
+                end_to_end=_for_cell(bench["end_to_end"], name),
+                per_layer=_for_cell(bench["per_layer"], name))
+
+
 def load_cell(name: str) -> Cell:
     bench = load_benchmark()
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -79,13 +92,18 @@ def load_cell(name: str) -> Cell:
     if w["config"] not in configs:
         raise SpecError(f"workload {name!r} names config {w['config']!r}, "
                         "which BENCHMARK.json does not list")
-    config = _load_json(os.path.join(ROOT, configs[w["config"]]["file"]))
-    traffic = _load_json(os.path.join(
-        BENCH_DIR, "traffic", w["traffic"] + ".json"))
-    return Cell(name=name, chips=int(w["chips"]), config_name=w["config"],
-                config=config, traffic_name=w["traffic"], traffic=traffic,
-                end_to_end=_for_cell(bench["end_to_end"], name),
-                per_layer=_for_cell(bench["per_layer"], name))
+    return _cell(bench, name, w["chips"], w["config"],
+                 os.path.join(ROOT, configs[w["config"]]["file"]),
+                 os.path.join(BENCH_DIR, "traffic", w["traffic"] + ".json"))
+
+
+def cell_from_files(config_file: str, traffic_file: str, chips: int) -> Cell:
+    """A cell that ``BENCHMARK.json`` does not name yet, from its two
+    files (``aot_rehearsal.py --config``): named ``<config>.<traffic>``
+    after them, with the metrics that every cell reports."""
+    config = _stem(config_file)
+    return _cell(load_benchmark(), f"{config}.{_stem(traffic_file)}", chips,
+                 config, config_file, traffic_file)
 
 
 def load_peaks(device_kind: str) -> dict:

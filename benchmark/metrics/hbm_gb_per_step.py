@@ -3,7 +3,10 @@ a traced step, mean over the devices: the async copies' payload
 (``Async XLA Ops``: first shape of each copy's HLO text) plus what the
 compute fusions stream themselves (``XLA Ops``: their operand and result
 shapes outside VMEM). It is what the fused update and the resident-state
-work moved. ``None`` where the op names carry no shapes."""
+work moved. Not counted: ``concatenate``, ``copy`` and slices that
+stand alone as instructions, so it is no measure of a step whose traffic
+sits in them (a flat buffer that is packed and sliced back). ``None``
+where the op names carry no shapes."""
 
 from benchmark.harness import xtrace
 

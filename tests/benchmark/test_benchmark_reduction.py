@@ -15,7 +15,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, REPO)
 
-from benchmark.harness import hlo, layers, spec, xtrace  # noqa: E402
+from benchmark.harness import check, hlo, layers, spec, xtrace  # noqa: E402
 
 HBM = "bf16[8,128]{1,0:T(8,128)}"          # 2,048 bytes in HBM
 VMEM = "f32[128]{0:T(128)S(1)}"            # scoped memory: not counted
@@ -287,3 +287,52 @@ def test_hlo_counts():
     assert not hlo.has_tpu_custom_call(text)
     assert hlo.has_tpu_custom_call(
         '%k = f32[8] custom-call(%x), custom_call_target="tpu_custom_call"')
+
+
+# A variadic all-reduce as the TPU compiler prints one (PR 25's step has
+# three, of 21 to 29 operands): a tuple shape whose elements XLA numbers
+# in comments from the sixth on, tiled layouts, operands without shapes.
+VARIADIC = (
+    "  %all-reduce.1 = (f32[3072,768]{1,0:T(8,128)}, "
+    "f32[12,64,768]{2,1,0:T(8,128)}, f32[768,12,64]{0,2,1:T(8,128)}, "
+    "f32[768]{0:T(1024)S(1)}, f32[768,3072]{1,0:T(8,128)}, "
+    "/*index=5*/f32[3072,768]{1,0:T(8,128)S(1)}, f32[]{:T(128)}) "
+    "all-reduce(%fusion.1, %fusion.2, %fusion.3, %fusion.4, %fusion.5, "
+    "/*index=5*/%fusion.6, %div.7), channel_id=3, "
+    "replica_groups={{0,1,2,3}}, use_global_device_ids=true, "
+    "to_apply=%region_1.2, frontend_attributes={hvd_phases=\"1\"}")
+VARIADIC_BYTES = 4 * (3 * 3072 * 768 + 2 * 12 * 64 * 768 + 768 + 1)
+
+
+def test_a_variadic_all_reduce_is_counted_whole():
+    assert hlo.collectives(VARIADIC) == [("all-reduce", VARIADIC_BYTES, 4)]
+    assert hlo.wire_bytes(VARIADIC) == VARIADIC_BYTES
+    assert hlo.all_reduce_group(VARIADIC) == 4
+    # Beside instructions that stand alone, each is counted once.
+    text = "\n".join([
+        VARIADIC,
+        "  %psum.9 = f32[768,30522]{0,1:T(8,128)} all-reduce(%fusion.200), "
+        "channel_id=1, replica_groups={{0,1,2,3}}, to_apply=%add",
+        "  %get-tuple-element.4 = f32[768]{0:T(1024)S(1)} "
+        "get-tuple-element(%all-reduce.1), index=3"])
+    assert hlo.wire_bytes(text) == VARIADIC_BYTES + 4 * 768 * 30522
+
+
+@pytest.mark.parametrize("replica_groups,spans", [
+    ("{{0,1,2,3}}", True), ("[1,4]<=[4]", True),
+    ("{{0,1},{2,3}}", False), ("[2,2]<=[4]", False)])
+def test_a_step_whose_all_reduces_are_all_variadic(replica_groups, spans):
+    """``all_reduce_spans_world`` rests on every all-reduce of the step,
+    the variadic ones too: a correct step that has no other passes, and
+    one that reduces over half the world does not."""
+    import jax
+
+    text = VARIADIC.replace("{{0,1,2,3}}", replica_groups)
+    batch = jax.numpy.zeros((4, 2))
+    system = types.SimpleNamespace(n_chips=4, mean_rank=1.5, hlo_text=text,
+                                   batch=(batch,))
+    cell = types.SimpleNamespace(config={"loss_tolerance": {"abs": 0.002}})
+    got = check.verdict(cell, system, [3.0, 2.9, 2.8], [2.0], [3.0] * 3,
+                        set(), on_tpu=False)
+    assert got["all_reduce_spans_world"] == {
+        "value": 0 if spans else 2, "limit": 0, "ok": spans}

@@ -113,6 +113,35 @@ def test_end_to_end_metrics_and_the_share_of_four_chip_cells():
     assert len(four) <= max(1, len(BENCH["workloads"]) // 4)
 
 
+def test_rehearsal_takes_a_cell_by_its_files_or_by_its_name(capsys):
+    """``aot_rehearsal.py --config --traffic --chips`` is for a cell that
+    has no entry yet; given an existing cell's files it resolves what the
+    name does, but the name and the metrics that list their cells."""
+    from benchmark import aot_rehearsal
+
+    (named,) = aot_rehearsal.resolve(["bert_base_s512"])
+    (by_files,) = aot_rehearsal.resolve([
+        "--config", os.path.join(REPO, "benchmark/configs/bert_base.json"),
+        "--traffic", os.path.join(REPO, "benchmark/traffic/seq512_bs16.json"),
+        "--chips", "1"])
+    assert named == spec.load_cell("bert_base_s512")
+    assert by_files.name == "bert_base.seq512_bs16"
+    for field in ("chips", "config_name", "config", "traffic_name",
+                  "traffic", "family", "end_to_end"):
+        assert getattr(by_files, field) == getattr(named, field), field
+    assert {m["name"] for m in by_files.per_layer} == {
+        m["name"] for m in BENCH["per_layer"] if "workloads" not in m}
+    # No argument: every cell of BENCHMARK.json, in its order.
+    assert [c.name for c in aot_rehearsal.resolve([])] == CELLS
+    for bad in (["--config", "x.json"], ["bert_base_s512", "--chips", "1"]):
+        with pytest.raises(SystemExit):
+            aot_rehearsal.resolve(bad)
+    assert "go together" in capsys.readouterr().err
+    with pytest.raises(spec.SpecError, match="missing file"):
+        aot_rehearsal.resolve(["--config", "no_such.json", "--traffic",
+                               "no_such.json", "--chips", "1"])
+
+
 def test_peaks_name_their_source_and_an_unknown_kind_is_an_error():
     with open(os.path.join(REPO, "benchmark", "peaks.json")) as f:
         peaks = json.load(f)

@@ -1,8 +1,10 @@
 """The harness end to end at tiny sizes on the CPU (``tiny_cells.py``):
 set-up, warm-up, the window, the reference and the last line's keys, on
 one device and on four virtual ones, with the pallas kernel interpreted,
-with the sharded int8 update and with a scan-fused step. Each runs in a
-process of its own: the harness calls ``hvd.init`` for its own world."""
+with the sharded int8 update and with a scan-fused step; then with the
+timed path broken underneath the harness, which has to say so. Each runs
+in a process of its own: the harness calls ``hvd.init`` for its own
+world."""
 
 import json
 import os
@@ -15,14 +17,14 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
 
 
-def _run(which, chips):
+def _run(which, chips, *fault):
     env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     env.pop("JAX_COMPILATION_CACHE_DIR", None)
     env.pop("HVD_NUMERICS", None)  # the default a user gets
     proc = subprocess.run(
         [sys.executable, os.path.join(HERE, "tiny_cells.py"), which,
-         str(chips)],
+         str(chips), *fault],
         capture_output=True, text=True, timeout=600, env=env, cwd=REPO)
     assert proc.returncode == 0, proc.stderr[-3000:]
     lines = [json.loads(x) for x in proc.stdout.splitlines()]
@@ -35,8 +37,9 @@ def _run(which, chips):
 def test_tiny_cell_runs_and_prints_the_result_line_last(which, chips):
     lines, stderr = _run(which, chips)
     result = lines[-1]
-    assert set(result) == {"correct", "attempted", "failed", "metrics",
-                           "device"}
+    # The numbers compared, each beside its limit, come last.
+    assert list(result) == ["correct", "attempted", "failed", "metrics",
+                            "device", "compared"]
     assert result["correct"] is True
     assert result["attempted"] > 0 and result["failed"] == 0
     assert set(result["metrics"]) == {"throughput_per_chip", "peak_hbm_gb",
@@ -50,7 +53,16 @@ def test_tiny_cell_runs_and_prints_the_result_line_last(which, chips):
                                 "count": chips, "memory_peak_bytes": 0}
 
     phases = {x["phase"]: x for x in lines[:-1]}
-    assert list(phases) == ["built", "measured", "checked"]
+    # The system is released before the reference begins: every array
+    # of its state deleted, its executable dropped, the batch kept (the
+    # reference reads it). XLA:CPU keeps no memory statistics, so the
+    # ``released`` line carries no bytes here.
+    assert list(phases) == ["built", "measured", "released",
+                            "reference_entered", "checked"]
+    assert phases["released"] == {"phase": "released"}
+    entered = phases["reference_entered"]
+    assert entered["state_leaves"] > 0 and entered["state_deleted"]
+    assert entered["compiled_dropped"] and not entered["batch_deleted"]
     built, checked = phases["built"], phases["checked"]
     assert built["mean_rank"] == (chips - 1) / 2
     assert built["item"] == ("images" if which == "resnet" else "tokens")
@@ -62,7 +74,42 @@ def test_tiny_cell_runs_and_prints_the_result_line_last(which, chips):
         checked["reference_losses"]) == 3
     assert all(checked["checks"].values()), checked["checks"]
     assert ("all_reduce_spans_world" in checked["checks"]) == (chips > 1)
+    assert list(result["compared"]) == list(checked["checks"])
+    said = [x for x in stderr.splitlines() if x.startswith("compared ")]
+    assert said == stderr.splitlines()[-len(said):]  # standard error's end
+    for line, (name, (value, limit)) in zip(said,
+                                            result["compared"].items()):
+        assert line == f"compared {name}: {value!r} limit {limit!r} ok"
+    assert result["compared"]["reference"][1] == 0.02  # the cell's own
     # Whole windows of 4 steps were counted.
     assert result["attempted"] % 4 == 0
     if which == "flash":
         assert "flash_attention runs in interpret mode" in stderr
+
+
+@pytest.mark.parametrize("fault,chips,failed", [
+    # the losses repeat: none falls, and the reference's do
+    ("state_unchanged", 1, {"loss_fell", "reference"}),
+    # the mean over half of each chip's rows is another loss
+    ("half_batch", 4, {"reference"}),
+    # every chip follows its own gradient: steps 2 and 3 fall too fast
+    ("no_exchange", 4, {"reference"}),
+])
+def test_a_broken_timed_path_reads_not_correct(fault, chips, failed):
+    """The harness's look for a chip skipped, the rest of a run driven
+    with the fault planted underneath (``tiny_cells.FAULTS``): ``correct``
+    comes out false by the checks named, and the last lines of standard
+    error say which number passed which limit."""
+    lines, stderr = _run("bert", chips, fault)
+    result = lines[-1]
+    assert result["correct"] is False
+    checks = lines[-2]["checks"]
+    assert {name for name, ok in checks.items() if not ok} == failed
+    for name in failed:
+        value, limit = result["compared"][name]
+        assert f"compared {name}: {value!r} limit {limit!r} FAILED" \
+            in stderr.splitlines()[-len(checks):]
+    # By a wide margin at this size, not by rounding.
+    if "reference" in failed:
+        value, limit = result["compared"]["reference"]
+        assert value > 3 * limit

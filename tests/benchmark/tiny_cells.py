@@ -1,8 +1,16 @@
 """Tiny cells that only the tests can reach: the harness end to end on
 the CPU (pallas kernels interpreted, virtual devices for several chips).
-Run as ``python tiny_cells.py <resnet|bert|flash> <chips>``; prints what
-``benchmark/run.py`` would, the result object last. Not a benchmark: a
-time from here is never a device metric."""
+Run as ``python tiny_cells.py <resnet|bert|flash> <chips> [fault]``;
+prints what ``benchmark/run.py`` would, the result object last, and one
+line of its own, ``reference_entered``, that says what was left of the
+system when the reference began. A ``fault`` (``FAULTS``) breaks the
+timed path underneath the harness, which has to say ``correct: false``.
+Not a benchmark: a time from here is never a device metric.
+
+Given a workload of ``BENCHMARK.json`` in place of a tiny cell's name
+(``tiny_cells.py bert_base_s512 1 half_batch <seed> <seconds>``) it
+plants the fault under that cell at its own size, on the chip only: how
+``PERF.md``'s readings of the faults were taken."""
 
 import json
 import os
@@ -44,20 +52,100 @@ CELLS = {
 }
 
 
-def main(which: str, chips: int) -> int:
-    from benchmark.harness import loop, spec
+class _StateUnchanged:
+    """A step that returns its state as it got it, with the loss of the
+    real step (which runs on copies, since it donates)."""
 
-    config, traffic = CELLS[which]
-    bench = spec.load_benchmark()
-    cell = spec.Cell(name="tiny", chips=chips, config_name="tiny",
-                     config=config, traffic_name="tiny", traffic=traffic,
-                     end_to_end=tuple(bench["end_to_end"]),
-                     per_layer=tuple(bench["per_layer"]))
-    result = loop.run_cell(cell, seed=0, seconds=0.3, trace=False,
-                           t_start=T_START, require_tpu=False)
+    def __init__(self, compiled):
+        self.compiled = compiled
+        self.memory_analysis = compiled.memory_analysis
+
+    def __call__(self, *args):
+        import jax
+
+        state, batch = args[:3], args[3:]
+        *_, loss = self.compiled(*jax.tree.map(lambda a: a.copy(), state),
+                                 *batch)
+        return (*state, loss)
+
+
+def _half_batch(loss_fn):
+    """Half of each chip's rows left out, the mean taken over the rest."""
+    import jax
+
+    def broken_loss(model, params, extra, batch):
+        return loss_fn(model, params, extra, jax.tree.map(
+            lambda a: a[:a.shape[0] // 2], batch))
+
+    return broken_loss
+
+
+FAULTS = ("state_unchanged", "half_batch", "no_exchange")
+
+
+def main(which: str, chips: int, fault: str = "", seed: int = 0,
+         seconds: float = 0.3) -> int:
+    import jax
+
+    from benchmark.harness import check, loop, spec, step
+
+    reference_losses = check.reference_losses
+
+    def spy(cell, reference, system, device):
+        leaves = jax.tree.leaves(system.state)
+        loop.log(phase="reference_entered", state_leaves=len(leaves),
+                 state_deleted=all(x.is_deleted() for x in leaves),
+                 compiled_dropped=system.compiled is None,
+                 batch_deleted=any(x.is_deleted() for x in system.batch))
+        return reference_losses(cell, reference, system, device)
+
+    check.reference_losses = spy
+    if fault == "state_unchanged":
+        build = step.build
+
+        def build_broken(*args):
+            system = build(*args)
+            system.compiled = _StateUnchanged(system.compiled)
+            return system
+
+        step.build = build_broken
+    elif fault == "no_exchange":
+        # Every chip updates with its own gradient; the loss's own
+        # all-reduce stays, as it would in a step that lost the other.
+        import horovod_tpu.jax as hvd_jax
+
+        hvd_jax.DistributedOptimizer = lambda opt, **_: opt
+    elif fault == "half_batch":
+        load_module = spec.load_module
+
+        def load_broken(kind, name):
+            module = load_module(kind, name)
+            if kind == "families":
+                module.loss_fn = _half_batch(module.loss_fn)
+            return module
+
+        spec.load_module = load_broken
+    elif fault:
+        raise SystemExit(f"fault {fault!r}: want one of {FAULTS}")
+
+    if which in CELLS:
+        config, traffic = CELLS[which]
+        bench = spec.load_benchmark()
+        cell = spec.Cell(name="tiny", chips=chips, config_name="tiny",
+                         config=config, traffic_name="tiny", traffic=traffic,
+                         end_to_end=tuple(bench["end_to_end"]),
+                         per_layer=tuple(bench["per_layer"]))
+    else:
+        from horovod_tpu.common.compile_cache import enable_compile_cache
+
+        cell = spec.load_cell(which)
+        enable_compile_cache()  # as run.py: the cell's step is there
+    result = loop.run_cell(cell, seed=seed, seconds=seconds, trace=False,
+                           t_start=T_START, require_tpu=which not in CELLS)
     print(json.dumps(result), flush=True)
     return 0
 
 
 if __name__ == "__main__":
-    sys.exit(main(sys.argv[1], int(sys.argv[2])))
+    sys.exit(main(sys.argv[1], int(sys.argv[2]), *sys.argv[3:4],
+                  *map(int, sys.argv[4:5]), *map(float, sys.argv[5:6])))
