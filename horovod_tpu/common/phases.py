@@ -33,13 +33,27 @@ PHASES = ("hvd_pack", "hvd_allreduce", "hvd_unpack", "hvd_numerics",
 KERNELS = ("flash_fwd_bhsd", "flash_dq_bwd_bhsd", "flash_dkv_bwd_bhsd",
            "xent_fwd", "xent_dx", "xent_dw")
 
+#: What a model's own layers issue, where a device trace should tell the
+#: parts of one layer apart (``scope(name)``): the expert layer of
+#: ``parallel/moe.py`` (``moe_route``: router scores, softmax, top-k and
+#: the weights; ``moe_dispatch``: counting the assignments, laying them
+#: out by expert and gathering the rows; ``moe_experts``: the grouped
+#: products over the experts held, forward and backward; ``moe_combine``:
+#: weighting the rows and adding them back; ``moe_shared``: the shared
+#: expert) and the attention of ``models/decoder.py`` (``attn_rope``: the
+#: rotary positions; ``attn_gate``: the gate on the heads' output). Kept
+#: apart from ``PHASES``, which is the framework's own vocabulary and
+#: which readers hold a copy of.
+MODEL_SCOPES = ("moe_route", "moe_dispatch", "moe_experts", "moe_combine",
+                "moe_shared", "attn_rope", "attn_gate")
+
 #: Stamped on every op of a ``hvd.jax.jit`` step as the frontend
 #: attribute ``hvd_phases``. jax's persistent compile cache keys on the
 #: program without its debug info, so a program that differs from a
 #: cached one only in names would be served the cached executable, old
 #: names and all; the attribute is in the key. Bump it with ``PHASES``,
-#: ``KERNELS`` or a move of where a name is emitted.
-VOCABULARY_VERSION = "1"
+#: ``KERNELS``, ``MODEL_SCOPES`` or a move of where a name is emitted.
+VOCABULARY_VERSION = "2"
 
 
 def phase(name: str):
@@ -47,6 +61,14 @@ def phase(name: str):
     if name not in PHASES:
         raise ValueError(f"unknown phase {name!r}: the vocabulary is "
                          f"{PHASES} (horovod_tpu/common/phases.py)")
+    return jax.named_scope(name)
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name of :data:`MODEL_SCOPES`."""
+    if name not in MODEL_SCOPES:
+        raise ValueError(f"unknown scope {name!r}: the model scopes are "
+                         f"{MODEL_SCOPES} (horovod_tpu/common/phases.py)")
     return jax.named_scope(name)
 
 

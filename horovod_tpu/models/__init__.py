@@ -11,7 +11,10 @@ models of reference README.md:45-50), the 2-layer MNIST convnet
 (examples/tensorflow_mnist.py:30-63), word2vec skip-gram
 (examples/tensorflow_word2vec.py), and a BERT-style transformer encoder (the
 tensor-fusion stress config of BASELINE.json) with pluggable attention so the
-long-context paths in :mod:`horovod_tpu.parallel` can drop in.
+long-context paths in :mod:`horovod_tpu.parallel` can drop in; and a causal
+decoder built from per-layer specs (:mod:`horovod_tpu.models.decoder`: full
+and window attention over grouped key-value heads, head counts by layer,
+rotary positions, gated heads, dense and sparse-expert SwiGLU MLPs).
 
 All models default to bfloat16 compute with float32 parameters — the MXU's
 native mixed precision.
@@ -33,6 +36,12 @@ from horovod_tpu.models.transformer import (  # noqa: F401
     TransformerConfig,
     TransformerLM,
     BertBase,
+)
+from horovod_tpu.models.decoder import (  # noqa: F401
+    Decoder,
+    DecoderConfig,
+    LayerSpec,
+    RopeSpec,
 )
 
 _REGISTRY = {

@@ -22,6 +22,15 @@ dK/dV (grid over K tiles, streaming Q), per the flash backward recurrence:
     dq_i = Σ_j ds_ij · k_j · scale
     dk_j = Σ_i ds_ij · q_i · scale
 
+``causal=True, window=w`` keeps, for query i, the keys i-w < j <= i (a
+sliding window): the innermost grid axis then covers only the k (or q)
+blocks the band touches, so blocks outside it are skipped, not masked.
+Grouped key-value heads: ``k`` and ``v`` may carry fewer heads than ``q``
+(query head h reads key-value head h // group); the dK/dV kernel then
+walks the group's query heads in its innermost axis and sums them in its
+f32 accumulators. With ``window=None`` and equal heads the kernels trace
+the program they traced before either existed (``tests/test_flash_window.py``).
+
 Plugs in anywhere the model zoo accepts an ``attention_fn``
 (:class:`horovod_tpu.models.TransformerConfig`) and composes with sequence
 parallelism: inside :func:`horovod_tpu.parallel.ulysses_attention` it
@@ -68,13 +77,59 @@ def _mm(a, b, precision):
                    preferred_element_type=jnp.float32)
 
 
-def _mask_block(sblk, qi, ki, block_q, block_k):
-    """Causal mask for one (block_q, block_k) logits tile."""
+def _mask_block(sblk, qi, ki, block_q, block_k, window=None):
+    """Causal (with ``window``: banded) mask for one (block_q, block_k)
+    logits tile."""
     q_pos = qi * block_q + jax.lax.broadcasted_iota(
         jnp.int32, sblk.shape, 0)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
         jnp.int32, sblk.shape, 1)
-    return jnp.where(q_pos >= k_pos, sblk, NEG_INF)
+    if window is None:
+        return jnp.where(q_pos >= k_pos, sblk, NEG_INF)
+    return jnp.where((q_pos >= k_pos) & (q_pos - k_pos < window), sblk,
+                     NEG_INF)
+
+
+def _max(a, b):
+    return max(a, b) if isinstance(a, int) else jnp.maximum(a, b)
+
+
+def _min(a, b):
+    return min(a, b) if isinstance(a, int) else jnp.minimum(a, b)
+
+
+class _Band:
+    """Which blocks a grid row visits: arithmetic the index maps and the
+    kernels share, on python ints and on traced indices alike. Row ``i``
+    of the forward and dQ grids is a q block and visits k blocks
+    ``k_first(i) .. k_last(i)``; row ``i`` of the dK/dV grid is a k block
+    and visits q blocks ``q_first(i) .. q_last(i)``. Without a window the
+    innermost axis spans every block and the causal ones outside are
+    clamped and skipped; with one it spans ``n_k`` (``n_q``) blocks from
+    the first visible, the most any row needs."""
+
+    def __init__(self, window, s, block_q, block_k):
+        self.window, self.bq, self.bk = window, block_q, block_k
+        self.nq, self.nk = s // block_q, s // block_k
+        self.n_k, self.n_q = self.nk, self.nq
+        if window is not None:
+            self.n_k = max(self.k_last(i) - self.k_first(i) + 1
+                           for i in range(self.nq))
+            self.n_q = max(self.q_last(i) - self.q_first(i) + 1
+                           for i in range(self.nk))
+
+    def k_first(self, i):
+        return _max(i * self.bq - (self.window - 1), 0) // self.bk
+
+    def k_last(self, i):
+        return (i * self.bq + self.bq - 1) // self.bk
+
+    def q_first(self, i):
+        return (i * self.bk) // self.bq
+
+    def q_last(self, i):
+        return _min((i * self.bk + self.bk + self.window - 2) // self.bq,
+                    self.nq - 1)
 
 
 # ---------------------------------------------------------------------------
@@ -85,17 +140,21 @@ def _mask_block(sblk, qi, ki, block_q, block_k):
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 *, causal: bool, scale: float, nk: int,
-                block_q: int, block_k: int):
+                block_q: int, block_k: int, band: _Band):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = pl.program_id(2)  # of the nk this row visits
+    window = band.window
+    ki = step if window is None else band.k_first(qi) + step
     precision = _precision(q_ref.dtype)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
+    # A banded row starts at its first visible block, so the causal test
+    # is the only one left: it also ends the row's shorter visits.
     visible = (qi * block_q + block_q > ki * block_k) if causal else True
 
     @pl.when(visible)
@@ -105,7 +164,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         vb = v_ref[0].astype(jnp.float32)
         sblk = _mm(q, kb.T, precision)  # (bq, bk) on the MXU
         if causal:
-            sblk = _mask_block(sblk, qi, ki, block_q, block_k)
+            sblk = _mask_block(sblk, qi, ki, block_q, block_k, window)
+        # A row whose keys in this block are all masked (a band's first
+        # block) leaves m at NEG_INF and p at 1; the first block with a
+        # visible key (the diagonal at the latest) rescales that by
+        # alpha = exp(NEG_INF - m) = 0.
         m_prev = m_ref[:, :1]
         l_prev = l_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(sblk, axis=1, keepdims=True))
@@ -116,7 +179,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         l = l_ref[:, :1]
         safe = jnp.where(l > 0, l, 1.0)
@@ -124,39 +187,55 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         lse_ref[0] = m_ref[:, :1] + jnp.log(safe)  # (bq, 1) lane
 
 
-def _kv_index(causal: bool, block_q: int, block_k: int):
+def _kv_index(causal: bool, band: _Band, group: int):
     """K/V index map for grids where the k tile is the innermost axis.
     For causal attention the index is clamped to the last visible k block
     of the current q block: pallas skips the HBM->VMEM copy when the
     block index repeats between grid steps, so fully-masked steps (whose
-    compute pl.when also skips) cost no memory traffic."""
-    if not causal:
-        return lambda b, i, j: (b, j, 0)
-    last = lambda i: (i * block_q + block_q - 1) // block_k  # noqa: E731
-    return lambda b, i, j: (b, jnp.minimum(j, last(i)), 0)
+    compute pl.when also skips) cost no memory traffic. A banded row
+    counts from its first visible block. Query head b reads key-value
+    head b // group."""
+    def index(b, i, j):
+        if group > 1:
+            b = b // group
+        if band.window is not None:
+            j = jnp.minimum(band.k_first(i) + j, band.k_last(i))
+        elif causal:
+            j = jnp.minimum(j, band.k_last(i))
+        return (b, j, 0)
+
+    return index
 
 
-def _q_index(causal: bool, block_q: int, block_k: int):
+def _q_index(causal: bool, band: _Band, group: int):
     """Q-side index map for the dK/dV grid (q tile innermost): clamped up
     to the first visible q block of the current k block (same
-    repeated-index DMA-skip trick as _kv_index)."""
-    if not causal:
-        return lambda b, i, j: (b, j, 0)
-    first = lambda i: (i * block_k) // block_q  # noqa: E731
-    return lambda b, i, j: (b, jnp.maximum(j, first(i)), 0)
+    repeated-index DMA-skip trick as _kv_index). The innermost axis walks
+    the ``group`` query heads of key-value head b one after the other."""
+    def index(b, i, j):
+        if group > 1:
+            b, j = b * group + j // band.n_q, j % band.n_q
+        if band.window is not None:
+            j = jnp.minimum(band.q_first(i) + j, band.q_last(i))
+        elif causal:
+            j = jnp.maximum(j, band.q_first(i))
+        return (b, j, 0)
+
+    return index
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "interpret"))
-def _fwd_bhsd(q, k, v, causal, block_q, block_k, interpret):
+                                             "interpret", "window"))
+def _fwd_bhsd(q, k, v, causal, block_q, block_k, interpret, window=None):
     bh, s, d = q.shape
-    nq, nk = s // block_q, s // block_k
+    band = _Band(window, s, block_q, block_k)
     kernel = functools.partial(_fwd_kernel, causal=causal, scale=d ** -0.5,
-                               nk=nk, block_q=block_q, block_k=block_k)
-    kv_idx = _kv_index(causal, block_q, block_k)
+                               nk=band.n_k, block_q=block_q,
+                               block_k=block_k, band=band)
+    kv_idx = _kv_index(causal, band, bh // k.shape[0])
     return pl.pallas_call(
         kernel,
-        grid=(bh, nq, nk),
+        grid=(bh, band.nq, band.n_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_idx),
@@ -188,12 +267,14 @@ def _fwd_bhsd(q, k, v, causal, block_q, block_k, interpret):
 
 def _dq_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, dq_ref,
                acc_ref, *, causal: bool, scale: float, nk: int,
-               block_q: int, block_k: int):
+               block_q: int, block_k: int, band: _Band):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = pl.program_id(2)
+    window = band.window
+    ki = step if window is None else band.k_first(qi) + step
     precision = _precision(q_ref.dtype)
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
@@ -207,30 +288,38 @@ def _dq_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, dq_ref,
         do = do_ref[0].astype(jnp.float32)
         sblk = _mm(q, kb.T, precision)
         if causal:
-            sblk = _mask_block(sblk, qi, ki, block_q, block_k)
+            sblk = _mask_block(sblk, qi, ki, block_q, block_k, window)
         p = jnp.exp(sblk - lse_ref[0])  # lse block is (bq, 1)
         dp = _mm(do, vb.T, precision)
         ds = p * (dp - delta_ref[0])
         acc_ref[...] += _mm(ds, kb, precision) * scale
 
-    @pl.when(ki == nk - 1)
+    @pl.when(step == nk - 1)
     def _finalize():
         dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
                 dk_ref, dv_ref, dk_acc, dv_acc, *, causal: bool,
-                scale: float, nq: int, block_q: int, block_k: int):
+                scale: float, nq: int, block_q: int, block_k: int,
+                band: _Band, group: int):
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)  # of group x nq: the heads, then their blocks
+    window = band.window
+    qi = step if group == 1 else step % nq
+    if window is not None:
+        qi = band.q_first(ki) + qi
     precision = _precision(q_ref.dtype)
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    visible = (qi * block_q + block_q > ki * block_k) if causal else True
+    if window is not None:  # the row starts at its first visible block
+        visible = qi <= band.q_last(ki)
+    else:
+        visible = (qi * block_q + block_q > ki * block_k) if causal else True
 
     @pl.when(visible)
     def _compute():
@@ -240,38 +329,40 @@ def _dkv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
         do = do_ref[0].astype(jnp.float32)
         sblk = _mm(q, kb.T, precision)
         if causal:
-            sblk = _mask_block(sblk, qi, ki, block_q, block_k)
+            sblk = _mask_block(sblk, qi, ki, block_q, block_k, window)
         p = jnp.exp(sblk - lse_ref[0])  # lse block is (bq, 1)
         dv_acc[...] += _mm(p.T, do, precision)
         dp = _mm(do, vb.T, precision)
         ds = p * (dp - delta_ref[0])
         dk_acc[...] += _mm(ds.T, q, precision)  # q already carries `scale`
 
-    @pl.when(qi == nq - 1)
+    @pl.when(step == group * nq - 1)
     def _finalize():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "interpret"))
-def _bwd_bhsd(q, k, v, lse, do, out, causal, block_q, block_k, interpret):
+                                             "interpret", "window"))
+def _bwd_bhsd(q, k, v, lse, do, out, causal, block_q, block_k, interpret,
+              window=None):
     bh, s, d = q.shape
-    nq, nk = s // block_q, s // block_k
+    band = _Band(window, s, block_q, block_k)
+    group = bh // k.shape[0]
     # Δ_i = do_i · o_i, a cheap row reduction XLA fuses on its own; keeps
     # the trailing unit lane dim the row-stat BlockSpecs need.
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1, keepdims=True)
 
     q_spec_i = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    k_spec_j = pl.BlockSpec((1, block_k, d),
-                            _kv_index(causal, block_q, block_k))
+    k_spec_j = pl.BlockSpec((1, block_k, d), _kv_index(causal, band, group))
     row_spec_i = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
 
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, causal=causal, scale=d ** -0.5,
-                          nk=nk, block_q=block_q, block_k=block_k),
-        grid=(bh, nq, nk),
+                          nk=band.n_k, block_q=block_q, block_k=block_k,
+                          band=band),
+        grid=(bh, band.nq, band.n_k),
         in_specs=[q_spec_i, k_spec_j, k_spec_j, row_spec_i, row_spec_i,
                   q_spec_i],
         out_specs=q_spec_i,
@@ -281,20 +372,22 @@ def _bwd_bhsd(q, k, v, lse, do, out, causal, block_q, block_k, interpret):
         name="flash_dq_bwd_bhsd",
     )(q, k, v, lse, delta, do)
 
-    # dK/dV: grid over K tiles, Q innermost.
-    q_idx = _q_index(causal, block_q, block_k)
+    # dK/dV: grid over K tiles of the key-value heads, Q innermost (the
+    # group's query heads one after the other).
+    q_idx = _q_index(causal, band, group)
     q_spec_j = pl.BlockSpec((1, block_q, d), q_idx)
     k_spec_i = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, i, 0))
     row_spec_j = pl.BlockSpec((1, block_q, 1), q_idx)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, causal=causal, scale=d ** -0.5,
-                          nq=nq, block_q=block_q, block_k=block_k),
-        grid=(bh, nk, nq),
+                          nq=band.n_q, block_q=block_q, block_k=block_k,
+                          band=band, group=group),
+        grid=(k.shape[0], band.nk, group * band.n_q),
         in_specs=[q_spec_j, k_spec_i, k_spec_i, row_spec_j, row_spec_j,
                   q_spec_j],
         out_specs=[k_spec_i, k_spec_i],
-        out_shape=[jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, s, d), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
@@ -307,23 +400,24 @@ def _bwd_bhsd(q, k, v, lse, do, out, causal, block_q, block_k, interpret):
 # custom_vjp core on (batch*heads, seq, head_dim) arrays
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash(q, k, v, causal, block_q, block_k, interpret):
-    out, _ = _fwd_bhsd(q, k, v, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash(q, k, v, causal, block_q, block_k, interpret, window):
+    out, _ = _fwd_bhsd(q, k, v, causal, block_q, block_k, interpret, window)
     return out
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret):
-    out, lse = _fwd_bhsd(q, k, v, causal, block_q, block_k, interpret)
+def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window):
+    out, lse = _fwd_bhsd(q, k, v, causal, block_q, block_k, interpret,
+                         window)
     # Residuals are O(s·d) + O(s): inputs, output, and the softmax row
     # statistics — never the (s × s) probabilities.
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, res, do):
+def _flash_bwd(causal, block_q, block_k, interpret, window, res, do):
     q, k, v, out, lse = res
     return _bwd_bhsd(q, k, v, lse, do, out, causal, block_q, block_k,
-                     interpret)
+                     interpret, window)
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
@@ -356,10 +450,14 @@ def _auto_block(s: int, cap: int = 512) -> int:
 
 def flash_attention(q, k, v, bias=None, causal: bool = False,
                     block_q: int | None = None, block_k: int | None = None,
-                    interpret: bool | None = None):
+                    interpret: bool | None = None,
+                    window: int | None = None):
     """Exact attention, flash-style, differentiable. Shapes
     (batch, seq, heads, head_dim) — the model zoo's ``attention_fn``
-    contract. ``bias`` is not supported by the kernel (use the stock
+    contract; ``k`` and ``v`` may carry a divisor of ``q``'s heads
+    (grouped key-value heads: query head h reads head h // group).
+    ``window`` (with ``causal``) keeps the keys i - window < j <= i of
+    query i. ``bias`` is not supported by the kernel (use the stock
     attention for biased variants). Block sizes default to
     :func:`_auto_block`; explicit block sizes must divide ``seq``."""
     if bias is not None:
@@ -367,6 +465,16 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
             "flash_attention does not take a bias; use "
             "models.transformer.dot_product_attention for biased attention")
     b, s, h, d = q.shape
+    if k.shape != v.shape or h % k.shape[2]:
+        raise ValueError(
+            f"flash_attention: {h} query heads over key/value shapes "
+            f"{k.shape} / {v.shape}: want equal shapes whose head count "
+            "divides the query's")
+    if window is not None:
+        if not causal or window < 1:
+            raise ValueError("flash_attention: window goes with "
+                             "causal=True and is at least 1")
+        window = None if window >= s else int(window)
     block_q = _auto_block(s) if block_q is None else min(block_q, s)
     block_k = _auto_block(s) if block_k is None else min(block_k, s)
     if s % block_q or s % block_k:
@@ -381,10 +489,10 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
             f"or the whole sequence ({s}) to compile for the TPU")
 
     def to_bhsd(t):
-        return jnp.transpose(t, (0, 2, 1, 3)).reshape(b * h, s, d)
+        return jnp.transpose(t, (0, 2, 1, 3)).reshape(-1, s, d)
 
     out = _flash(to_bhsd(q), to_bhsd(k), to_bhsd(v), causal,
-                 block_q, block_k, interpret)
+                 block_q, block_k, interpret, window)
     return jnp.transpose(out.reshape(b, h, s, d), (0, 2, 1, 3))
 
 
