@@ -6,9 +6,18 @@ The hot op of transformer training. XLA's stock attention materializes the
 so HBM traffic is O(s·d) instead of O(s²) and VMEM residency is bounded by
 the block sizes regardless of sequence length — the standard flash
 formulation (Dao et al.), written for the MXU: accumulation in f32, block
-sizes default to 512 (a multiple of the 128-wide systolic tile — measured
-~2× faster than 128-blocks on a v5e at s=2048-8192, and 2.6× faster than
-the stock attention at s=4096 fwd+bwd).
+sizes default to 512 (a multiple of the 128-wide systolic tile; on a v5e
+at d = 64 the forward takes 0.98 µs for a 512 × 512 tile of scores and
+2.3 µs for the same area in 256-blocks: PERF.md, PR 28), the forward's q
+block to 1,024 rows where no window cuts its rows short.
+
+The forward's row statistics are whole (block_q, 128) tiles that the k
+loop reads and writes as they are: the running maximum equal along its
+lanes, the running sum as one partial sum a lane, added up once a row.
+Reading one column of such a tile and broadcasting a column back at
+every k step, as this kernel did before PR 28, relaid the tile out lane
+by lane and cost more than the step's two matrix products (2.2–2.4
+against 1.0–1.3 µs a tile on a v5e, at head sizes 64 and 128).
 
 Training works end-to-end: :func:`flash_attention` carries a
 ``jax.custom_vjp`` whose backward recomputes attention probabilities from
@@ -28,8 +37,9 @@ blocks the band touches, so blocks outside it are skipped, not masked.
 Grouped key-value heads: ``k`` and ``v`` may carry fewer heads than ``q``
 (query head h reads key-value head h // group); the dK/dV kernel then
 walks the group's query heads in its innermost axis and sums them in its
-f32 accumulators. With ``window=None`` and equal heads the kernels trace
-the program they traced before either existed (``tests/test_flash_window.py``).
+f32 accumulators. The two backward kernels trace the program they traced
+before PR 28 changed the forward's (``tests/test_flash_window.py`` pins
+its hash): they are that change's control.
 
 Plugs in anywhere the model zoo accepts an ``attention_fn``
 (:class:`horovod_tpu.models.TransformerConfig`) and composes with sequence
@@ -63,7 +73,7 @@ from jax.experimental.pallas import tpu as pltpu
 from horovod_tpu.ops.pallas_mode import resolve_interpret
 
 NEG_INF = -1e30
-_STAT = 128  # lane width for the (block_q, 128) row-stat scratch tiles
+_LANES = 128  # of a vector register: the width of the row-stat tiles
 
 
 def _precision(dtype):
@@ -138,6 +148,25 @@ class _Band:
 # scratch accumulators.
 # ---------------------------------------------------------------------------
 
+def _stat_lanes(block_k: int) -> int:
+    """Width of the forward's row-stat tiles: one vector register's lanes
+    where the k block is made of whole ones, else the k block's own."""
+    return block_k if block_k % _LANES else _LANES
+
+
+def _at_width(x, n: int):
+    """``x`` (rows, w), equal along its lanes, at width ``n``: whole
+    tiles side by side or a leading slice where that serves, since
+    either keeps the lane layout; a broadcast from one column is what
+    the k loop must not pay (module doc)."""
+    w = x.shape[1]
+    if n % w == 0:
+        return jnp.tile(x, (1, n // w)) if n > w else x
+    if n < w:
+        return x[:, :n]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], n))
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
                 *, causal: bool, scale: float, nk: int,
                 block_q: int, block_k: int, band: _Band):
@@ -169,19 +198,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         # block) leaves m at NEG_INF and p at 1; the first block with a
         # visible key (the diagonal at the latest) rescales that by
         # alpha = exp(NEG_INF - m) = 0.
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
+        # The running maximum is a whole (block_q, lanes) tile, equal
+        # along its lanes, read and written as it is; the running sum
+        # keeps one partial sum a lane, added up once a row in _finalize.
+        lanes = m_ref.shape[1]
+        m_prev = m_ref[...]
         m_new = jnp.maximum(m_prev, jnp.max(sblk, axis=1, keepdims=True))
-        p = jnp.exp(sblk - m_new)
+        p = jnp.exp(sblk - _at_width(m_new, block_k))
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + _mm(p, vb, precision)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        l_new = l_ref[...] * alpha
+        for j in range(0, block_k, lanes):
+            l_new += p[:, j:j + lanes]
+        l_ref[...] = l_new
+        acc_ref[...] = (acc_ref[...] * _at_width(alpha, acc_ref.shape[1])
+                        + _mm(p, vb, precision))
+        m_ref[...] = m_new
 
     @pl.when(step == nk - 1)
     def _finalize():
-        l = l_ref[:, :1]
+        l = jnp.sum(l_ref[...], axis=1, keepdims=True)
         safe = jnp.where(l > 0, l, 1.0)
         o_ref[0] = (acc_ref[...] / safe).astype(o_ref.dtype)
         lse_ref[0] = m_ref[:, :1] + jnp.log(safe)  # (bq, 1) lane
@@ -233,6 +268,7 @@ def _fwd_bhsd(q, k, v, causal, block_q, block_k, interpret, window=None):
                                nk=band.n_k, block_q=block_q,
                                block_k=block_k, band=band)
     kv_idx = _kv_index(causal, band, bh // k.shape[0])
+    stat = pltpu.VMEM((block_q, _stat_lanes(block_k)), jnp.float32)
     return pl.pallas_call(
         kernel,
         grid=(bh, band.nq, band.n_k),
@@ -252,9 +288,7 @@ def _fwd_bhsd(q, k, v, causal, block_q, block_k, interpret, window=None):
             jax.ShapeDtypeStruct((bh, s, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, d), jnp.float32),
-            pltpu.VMEM((block_q, _STAT), jnp.float32),
-            pltpu.VMEM((block_q, _STAT), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32), stat, stat,
         ],
         interpret=interpret,
         name="flash_fwd_bhsd",
@@ -400,21 +434,25 @@ def _bwd_bhsd(q, k, v, lse, do, out, causal, block_q, block_k, interpret,
 # custom_vjp core on (batch*heads, seq, head_dim) arrays
 # ---------------------------------------------------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, block_q, block_k, interpret, window):
-    out, _ = _fwd_bhsd(q, k, v, causal, block_q, block_k, interpret, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, block_q, block_k, interpret, window,
+           fwd_block_q):
+    out, _ = _fwd_bhsd(q, k, v, causal, fwd_block_q, block_k, interpret,
+                       window)
     return out
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window):
-    out, lse = _fwd_bhsd(q, k, v, causal, block_q, block_k, interpret,
+def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, window,
+               fwd_block_q):
+    out, lse = _fwd_bhsd(q, k, v, causal, fwd_block_q, block_k, interpret,
                          window)
     # Residuals are O(s·d) + O(s): inputs, output, and the softmax row
     # statistics — never the (s × s) probabilities.
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_k, interpret, window, res, do):
+def _flash_bwd(causal, block_q, block_k, interpret, window, fwd_block_q,
+               res, do):
     q, k, v, out, lse = res
     return _bwd_bhsd(q, k, v, lse, do, out, causal, block_q, block_k,
                      interpret, window)
@@ -459,7 +497,9 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
     ``window`` (with ``causal``) keeps the keys i - window < j <= i of
     query i. ``bias`` is not supported by the kernel (use the stock
     attention for biased variants). Block sizes default to
-    :func:`_auto_block`; explicit block sizes must divide ``seq``."""
+    :func:`_auto_block` (the forward's q block, without a window, to
+    twice its cap); explicit block sizes must divide ``seq`` and hold for
+    all three kernels."""
     if bias is not None:
         raise NotImplementedError(
             "flash_attention does not take a bias; use "
@@ -475,7 +515,15 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
             raise ValueError("flash_attention: window goes with "
                              "causal=True and is at least 1")
         window = None if window >= s else int(window)
-    block_q = _auto_block(s) if block_q is None else min(block_q, s)
+    # The forward alone takes a q block of up to 1,024 rows where none is
+    # asked for and no window cuts the row short: its time a tile falls
+    # with the rows a k tile serves (PERF.md, PR 28), and a band two
+    # blocks wide would only visit more of what it masks.
+    if block_q is None:
+        block_q = _auto_block(s)
+        fwd_block_q = block_q if window is not None else _auto_block(s, 1024)
+    else:
+        block_q = fwd_block_q = min(block_q, s)
     block_k = _auto_block(s) if block_k is None else min(block_k, s)
     if s % block_q or s % block_k:
         raise ValueError(
@@ -492,7 +540,7 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
         return jnp.transpose(t, (0, 2, 1, 3)).reshape(-1, s, d)
 
     out = _flash(to_bhsd(q), to_bhsd(k), to_bhsd(v), causal,
-                 block_q, block_k, interpret, window)
+                 block_q, block_k, interpret, window, fwd_block_q)
     return jnp.transpose(out.reshape(b, h, s, d), (0, 2, 1, 3))
 
 

@@ -4,13 +4,15 @@
 
 Compiles every pallas kernel of this package with ``interpret=False`` at
 the shapes the models use and compares it with its plain-XLA reference:
-flash attention forward and both backward kernels against
-``models.transformer`` attention in f32 (the reference at full f32 matmul
-precision — the TPU default would round it to bf16), and the fused
-LM-head cross-entropy against the chunked XLA scan. Tolerances are the
-ones ``tests/test_flash_attention.py`` uses in interpret mode. Exits
-nonzero on the first mismatch, or off a TPU. The CPU tier runs the same
-checks at toy shapes in interpret mode (tests/test_flash_attention.py).
+flash attention forward and both backward kernels against masked softmax
+in f32 (the reference at full f32 matmul precision — the TPU default
+would round it to bf16), with f32 inputs and with bf16 ones, and the
+fused LM-head cross-entropy against the chunked XLA scan. The f32
+tolerances are the ones ``tests/test_flash_attention.py`` uses in
+interpret mode; bf16 inputs are held to a share of each result's own
+scale, as the fused loss is. Exits nonzero on the first mismatch, or off
+a TPU. The CPU tier runs the same checks at toy shapes in interpret mode
+(tests/test_flash_attention.py).
 """
 
 from __future__ import annotations
@@ -21,19 +23,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from horovod_tpu.models.transformer import (
-    causal_attention,
-    dot_product_attention,
-)
 from horovod_tpu.ops.chunked_loss import (
     chunked_softmax_cross_entropy,
     fused_softmax_cross_entropy,
 )
 from horovod_tpu.ops.flash_attention import flash_attention
 
-# (batch, seq, heads, head_dim, causal): BERT-base's attention, and a
-# long causal sequence.
-FLASH_SHAPES = ((8, 512, 12, 64, False), (2, 2048, 12, 64, True))
+# (batch, seq, heads, head_dim, causal, window, key-value heads):
+# BERT-base's attention, a long causal sequence, and a sliding-window
+# layer of the decoder cell (72 query heads over 8).
+FLASH_SHAPES = ((8, 512, 12, 64, False, None, 12),
+                (2, 2048, 12, 64, True, None, 12),
+                (1, 8192, 72, 128, True, 512, 8))
+# Worst error of a bf16 run as a share of the result's largest entry:
+# the output and the probabilities round to eight bits of mantissa.
+BF16_SHARE = 2e-2
 # (tokens, hidden, vocab): BERT-base bs8 x seq512 into its LM head.
 LOSS_SHAPE = (4096, 768, 30522)
 
@@ -42,29 +46,72 @@ def _value_and_grads(fn, **kw):
     return jax.jit(jax.value_and_grad(fn, argnums=(0, 1, 2), **kw))
 
 
-def check_flash(b, s, h, d, causal, interpret=False, **blocks):
+def plain_attention(q, k, v, causal, window=None):
+    """Masked softmax attention one query head at a time, so that the
+    scores held at once are (batch, s, s) and not (batch, heads, s, s);
+    query head h reads key-value head h // group."""
+    s, h, d = q.shape[1:]
+    i = jnp.arange(s)[:, None]
+    j = jnp.arange(s)[None, :]
+    keep = (j <= i) if causal else jnp.ones((s, s), bool)
+    if window is not None:
+        keep &= i - j < window
+
+    @jax.checkpoint
+    def head(qkv):
+        qh, kh, vh = qkv
+        scores = jnp.einsum("bqd,bkd->bqk", qh, kh) * d ** -0.5
+        probs = jax.nn.softmax(jnp.where(keep, scores, -jnp.inf), -1)
+        return jnp.einsum("bqk,bkd->bqd", probs, vh)
+
+    by_head = [jnp.moveaxis(t, 2, 0) for t in (q, k, v)]
+    by_head[1:] = [jnp.repeat(t, h // k.shape[2], axis=0)
+                   for t in by_head[1:]]
+    return jnp.moveaxis(jax.lax.map(head, tuple(by_head)), 0, 2)
+
+
+def check_flash(b, s, h, d, causal, window=None, kv_heads=None,
+                dtype=jnp.float32, interpret=False, **blocks):
+    """Forward and the three gradients against :func:`plain_attention`;
+    returns each result's worst error as a share of its largest entry."""
+    kv_heads = kv_heads or h
     rng = np.random.RandomState(0)
-    q, k, v = (jnp.asarray(rng.randn(b, s, h, d), jnp.float32)
-               for _ in range(3))
-    ref_fn = causal_attention if causal else dot_product_attention
+    q, k, v = (jnp.asarray(rng.randn(b, s, heads, d), dtype)
+               for heads in (h, kv_heads, kv_heads))
 
     def flash(q, k, v):
-        o = flash_attention(q, k, v, causal=causal, interpret=interpret,
-                            **blocks)
+        o = flash_attention(q, k, v, causal=causal, window=window,
+                            interpret=interpret,
+                            **blocks).astype(jnp.float32)
         return jnp.sum(o * o), o
 
     def ref(q, k, v):
         with jax.default_matmul_precision("highest"):
-            o = ref_fn(q, k, v)
+            o = plain_attention(*(t.astype(jnp.float32) for t in (q, k, v)),
+                                causal, window)
         return jnp.sum(o * o), o
 
     (_, out), grads = _value_and_grads(flash, has_aux=True)(q, k, v)
     (_, out_ref), grads_ref = _value_and_grads(ref, has_aux=True)(q, k, v)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(out_ref),
-                               rtol=2e-4, atol=2e-5)
-    for got, want, name in zip(grads, grads_ref, "qkv"):
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-3, atol=2e-4, err_msg=f"d{name}")
+    # f32: element by element at the interpret-mode tests' tolerances;
+    # dk and dv of grouped heads sum ``group`` heads' gradients, each
+    # held to the absolute tolerance, so the sum to ``group`` times it.
+    group = h // kv_heads
+    tolerances = {"out": (2e-4, 2e-5), "dq": (2e-3, 2e-4),
+                  "dk": (2e-3, 2e-4 * group), "dv": (2e-3, 2e-4 * group)}
+    shares = {}
+    for got, want, name in zip((out,) + grads, (out_ref,) + grads_ref,
+                               tolerances):
+        got, want = (np.asarray(a, np.float32) for a in (got, want))
+        shares[name] = float(np.abs(got - want).max() / np.abs(want).max())
+        if dtype == jnp.float32:
+            rtol, atol = tolerances[name]
+            np.testing.assert_allclose(got, want, rtol=rtol, atol=atol,
+                                       err_msg=name)
+        else:
+            assert shares[name] < BF16_SHARE, (
+                f"{name}: max error {shares[name]:.2e} of its scale")
+    return shares
 
 
 def check_fused_loss(n, hidden, vocab, interpret=False, **blocks):
@@ -100,9 +147,12 @@ def main() -> int:
               file=sys.stderr)
         return 1
     for shape in FLASH_SHAPES:
-        check_flash(*shape)
-        print(f"flash_attention fwd+bwd {shape}: compiled, matches f32 "
-              f"reference ({dev.device_kind})", flush=True)
+        for dtype in (jnp.float32, jnp.bfloat16):
+            shares = check_flash(*shape, dtype=dtype)
+            worst = ", ".join(f"{n} {e:.1e}" for n, e in shares.items())
+            print(f"flash_attention fwd+bwd {shape} {dtype.__name__}: "
+                  f"compiled, matches the f32 reference (worst error by "
+                  f"its scale: {worst}; {dev.device_kind})", flush=True)
     check_fused_loss(*LOSS_SHAPE)
     print(f"fused_softmax_cross_entropy fwd+bwd {LOSS_SHAPE}: compiled, "
           f"matches the chunked scan ({dev.device_kind})", flush=True)
