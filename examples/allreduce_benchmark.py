@@ -116,9 +116,8 @@ def _decompose_timeline(path, n_ops):
               f"{os.environ.get('HVD_CACHE_CAPACITY', 'default')}): "
               + " | ".join(parts))
 
-    # Per-SPAN medians over the canonical engine-path phases, for
-    # perfwatch/perf.jsonl trending (QUEUE/NEGOTIATE/MEMCPY/ALLREDUCE/
-    # MEMCPY_OUT — MEMCPY folds the submit snapshot and the fusion
+    # Per-SPAN medians over the canonical engine-path phases
+    # (QUEUE/NEGOTIATE/MEMCPY/ALLREDUCE/MEMCPY_OUT — MEMCPY folds the submit snapshot and the fusion
     # copy-in together: the copy-in cost a tensor pays on the way to the
     # wire; the zero-copy pool/donation work moves exactly these two).
     def _median(names):
@@ -498,8 +497,7 @@ def main():
                     help="additionally print ONE machine-readable JSON "
                          "line with the sweep results (and, with "
                          "--decompose, the per-phase + negotiate "
-                         "cached/full split) — the engine-path analogue "
-                         "of bench.py's line, for tracking round-trip "
+                         "cached/full split), for tracking round-trip "
                          "latency across rounds")
     args = ap.parse_args()
 
@@ -566,7 +564,7 @@ def main():
     if args.compression and args.compression != "none":
         print("# note: --compression measures the ENGINE wire format "
               "(use --engine); the compiled-path policy rides "
-              "DistributedOptimizer / bench.py --compression")
+              "DistributedOptimizer(compression=...)")
     n = hvd.size()
     mesh = hvd.mesh()
     from horovod_tpu.ops.collectives import _hier_allreduce_active
@@ -591,8 +589,7 @@ def main():
         t0 = time.perf_counter()
         for _ in range(args.iters):
             out = ranked_allreduce(x)
-        # Real device->host fetch of a SLICED scalar (bench.py's
-        # barrier): fetching the whole buffer would bill a multi-MB
+        # Real device->host fetch of a SLICED scalar: fetching the whole buffer would bill a multi-MB
         # host transfer to the collective being measured.
         float(np.asarray(out[0]))
         dt = (time.perf_counter() - t0) / args.iters

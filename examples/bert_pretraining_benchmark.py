@@ -40,11 +40,10 @@ def main():
     ap.add_argument("--warmup", type=int, default=5)
     ap.add_argument("--steps-per-call", type=int, default=5,
                     help="steps fused into one dispatch via lax.scan "
-                         "(amortizes per-call host latency; see bench.py)")
+                         "(amortizes per-call host latency)")
     ap.add_argument("--unroll", type=int, default=5,
                     help="scan unroll factor: lets XLA software-pipeline "
-                         "across step boundaries (bench.py --unroll; "
-                         "measured +3.8%% tokens/sec on BERT-base here)")
+                         "across step boundaries")
     ap.add_argument("--bf16", action="store_true", default=True)
     ap.add_argument("--remat", action="store_true",
                     help="checkpoint each layer (HBM for FLOPs)")
@@ -169,8 +168,7 @@ def main():
     # ops.axis_rank() inside the step).
     step_key = jax.random.fold_in(jax.random.PRNGKey(1), hvd.rank())
     # AOT compile: reuse the executable AND read XLA's own FLOP count so
-    # the printout carries MFU (cost analysis counts a scan body once —
-    # see bench.py for the on-chip verification of that invariant).
+    # the printout carries MFU (cost analysis counts a scan body once).
     flops_per_step = 0.0
     counted = 1  # scan steps cost_analysis holds (set with flops below)
     step_fn = step
@@ -183,8 +181,7 @@ def main():
             ca = ca[0] if ca else {}
         from horovod_tpu.utils.hardware import scan_cost_analysis_steps
 
-        # Scan body + peeled remainder each counted once (bench.py's
-        # on-chip-verified rule, shared via utils.hardware).
+        # Scan body + peeled remainder each counted once.
         counted = scan_cost_analysis_steps(spc, args.unroll)
         flops_per_step = float(ca.get("flops", 0.0)) / counted
     except Exception as exc:  # pragma: no cover
@@ -196,7 +193,7 @@ def main():
     for _ in range(ncalls_warm):
         params, opt_state, step_key, loss = step_fn(params, opt_state,
                                                     step_key, toks)
-    # Real device->host fetch (bench.py's barrier).
+    # Real device->host fetch: returns only once every step has run.
     float(np.asarray(loss))
 
     if args.profile:
@@ -224,7 +221,7 @@ def main():
     peak = peak_flops(jax.devices()[0])
     if peak and flops_per_step / step_time > peak:
         # Value was pre-divided by `counted`: recover one step's FLOPs as
-        # raw/spc (the same over-peak guard rescale as bench.py).
+        # raw/spc, where the count came out over the chip's peak.
         flops_per_step *= counted / spc
     mfu = flops_per_step / step_time / peak if peak and flops_per_step \
         else float("nan")

@@ -47,8 +47,7 @@ def main():
     import jax.numpy as jnp
 
     # Feed bf16: the model computes in bf16, and halving the host->device
-    # bytes matters wherever the feed link is the bottleneck (bench.py
-    # does the same).
+    # bytes matters wherever the feed link is the bottleneck.
     x = x.astype(jnp.bfloat16)
 
     trainer = hvd_keras.Trainer(

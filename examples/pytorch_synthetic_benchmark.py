@@ -2,7 +2,7 @@
 """Torch synthetic benchmark — img/sec through the async-engine allreduce
 path (reference: examples/pytorch_synthetic_benchmark.py). This measures
 the *host* engine (enqueue → fuse → XLA collective), the path torch
-training uses; compiled-in JAX training is benchmarked by bench.py.
+training uses; compiled-in JAX training is measured by benchmark/run.py.
 
 Run: PYTHONPATH=. python examples/pytorch_synthetic_benchmark.py \
          --num-iters 3 --model resnet18

@@ -37,8 +37,8 @@ import numpy as np
 
 def _timeit(fn, barrier, warmup=2, iters=8):
     """Timed window ending in ``barrier(out)`` — a real device->host
-    fetch (bench.py's barrier). The one timing convention for both the
-    allreduce and training measurements in this file."""
+    fetch. The one timing convention for both the allreduce and
+    training measurements in this file."""
     for _ in range(warmup):
         out = fn()
     barrier(out)
@@ -211,7 +211,8 @@ def _hier_sweep(args, world):
 
 def _train_throughput(args, n):
     """Synthetic training images/sec on the current n-chip world
-    (bench.py's methodology at sweep-friendly step counts)."""
+    (examples/jax_synthetic_benchmark.py's loop at sweep-friendly step
+    counts)."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -231,8 +232,7 @@ def _train_throughput(args, n):
     opt_state = opt.init(params)
 
     def loss_fn(p, bs, xx, yy, dk):
-        # Dropout models (vgg16/inceptionv3) need an rng; others ignore it
-        # (bench.py threads the same stream).
+        # Dropout models (vgg16/inceptionv3) need an rng; others ignore it.
         logits, mut = model.apply({"params": p, "batch_stats": bs}, xx,
                                   True, mutable=["batch_stats"],
                                   rngs={"dropout": dk})

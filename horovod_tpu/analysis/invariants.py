@@ -7,8 +7,8 @@ they must exit clean on the live tree — a rule that needs an allowlist
 to pass HEAD is mis-specified.
 
 Scanned surfaces: ``horovod_tpu/``, ``examples/``, and ``tests/`` (the
-worker scripts spawn real engine worlds), plus the two import-free
-entrypoints (``bench.py``, ``horovod_tpu/run.py``).
+worker scripts spawn real engine worlds), plus the import-free
+entrypoint (``horovod_tpu/run.py``).
 """
 
 from __future__ import annotations
@@ -589,16 +589,13 @@ def check_fault_sites(root: str) -> List[Finding]:
 def check_entrypoint_imports(root: str,
                              entrypoints: Optional[List[str]] = None
                              ) -> List[Finding]:
-    """``bench.py --help/--dry`` and ``run.py`` (the launcher) must not
-    import jax or any framework at module level: argparse errors must
-    never pay the multi-second import, and the launcher must survive on
-    hosts where the frameworks are absent. tests/test_bench_contract.py
-    proves the runtime behavior with a poisoned sys.path; this rule
-    fails the diff at analysis time instead of the subprocess tier."""
+    """``run.py`` (the launcher) must not import jax or any framework
+    at module level: argparse errors must never pay the multi-second
+    import, and the launcher must start on hosts where the frameworks
+    are absent."""
     findings = []
     stdlib = getattr(sys, "stdlib_module_names", frozenset())
-    for rel in entrypoints or ("bench.py",
-                               os.path.join("horovod_tpu", "run.py")):
+    for rel in entrypoints or (os.path.join("horovod_tpu", "run.py"),):
         path = os.path.join(root, rel)
         if not os.path.exists(path):
             findings.append(Finding(

@@ -75,8 +75,8 @@ RULE_CATALOG: Dict[str, str] = {
                    "host first and drain before returning",
     "lock-order": "lock acquisitions must follow the documented "
                   "hierarchy: engine lock > pool lock > telemetry locks",
-    "entrypoint-imports": "bench.py and run.py must stay import-free at "
-                          "module level (stdlib only)",
+    "entrypoint-imports": "run.py must stay import-free at module "
+                          "level (stdlib only)",
     "fault-site-registry": "every faultline site referenced in "
                            "tests/docs/specs must resolve to a declared "
                            "site+mode, and every declared site must be "

@@ -3,7 +3,7 @@
 The cache directory is part of every entry's key, so it has to be a
 fixed path: a directory built from a pid, the time or ``tempfile`` never
 hits. One rule, applied by the entry points that compile large programs
-(``bench.py``, ``chip_smoke.py``, the benchmark examples):
+(``chip_smoke.py``, the benchmark examples):
 
 - ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it on its own; nothing is
   set in code (the operator, or the machine image, owns the placement).

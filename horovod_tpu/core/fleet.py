@@ -30,10 +30,10 @@ to rank 0's ``/metrics``, and the live console
 The publisher is OFF by default: it starts from ``topology.init`` only
 when a fleet directory resolves (``HVD_FLEET_DIR``, or
 ``<HVD_ELASTIC_DIR>/fleet`` when the elastic plane is up) and
-``HVD_FLEET`` is not ``0``. ``bench.py`` sets neither, so the headline
-path never pays for this plane. The compiled/AOT hot path is untouched
-either way — snapshots read the registry, they never instrument the
-step.
+``HVD_FLEET`` is not ``0``. ``benchmark/run.py`` sets neither, so the
+measured path never pays for this plane. The compiled/AOT hot path is
+untouched either way — snapshots read the registry, they never
+instrument the step.
 """
 
 from __future__ import annotations

@@ -42,8 +42,7 @@ poisoning rank. Like ``HVD_CONSISTENCY_CHECKS``, the exchange assumes
 SPMD-symmetric synchronize order across processes (the standard
 collective-call contract).
 
-Knobs: ``HVD_NUMERICS=off|warn|halt`` (default **warn**; the bench
-headline sets ``off`` for its AOT window — bench.py), and
+Knobs: ``HVD_NUMERICS=off|warn|halt`` (default **warn**), and
 ``HVD_NUMERICS_EVERY`` (host check cadence in steps, default 50; the
 halt policy checks every step). Stdlib + numpy only on the observe
 path; jax is imported only where a collective actually runs.
@@ -52,8 +51,7 @@ Surfaces: ``hvd.numerics_report()``, the ``hvd_numerics_*`` metric
 family in every telemetry exposition (file, ``/metrics``,
 ``utils.stats --json``), ``/healthz`` (degrades on a recent
 ``nonfinite``/``diverged`` verdict), ``python -m
-horovod_tpu.utils.numerics <file|http://...>``, and the ``numerics``
-object in bench.py's JSON line.
+horovod_tpu.utils.numerics <file|http://...>``.
 """
 
 from __future__ import annotations
@@ -534,8 +532,8 @@ def report() -> dict:
 
 
 def compact() -> dict:
-    """Small summary for bench.py's one JSON line (post-window; nulls
-    when nothing was observed)."""
+    """Small summary for embedding in one JSON object (nulls when
+    nothing was observed)."""
     flat = tele.REGISTRY.flat()
     ring = flat.get("numerics.grad_norm") or {}
     with _lock:

@@ -26,15 +26,15 @@ when measurement is continuous, arxiv 1909.09756 §3):
   :func:`horovod_tpu.utils.xplane.hbm_json` into measured
   hbm_gb_per_step / membw_util (and MFU when
   :func:`set_flops_per_step` was told the program's cost) and appends
-  one JSON record to ``$HVD_PROFILE_DIR/perf.jsonl`` — the health log
-  ``utils/perfwatch`` gates against.
+  one JSON record to ``$HVD_PROFILE_DIR/perf.jsonl``, the run's health
+  log (one JSON object a line).
 - **Health** (:func:`health`): the ``/healthz`` payload the
   ``HVD_TELEMETRY_PORT`` endpoint serves (core/telemetry_http.py) —
   watchdog verdicts + last-step age.
 
-The bench.py AOT hot window stays uninstrumented: the sentinel only sees
-the per-call dispatch boundary (``_InstrumentedJit``) and post-window
-captures — never the inside of the compiled program.
+The AOT path (``lower().compile()``) stays uninstrumented: the sentinel
+only sees the per-call dispatch boundary (``_InstrumentedJit``) and
+post-window captures — never the inside of the compiled program.
 
 Knobs (all env): ``HVD_WATCHDOG`` (default on; 0 disables),
 ``HVD_WATCHDOG_FACTOR`` (default 3.0 × EWMA), ``HVD_WATCHDOG_P99_MULT``
@@ -416,8 +416,8 @@ class AutoCapture:
             "mfu": None,
             "gflops_per_step": None,
             # Latest host-visible training loss (Trainer epoch
-            # boundaries): the perfwatch trend table's convergence
-            # column. None when no loop reported one.
+            # boundaries): convergence beside throughput. None when
+            # no loop reported one.
             "final_loss": self._sentinel.last_loss,
             "error": active.get("error"),
         }
@@ -583,8 +583,8 @@ class Sentinel:
     def note_loss(self, loss):
         """Latest host-visible training loss (the Trainer reports it at
         epoch boundaries, where it is already a host float): auto-capture
-        perf.jsonl records carry it as ``final_loss`` so the perfwatch
-        trend table can show convergence next to throughput."""
+        perf.jsonl records carry it as ``final_loss``, so the log shows
+        convergence next to throughput."""
         try:
             self.last_loss = float(loss)
         except (TypeError, ValueError):

@@ -3,8 +3,8 @@ collectives ran, how big, how long, and who was late".
 
 The framework has three execution paths — the compiled SPMD hot path
 (hvd.jax.jit), the Python engine and the native C++ engine — and, before
-this module, three disconnected lenses on them (chrome timeline, xplane
-HBM tables, bench.py's JSON line). This registry is the common sink every
+this module, two disconnected lenses on them (chrome timeline, xplane
+HBM tables). This registry is the common sink every
 layer feeds (reference rationale: Horovod's production story leaned on
 exactly this instrumentation — timeline + stall/straggler analysis,
 arxiv 1802.05799 §5; step-time/traffic accounting is what turns a
@@ -609,8 +609,8 @@ def report() -> str:
 
 
 def compact() -> dict:
-    """Small flat summary for embedding in bench.py's single JSON line:
-    nonzero counters, ring counts, and per-process straggler waits."""
+    """Small flat summary for embedding in one JSON object (the flight
+    recorder's dump): nonzero counters, ring counts, and per-process straggler waits."""
     out: Dict[str, object] = {}
     for name, val in REGISTRY.flat().items():
         if isinstance(val, (int, float)) and val:

@@ -283,7 +283,7 @@ def is_dir_mode(raw: str) -> bool:
     the reference allowed arbitrary trace filenames, and treating a
     legacy ``HOROVOD_TIMELINE=/tmp/hvd.trace`` leftover as a directory
     would crash engine init on makedirs. The ONE definition of the
-    rule — the launcher and bench.py classify through this too, so
+    rule — the launcher classifies through this too, so
     where children write always matches where the mergers look."""
     if os.path.isdir(raw):
         return True

@@ -555,8 +555,8 @@ class _InstrumentedJit:
     itself is async) into the telemetry ring buffer for the compiled path.
     Everything else (``lower``, ``trace``, AOT compilation, ...) delegates
     to the wrapped ``jax.jit`` object, so the perf-critical AOT path
-    (``fn.lower(...).compile()`` — bench.py) bypasses instrumentation
-    entirely. Overhead: two clock reads + a few deque appends/compares
+    (``fn.lower(...).compile()`` — ``benchmark/run.py``) bypasses
+    instrumentation entirely. Overhead: two clock reads + a few deque appends/compares
     per dispatch (ring + the sentinel watchdog), ~1-2 µs against a
     ≥50 µs dispatch."""
 
@@ -574,8 +574,7 @@ class _InstrumentedJit:
         # Performance sentinel: the per-call dispatch boundary is the
         # compiled path's watchdog signal (a recompile shows up as one
         # giant dispatch). The AOT path (lower().compile()) bypasses
-        # this wrapper entirely — bench.py's hot window stays
-        # uninstrumented.
+        # this wrapper entirely.
         _sentinel.observe_step(dt, origin="jax.dispatch")
         return out
 

@@ -3,8 +3,7 @@
 arxiv 1909.09756, shows reduced-precision communication is load-bearing
 for pod-scale efficiency).
 
-The bench is measured nearly bandwidth-bound (membw_util 0.876), so the
-next multi-chip scaling win must cut BYTES on the wire. A cast to bf16
+The next multi-chip scaling win must cut BYTES on the wire. A cast to bf16
 halves them; block-scaled int8 quarters them: per ``block`` contiguous
 elements the wire carries ``round(x * qmax / amax)`` at 1 byte/element
 plus ONE f32 scale — 4/(1 + 4/block) ≈ 3.9x fewer bytes than f32 at the

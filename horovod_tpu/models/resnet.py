@@ -17,54 +17,9 @@ from functools import partial
 from typing import Any, Callable, Sequence, Tuple
 
 import flax.linen as nn
-import jax
 import jax.numpy as jnp
-from jax.ad_checkpoint import checkpoint_name
 
 ModuleDef = Any
-
-# Tag under which conv outputs are offered to a remat policy — transparent
-# when no remat is active. Wrap the whole loss/apply in
-# ``jax.checkpoint(fn, policy=conv_saves_policy())`` to store ONLY conv
-# outputs for backward and recompute the BN/ReLU/residual-join chains from
-# them instead of round-tripping them through HBM: a bandwidth-bound
-# ResNet step (bs32 measures ~65% idle MXU) trades spare compute for
-# removed traffic, with numerics identical — the recompute is the same
-# deterministic elementwise function of the same saved values.
-CONV_SAVE_NAME = "conv_out"
-# Tag on the big post-norm/activation elementwise intermediates — the
-# candidates for DROPPING from the saved set (see act_drop_policy).
-ACT_DROP_NAME = "block_act"
-
-
-def conv_saves_policy():
-    """Remat policy: keep ONLY conv outputs, drop and recompute
-    everything else. MEASURED NEGATIVE on the v5e headline bench
-    (docs/benchmarks.md): dropping the BN mean/var reductions forces
-    full re-reads of conv outputs to recompute them — traffic went UP
-    7.81 → 10.82 GB/step. Kept for the record; use
-    :func:`act_drop_policy` instead."""
-    return jax.checkpoint_policies.save_only_these_names(CONV_SAVE_NAME)
-
-
-def act_drop_policy():
-    """Remat policy: save everything stock autodiff would EXCEPT the
-    tagged post-BN/ReLU/join activations; those are recomputed in
-    backward from the (still saved) conv outputs and BN statistics —
-    elementwise recompute, no extra reduction passes. Function-level
-    ``jax.checkpoint`` keeps flax param paths untouched (``nn.remat``
-    would rename module scopes, making checkpoints
-    non-interchangeable)."""
-    return jax.checkpoint_policies.save_anything_except_these_names(
-        ACT_DROP_NAME)
-
-
-def _name_conv(y):
-    return checkpoint_name(y, CONV_SAVE_NAME)
-
-
-def _name_act(y):
-    return checkpoint_name(y, ACT_DROP_NAME)
 
 
 class BottleneckBlock(nn.Module):
@@ -79,23 +34,19 @@ class BottleneckBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         residual = x
-        y = _name_conv(self.conv(self.filters, (1, 1))(x))
-        y = _name_act(self.norm()(y))
-        y = _name_act(self.act(y))
-        y = _name_conv(self.conv(self.filters, (3, 3), self.strides)(y))
-        y = _name_act(self.norm()(y))
-        y = _name_act(self.act(y))
-        y = _name_conv(self.conv(self.filters * 4, (1, 1))(y))
+        y = self.act(self.norm()(self.conv(self.filters, (1, 1))(x)))
+        y = self.act(self.norm()(
+            self.conv(self.filters, (3, 3), self.strides)(y)))
         # Zero-init of the last BN scale: each block starts as identity,
         # which is what lets large-batch distributed training (the regime
         # this framework exists for) hold accuracy at high learning rates.
-        y = _name_act(self.norm(scale_init=nn.initializers.zeros)(y))
+        y = self.norm(scale_init=nn.initializers.zeros)(
+            self.conv(self.filters * 4, (1, 1))(y))
         if residual.shape != y.shape:
-            residual = _name_conv(
+            residual = self.norm(name="norm_proj")(
                 self.conv(self.filters * 4, (1, 1), self.strides,
                           name="conv_proj")(residual))
-            residual = _name_act(self.norm(name="norm_proj")(residual))
-        return _name_act(self.act(residual + y))
+        return self.act(residual + y)
 
 
 class BasicBlock(nn.Module):
@@ -110,17 +61,15 @@ class BasicBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         residual = x
-        y = _name_conv(self.conv(self.filters, (3, 3), self.strides)(x))
-        y = _name_act(self.norm()(y))
-        y = _name_act(self.act(y))
-        y = _name_conv(self.conv(self.filters, (3, 3))(y))
-        y = _name_act(self.norm(scale_init=nn.initializers.zeros)(y))
+        y = self.act(self.norm()(
+            self.conv(self.filters, (3, 3), self.strides)(x)))
+        y = self.norm(scale_init=nn.initializers.zeros)(
+            self.conv(self.filters, (3, 3))(y))
         if residual.shape != y.shape:
-            residual = _name_conv(
+            residual = self.norm(name="norm_proj")(
                 self.conv(self.filters, (1, 1), self.strides,
                           name="conv_proj")(residual))
-            residual = _name_act(self.norm(name="norm_proj")(residual))
-        return _name_act(self.act(residual + y))
+        return self.act(residual + y)
 
 
 def space_to_depth_2x2(x):
@@ -160,11 +109,6 @@ class ResNet(nn.Module):
     :func:`conv7_kernel_to_s2d`; the standard TPU ResNet stem). Same
     function class, different parameterization — checkpoints are not
     interchangeable between stems.
-
-    Conv outputs carry the :data:`CONV_SAVE_NAME` checkpoint tag: wrap
-    the loss in ``jax.checkpoint(fn, policy=conv_saves_policy())`` to
-    recompute the BN/ReLU/join chains in backward instead of storing
-    them (see :func:`conv_saves_policy`).
     """
 
     stage_sizes: Sequence[int]
@@ -198,8 +142,7 @@ class ResNet(nn.Module):
                         name="conv_init")(x)
         else:
             x = conv(self.num_filters, (7, 7), (2, 2), name="conv_init")(x)
-        x = _name_act(norm(name="bn_init")(x))
-        x = _name_act(self.act(x))
+        x = self.act(norm(name="bn_init")(x))
         x = nn.max_pool(x, (3, 3), strides=(2, 2), padding="SAME")
         for i, block_count in enumerate(self.stage_sizes):
             for j in range(block_count):

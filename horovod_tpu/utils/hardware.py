@@ -2,7 +2,7 @@
 
 Used by the benchmarks to report MFU (model FLOPs utilization) and HBM
 bandwidth pressure next to raw throughput, so a physically impossible
-number is self-evident (the honesty contract of bench.py). Public
+number is self-evident. Public
 figures: TPU v4 275 TFLOPS bf16 / 1.23 TB/s; v5e 197 / 0.82; v5p 459 /
 2.77; v6e (Trillium) 918 / 1.64.
 """
@@ -57,7 +57,7 @@ def scan_cost_analysis_steps(steps_per_call: int, unroll: int) -> int:
     """How many *steps* XLA's cost analysis counts for a
     ``lax.scan(body, length=steps_per_call, unroll=unroll)`` program.
 
-    The while body is counted ONCE (verified on chip, see bench.py) and
+    The while body is counted ONCE (verified on chip) and
     holds ``unroll`` steps; jax peels a remainder of
     ``steps_per_call % unroll`` steps outside the loop (also counted
     once). When ``unroll >= steps_per_call`` there is no while loop at

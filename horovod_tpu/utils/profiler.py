@@ -4,8 +4,7 @@ The reference times device-side work with CUDA events feeding the
 timeline (reference: horovod/common/operations.cc:671-695 RECORD_EVENT /
 WAIT_FOR_EVENTS); on TPU the compiled step is one fused XLA program, so
 device-side spans come from the XLA profiler instead. This module makes
-that a one-liner (and ``python bench.py --profile DIR`` a one-command
-capture):
+that a one-liner:
 
     from horovod_tpu.utils import profiler
     with profiler.profile("/tmp/prof"):
@@ -49,8 +48,8 @@ def capture(fn: Callable, *args, logdir: str, iters: int = 3,
             barrier: Optional[Callable] = None) -> str:
     """Run ``fn(*args)`` ``iters`` times under the profiler and return the
     logdir. ``barrier`` (default: numpy-fetch the last output's first
-    leaf) forces execution to finish inside the trace window (the same
-    device->host fetch barrier bench.py ends its windows with).
+    leaf) forces execution to finish inside the trace window (a
+    device->host fetch cannot return before the last step has run).
 
     Raises :class:`CaptureError` when the capture lands no new
     ``*.xplane.pb`` under ``logdir`` (profiler plugin missing, a

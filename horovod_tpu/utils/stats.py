@@ -10,7 +10,8 @@
 - an ``http://host:port`` (or full ``.../metrics``) URL served by
   ``HVD_TELEMETRY_PORT`` (:mod:`horovod_tpu.core.telemetry_http`) —
   fetched and rendered exactly like the file (``--watch`` re-fetches);
-- an XLA profiler capture directory (``bench.py --profile DIR``) — the
+- an XLA profiler capture directory (``utils.profiler``'s, or the
+  sentinel's auto-capture) — the
   machine-readable HBM attribution (:func:`horovod_tpu.utils.xplane.
   hbm_json`, the same data ``xplane --hbm --json`` emits), so bench
   tooling never re-parses the human table;

@@ -4,8 +4,8 @@ The reference answers "where did the time go" with its chrome-tracing
 timeline of host-side engine phases (horovod/common/timeline.cc); on TPU
 the compiled step is one fused XLA program, so the equivalent question is
 answered from the XLA profiler's device plane. This module turns a
-``jax.profiler.trace`` capture (``bench.py --profile DIR``,
-``examples/bert_pretraining_benchmark.py --profile DIR``) into the
+``jax.profiler.trace`` capture
+(``examples/bert_pretraining_benchmark.py --profile DIR``) into the
 per-op-category breakdown used in docs/benchmarks.md:
 
     python -m horovod_tpu.utils.xplane /tmp/prof [--top 30]

@@ -10,7 +10,7 @@ import os
 # in the suite, hundreds of heterogeneous jit programs share one process
 # and every first-call compile would read as a dispatch anomaly — dumps
 # and warnings all over the output. Tests that exercise the watchdog
-# re-enable it explicitly (tests/test_perfwatch.py resets the
+# re-enable it explicitly (tests/test_sentinel.py resets the
 # singleton). setdefault: an operator's explicit env still wins.
 os.environ.setdefault("HVD_WATCHDOG", "0")
 

@@ -77,7 +77,6 @@ def _mini_root(tmp_path):
                     native)
     shutil.copy(os.path.join(REPO, "horovod_tpu", "utils", "stats.py"),
                 utils)
-    shutil.copy(os.path.join(REPO, "bench.py"), tmp_path)
     shutil.copy(os.path.join(REPO, "horovod_tpu", "run.py"),
                 tmp_path / "horovod_tpu")
     return str(tmp_path)
@@ -560,7 +559,8 @@ class BufferPool:
 
 def test_rule_entrypoint_imports_catches_framework_import(tmp_path):
     root = _mini_root(tmp_path)
-    _edit(root, "bench.py", "import argparse", "import argparse\nimport jax")
+    _edit(root, os.path.join("horovod_tpu", "run.py"), "import argparse",
+          "import argparse\nimport jax")
     findings = invariants.check_entrypoint_imports(root)
     assert any(f.rule == "entrypoint-imports" and "'jax'" in f.message
                for f in findings), findings

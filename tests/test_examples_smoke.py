@@ -10,6 +10,7 @@ elsewhere (the Trainer/engine paths of the mnist variants and the
 frontend suites).
 """
 
+import json
 import os
 import subprocess
 import sys
@@ -28,13 +29,12 @@ _CASES = {
         "--model", "mnist_mlp", "--batch-size", "8",
         "--num-warmup-batches", "1", "--num-batches-per-iter", "2",
         "--num-iters", "1", "--image-size", "8"],
-    # Dropout model through the full bench step: pins the rngs plumbing
+    # Dropout model through the full step: pins the rngs plumbing
     # (vgg/inception need a dropout stream; mnist/resnet ignore it).
     "jax_synthetic_benchmark.py --model vgg16": [
         "--model", "vgg16", "--batch-size", "2",
         "--num-warmup-batches", "1", "--num-batches-per-iter", "1",
-        "--num-iters", "1", "--image-size", "32", "--steps-per-call",
-        "1"],
+        "--num-iters", "1", "--image-size", "32"],
     "bert_pretraining_benchmark.py": [
         "--layers", "1", "--hidden", "64", "--heads", "2", "--vocab",
         "128", "--seq-len", "32", "--batch-size", "2", "--steps", "2",
@@ -86,17 +86,9 @@ _TIMEOUTS = {"keras_imagenet_resnet50.py": 900,
 _SLOW = {"keras_imagenet_resnet50.py", "pytorch_imagenet_resnet50.py"}
 
 
-@pytest.mark.parametrize("case", sorted(_CASES), ids=lambda s: s)
-def test_example_runs(case):
-    script = case.split()[0]  # keys may carry a variant suffix for ids
-    slow_on = (os.environ.get("HVD_SLOW_TESTS", "").lower()
-               not in ("", "0", "false", "off"))
-    if script in _SLOW and not slow_on:
-        pytest.skip("multi-minute XLA:CPU ResNet-50 case; set "
-                    "HVD_SLOW_TESTS=1 to run (core paths covered by the "
-                    "frontend suites)")
+def _run_example(script, args, timeout=420):
+    """The user's invocation of one example on the virtual CPU mesh."""
     env = dict(os.environ)
-    # These children run on the virtual CPU mesh.
     env["PYTHONPATH"] = _REPO + os.pathsep + env.get("PYTHONPATH", "")
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
@@ -108,10 +100,46 @@ def test_example_runs(case):
     env.setdefault("JAX_COMPILATION_CACHE_DIR",
                    os.path.join(_REPO, ".cache", "jax"))
     env.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "10")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "examples", script),
-         *_CASES[case]],
-        capture_output=True, text=True, timeout=_TIMEOUTS.get(case, 420),
-        env=env, cwd=_REPO)
+    return subprocess.run(
+        [sys.executable, os.path.join(_REPO, "examples", script), *args],
+        capture_output=True, text=True, timeout=timeout, env=env, cwd=_REPO)
+
+
+@pytest.mark.parametrize("case", sorted(_CASES), ids=lambda s: s)
+def test_example_runs(case):
+    script = case.split()[0]  # keys may carry a variant suffix for ids
+    slow_on = (os.environ.get("HVD_SLOW_TESTS", "").lower()
+               not in ("", "0", "false", "off"))
+    if script in _SLOW and not slow_on:
+        pytest.skip("multi-minute XLA:CPU ResNet-50 case; set "
+                    "HVD_SLOW_TESTS=1 to run (core paths covered by the "
+                    "frontend suites)")
+    proc = _run_example(script, _CASES[case], _TIMEOUTS.get(case, 420))
     assert proc.returncode == 0, (
         f"{script} failed:\n{proc.stdout[-2500:]}\n{proc.stderr[-1500:]}")
+
+
+def test_synthetic_benchmark_prints_one_result_line():
+    """The last stdout line of the synthetic benchmark is one JSON object
+    that says what was timed and names the device it ran on; the lines
+    before it are the reference's human-readable ones."""
+    proc = _run_example("jax_synthetic_benchmark.py",
+                        _CASES["jax_synthetic_benchmark.py"])
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
+    assert [ln for ln in lines if ln.startswith("{")] == lines[-1:]
+    rec = json.loads(lines[-1])
+    assert rec["unit"] == "images/sec/chip" and rec["value"] > 0
+    assert (rec["platform"], rec["n_devices"]) == ("cpu", 8)
+    assert rec["device_kind"]
+    assert any(ln.startswith("Img/sec per chip:") for ln in lines)
+
+
+def test_allreduce_benchmark_has_json_flag():
+    """The machine-readable ``--json`` surface of the engine-path sweep,
+    at the argparse level (its full run is the case above)."""
+    proc = _run_example("allreduce_benchmark.py", ["--help"])
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "--json" in proc.stdout
+    assert "--decompose" in proc.stdout
+    assert "--compression" in proc.stdout

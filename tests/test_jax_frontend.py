@@ -260,8 +260,8 @@ def test_state_storage_identity_when_off():
 
 def test_distributed_optimizer_fused_update_spmd(hvd):
     """fused_update=True inside the compiled SPMD step gives the same
-    trajectory as the default path (the profile-driven fast path for
-    bench.py; VERDICT r3 item 1)."""
+    trajectory as the default path (the profile-driven fast path every
+    benchmark cell takes; VERDICT r3 item 1)."""
     xs, ys = _toy_data()
     n = hj.size()
 

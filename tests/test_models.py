@@ -191,49 +191,6 @@ def test_space_to_depth_stem_is_exact_reparameterization():
                                rtol=1e-5, atol=1e-5)
 
 
-def test_resnet_remat_policies_bit_exact():
-    """Both traffic-removal remat policies (measured NEGATIVE on chip,
-    docs/benchmarks.md r5 — kept as opt-ins) are BIT-exact against
-    stock autodiff: the recompute is the same deterministic function of
-    the same saved values."""
-    from functools import partial
-
-    from horovod_tpu.models.resnet import (BottleneckBlock, ResNet,
-                                           act_drop_policy,
-                                           conv_saves_policy)
-
-    m = ResNet(stage_sizes=[1, 1], block_cls=BottleneckBlock,
-               num_classes=10, num_filters=8, dtype=jnp.float32)
-    x = jnp.asarray(np.random.RandomState(0).randn(2, 32, 32, 3),
-                    jnp.float32)
-    v = m.init(jax.random.PRNGKey(0), x, False)
-
-    def loss(params, bs):
-        out, mut = m.apply({"params": params, "batch_stats": bs}, x,
-                           True, mutable=["batch_stats"])
-        return out.sum(), mut["batch_stats"]
-
-    import jax.tree_util as jtu
-
-    (l1, bs1), g1 = jax.value_and_grad(loss, has_aux=True)(
-        v["params"], v["batch_stats"])
-    for policy in (act_drop_policy(), conv_saves_policy()):
-        (l2, bs2), g2 = jax.value_and_grad(
-            jax.checkpoint(loss, policy=policy), has_aux=True)(
-            v["params"], v["batch_stats"])
-        assert float(l1) == float(l2)
-        gd = jtu.tree_map(lambda a, b: float(jnp.abs(a - b).max()), g1, g2)
-        # Bit-exactness holds on chip (verified r5). XLA:CPU's current
-        # jaxlib fuses the rematerialized backward differently from stock
-        # autodiff — float32 reassociation noise in the last ulps — so off
-        # chip the pin is "same computation to a few ulps", not zero.
-        tol = 0.0 if jax.devices()[0].platform == "tpu" else 5e-7
-        assert max(jtu.tree_leaves(gd)) <= tol, gd
-        bd = jtu.tree_map(lambda a, b: float(jnp.abs(a - b).max()),
-                          bs1, bs2)
-        assert max(jtu.tree_leaves(bd)) <= tol, bd
-
-
 def test_inception_s2d_stem_is_exact_reparameterization():
     """The Inception stem's 3x3/s2 'VALID' conv computes EXACTLY as the
     2x2/s1 conv over space-to-depth input when the kernel is derived

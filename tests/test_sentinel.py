@@ -1,16 +1,12 @@
-"""The performance sentinel (core/sentinel.py) and the perf regression
-gate (utils/perfwatch.py): gate arithmetic pinned against a SYNTHETIC
-BENCH_r*.json history (tests/data/bench_history — made-up numbers in the
-shape a driver records; the repo root holds no history, where ``bench.py
---check`` reports ``skip``), watchdog anomaly semantics (fire-once, cooldown,
-attribution), flight-dump retention, the /metrics + /healthz endpoint,
-and the unified stats --json envelope."""
+"""The performance sentinel (core/sentinel.py): watchdog anomaly semantics
+(fire-once, cooldown, attribution), the periodic capture's ``perf.jsonl``
+records, flight-dump retention, the /metrics + /healthz endpoint, and the
+unified stats --json envelope."""
 
 import contextlib
 import io
 import json
 import os
-import re
 import time
 
 import numpy as np
@@ -18,11 +14,6 @@ import pytest
 
 from horovod_tpu.core import sentinel as sen
 from horovod_tpu.core import telemetry as tele
-from horovod_tpu.utils import perfwatch as pw
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-HISTORY = os.path.join(REPO, "tests", "data", "bench_history")
-R05 = os.path.join(HISTORY, "BENCH_r05.json")
 
 
 @pytest.fixture()
@@ -43,154 +34,6 @@ def fresh_sentinel(monkeypatch):
     yield make
     sen.reset_sentinel()
     tele.STRAGGLERS.reset()
-
-
-# ---------------------------------------------------------------------------
-# perfwatch: loading + gate arithmetic over the checked-in history
-# ---------------------------------------------------------------------------
-
-def test_perfwatch_is_stdlib_only():
-    """bench.py --check depends on this module staying import-light (the
-    --dry guard proves argparse paths never pay jax; the gate itself
-    must stay runnable on a CI box with no framework)."""
-    src = open(os.path.join(REPO, "horovod_tpu", "utils",
-                            "perfwatch.py")).read()
-    assert not re.search(r"^\s*(import|from)\s+(jax|numpy|tensorflow|"
-                         r"torch|horovod_tpu)\b", src, re.M), \
-        "perfwatch.py must stay stdlib-only"
-
-
-def test_load_history_fixtures():
-    hist = pw.load_history(HISTORY)
-    labels = [r["label"] for r in hist]
-    assert labels[:5] == ["r01", "r02", "r03", "r04", "r05"]
-    r05 = hist[labels.index("r05")]
-    assert r05["value"] == 1200.0
-    assert r05["hbm_gb_per_step"] == 8.0
-    # The recorded iteration spread (1194-1206 over median 1200).
-    assert r05["spread_frac"] == pytest.approx((1206 - 1194) / 1200.0)
-    # BASELINE.json is metadata-only today: no numeric record.
-    assert pw.load_record(os.path.join(REPO, "BASELINE.json")) is None
-    # The repo root carries no history: the gate has nothing to judge
-    # against and says so.
-    assert pw.load_history(REPO) == []
-    assert pw.gate(r05, pw.pick_reference([], r05))["status"] == "skip"
-
-
-def test_gate_passes_on_r05_against_history():
-    hist = pw.load_history(HISTORY)
-    cur = pw.load_record(R05)
-    ref = pw.pick_reference(hist, cur)
-    assert ref["label"] == "r05"  # newest same-metric record
-    result = pw.gate(cur, ref)
-    assert result["status"] == "pass", result
-    fields = {c["field"] for c in result["checks"]}
-    assert fields == {"value", "hbm_gb_per_step"}
-    # And an honest improvement (r05 vs r04) passes too.
-    r04 = next(r for r in hist if r["label"] == "r04")
-    assert pw.gate(cur, r04)["status"] == "pass"
-
-
-def test_gate_fails_on_doctored_img_per_sec_drop():
-    hist = pw.load_history(HISTORY)
-    cur = pw.load_record(R05)
-    cur["value"] = round(cur["value"] * 0.90, 2)  # -10%
-    result = pw.gate(cur, pw.pick_reference(hist, cur))
-    assert result["status"] == "fail"
-    bad = [c for c in result["checks"] if not c["ok"]]
-    assert [c["field"] for c in bad] == ["value"]
-    # The bound is noise-aware: spread (1.0%) below the 2% floor, so
-    # the floor rules -> reference * (1 - 0.02 * 1.5).
-    assert bad[0]["bound"] == pytest.approx(
-        1200.0 * (1 - pw.MIN_NOISE * pw.NOISE_MULT), abs=0.01)
-
-
-def test_gate_fails_on_hbm_traffic_creep():
-    hist = pw.load_history(HISTORY)
-    cur = pw.load_record(R05)
-    cur["hbm_gb_per_step"] = round(cur["hbm_gb_per_step"] * 1.10, 3)
-    result = pw.gate(cur, pw.pick_reference(hist, cur))
-    assert result["status"] == "fail"
-    bad = [c for c in result["checks"] if not c["ok"]]
-    assert [c["field"] for c in bad] == ["hbm_gb_per_step"]
-    assert bad[0]["bound"] == pytest.approx(8.0 * (1 + pw.HBM_TOL),
-                                            abs=1e-3)
-
-
-def test_gate_skips_cleanly():
-    # No history at all.
-    assert pw.gate({"value": 1.0}, None)["status"] == "skip"
-    # Metric mismatch: a vgg run must not gate against the resnet line.
-    hist = pw.load_history(HISTORY)
-    other = {"metric": "vgg16_train_images_per_sec_per_chip_bs32",
-             "value": 100.0}
-    assert pw.pick_reference(hist, other) is None
-    # Null fields skip their check, not the whole gate: a CPU record
-    # with no measured HBM still gates on throughput.
-    cur = pw.load_record(R05)
-    cur["hbm_gb_per_step"] = None
-    result = pw.gate(cur, pw.pick_reference(hist, cur))
-    assert result["status"] == "pass"
-    assert [c["field"] for c in result["checks"]] == ["value"]
-
-
-def test_perfwatch_cli_trend_and_check(tmp_path, capsys):
-    # Trend table over the checked-in history.
-    assert pw.main(["--history", HISTORY]) == 0
-    out = capsys.readouterr().out
-    assert "r05" in out and "1200" in out
-    # The byte-diet delta column (HBM diet round 2): hbm_gb_per_step
-    # movement is visible next to the headline Δ%.
-    assert "hbmΔ%" in out
-
-
-def test_trend_table_hbm_delta_column():
-    """The hbm delta tracks the previous non-null hbm record — a byte
-    cut shows negative, a creep positive, nulls pass through as '-'."""
-    recs = [
-        {"label": "r1", "value": 2900.0, "hbm_gb_per_step": 7.8},
-        {"label": "r2", "value": 2920.0, "hbm_gb_per_step": None},
-        {"label": "r3", "value": 2950.0, "hbm_gb_per_step": 5.85},
-    ]
-    table = pw.trend_table(recs)
-    rows = table.splitlines()
-    assert "hbmΔ%" in rows[0]
-    r2 = next(r for r in rows if r.startswith("r2"))
-    assert r2.rstrip().endswith("-")
-    r3 = next(r for r in rows if r.startswith("r3"))
-    # 5.85 vs 7.8 = -25.0%
-    assert "-25.0" in r3
-
-
-def test_perfwatch_cli_gate(tmp_path, capsys):
-    # A passing record file gates green...
-    good = tmp_path / "good.json"
-    good.write_text(json.dumps(
-        {"metric": "resnet50_train_images_per_sec_per_chip_bs32",
-         "value": 1202.0, "hbm_gb_per_step": 7.9, "spread_pct": 1.1}))
-    assert pw.main([str(good), "--history", HISTORY, "--check"]) == 0
-    # ...a doctored one exits 2 with the failing field named.
-    bad = tmp_path / "bad.json"
-    bad.write_text(json.dumps(
-        {"metric": "resnet50_train_images_per_sec_per_chip_bs32",
-         "value": 1080.0, "hbm_gb_per_step": 8.8}))
-    capsys.readouterr()
-    assert pw.main([str(bad), "--history", HISTORY, "--check"]) == 2
-    out = capsys.readouterr().out
-    assert "FAIL" in out and "hbm_gb_per_step" in out
-    # perf.jsonl loads line-per-record; the last record gates.
-    pj = tmp_path / "perf.jsonl"
-    pj.write_text(
-        json.dumps({"kind": "periodic", "hbm_gb_per_step": 7.5}) + "\n" +
-        json.dumps({"kind": "periodic", "hbm_gb_per_step": 9.9}) + "\n")
-    recs = pw.load_records(str(pj))
-    assert len(recs) == 2
-    assert pw.load_record(str(pj))["hbm_gb_per_step"] == 9.9
-    # Unnamed capture records gate against the log's EARLIER captures —
-    # never against the named bench history (pick_reference refuses the
-    # cross): 9.9 GB vs the log's own 7.5 GB is a creep -> exit 2.
-    assert pw.pick_reference(pw.load_history(HISTORY), recs[-1]) is None
-    assert pw.main([str(pj), "--history", HISTORY, "--check"]) == 2
 
 
 # ---------------------------------------------------------------------------
@@ -439,8 +282,6 @@ def test_autocapture_periodic_appends_perf_jsonl(hvd, tmp_path,
     assert rec["kind"] == "periodic" and rec["steps"] == 2
     assert rec["step_time_ms"] is not None
     assert os.path.isdir(rec["capture_dir"])
-    # The perf.jsonl schema is exactly what perfwatch loads.
-    assert pw.load_record(pj)["step_time_ms"] == rec["step_time_ms"]
 
 
 # ---------------------------------------------------------------------------
@@ -657,39 +498,6 @@ def test_launcher_exposes_telemetry_port_flag():
 # ---------------------------------------------------------------------------
 # Numerics observatory satellites (ISSUE 8): the convergence column
 # ---------------------------------------------------------------------------
-
-
-def test_trend_table_final_loss_column():
-    """perf.jsonl records carry final_loss (the sentinel stamps the
-    Trainer's last epoch loss); pre-numerics histories simply REFUSE the
-    column with '-' — never a crash, never a faked number — and the gate
-    never gates on it."""
-    recs = [
-        {"label": "c1", "value": 2900.0, "final_loss": 2.3456},
-        {"label": "c2", "value": 2920.0},  # pre-numerics history record
-    ]
-    table = pw.trend_table(recs)
-    rows = table.splitlines()
-    assert "loss" in rows[0]
-    assert "2.346" in next(r for r in rows if r.startswith("c1"))
-    assert "2.346" not in next(r for r in rows if r.startswith("c2"))
-    # The regression gate ignores the convergence column entirely: a
-    # loss-less reference vs a loss-carrying current still gates on
-    # throughput alone.
-    result = pw.gate({"value": 2920.0, "final_loss": 2.3},
-                     {"value": 2900.0, "label": "ref"})
-    assert result["status"] == "pass"
-    assert [c["field"] for c in result["checks"]] == ["value"]
-
-
-def test_normalize_carries_final_loss_from_perf_jsonl(tmp_path):
-    log = tmp_path / "perf.jsonl"
-    log.write_text(json.dumps({"value": 100.0, "metric": "m",
-                               "final_loss": 0.75}) + "\n"
-                   + json.dumps({"value": 101.0, "metric": "m"}) + "\n")
-    recs = pw.load_records(str(log))
-    assert recs[0]["final_loss"] == 0.75
-    assert recs[1]["final_loss"] is None
 
 
 def test_sentinel_note_loss_feeds_capture_records():

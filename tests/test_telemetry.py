@@ -357,8 +357,8 @@ def test_jit_dispatch_ring(hvd):
     d = _delta(before, _counters())
     assert d["jax.dispatches"] == 1
     assert tele.REGISTRY.ring("jax.dispatch_s").count == n0 + 1
-    # AOT surface still reachable through the wrapper (bench.py relies
-    # on .lower/.compile bypassing instrumentation).
+    # AOT surface still reachable through the wrapper (benchmark/run.py
+    # relies on .lower/.compile bypassing instrumentation).
     assert "all-reduce" in step.lower(x).compile().as_text()
 
 

@@ -369,17 +369,3 @@ def test_xplane_hbm_accounting_on_synthetic_capture(tmp_path):
         "f32[2,2]{1,0:T(8,128)} f32[4]{0:T(128)S(1)} bf16[8]{0}") == 32
     assert xp._op_root("%get-tuple-element.991 = ...") == "get-tuple-element"
     assert xp._op_root("%while.2 = (...) while(...)") == "while"
-
-
-def test_membw_plumbing_on_cpu():
-    """The bandwidth suite's math and jit plumbing (tiny arrays; the
-    bandwidth VALUE is only meaningful on the real chip)."""
-    from horovod_tpu.utils import membw
-
-    assert membw._slope_ms({1: 0.10, 2: 0.11, 4: 0.13}) == pytest.approx(10.0)
-    # CPU timing noise at toy sizes can produce any slope sign; assert
-    # the plumbing (keys, traffic accounting), not the bandwidth value.
-    r = membw.measure("copy", array_mb=1, iters=(2, 4), repeats=1)
-    assert isinstance(r["gbps"], float) and r["traffic_mb_per_iter"] == 2.0
-    r = membw.measure("triad", array_mb=1, iters=(2, 4), repeats=1)
-    assert isinstance(r["gbps"], float) and r["traffic_mb_per_iter"] == 3.0
