@@ -29,9 +29,12 @@ PHASES = ("hvd_pack", "hvd_allreduce", "hvd_unpack", "hvd_numerics",
 
 #: Pallas kernels' ``name=``. The flash names keep the ``_fwd_bhsd`` /
 #: ``_bwd_bhsd`` of the jitted functions round them, which readers of
-#: device traces already match.
+#: device traces already match. The fused backward kernel, which makes
+#: dq in the dK/dV kernel's pass, holds that kernel's name whole, so a
+#: reader that knows three flash kernels counts it as the dK/dV one and
+#: reads no dQ kernel where every layer took it.
 KERNELS = ("flash_fwd_bhsd", "flash_dq_bwd_bhsd", "flash_dkv_bwd_bhsd",
-           "xent_fwd", "xent_dx", "xent_dw")
+           "fused_flash_dkv_bwd_bhsd", "xent_fwd", "xent_dx", "xent_dw")
 
 #: What a model's own layers issue, where a device trace should tell the
 #: parts of one layer apart (``scope(name)``): the expert layer of
@@ -53,7 +56,7 @@ MODEL_SCOPES = ("moe_route", "moe_dispatch", "moe_experts", "moe_combine",
 #: cached one only in names would be served the cached executable, old
 #: names and all; the attribute is in the key. Bump it with ``PHASES``,
 #: ``KERNELS``, ``MODEL_SCOPES`` or a move of where a name is emitted.
-VOCABULARY_VERSION = "2"
+VOCABULARY_VERSION = "3"
 
 
 def phase(name: str):

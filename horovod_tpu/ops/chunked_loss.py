@@ -343,9 +343,11 @@ def _ce_bwd_call(h2d, kernel, bias, lab, lse, g, block_n, block_v,
     nn, nv = n // block_n, vpad // block_v
     inputs = (x, kernel_p, bias_p[None, :], labs, lsep, gpad)
     # Two kernels, each with a clean VMEM accumulator over its inner grid
-    # axis (the flash-attention dq/dkv split, ops/flash_attention.py:
-    # _dq_kernel/_dkv_kernel): a cross-OUTER-axis accumulator would need
-    # non-contiguous output-block revisits, which pallas does not give.
+    # axis (the split of ops/flash_attention.py's _dq_kernel/_dkv_kernel):
+    # a cross-OUTER-axis accumulator would need non-contiguous
+    # output-block revisits, which pallas does not give. The flash
+    # backward gets round that by keeping a whole head's dq in VMEM
+    # (_fused_bwd_kernel); not tried for dx here: no cell runs this.
     n_specs = [
         pl.BlockSpec((block_n, hdim), lambda i, j: (i, 0)),
         pl.BlockSpec((hdim, block_v), lambda i, j: (0, j)),

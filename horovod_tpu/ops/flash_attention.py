@@ -21,9 +21,8 @@ against 1.0–1.3 µs a tile on a v5e, at head sizes 64 and 128).
 
 Training works end-to-end: :func:`flash_attention` carries a
 ``jax.custom_vjp`` whose backward recomputes attention probabilities from
-the saved log-sum-exp row statistics (no (s × s) residuals), with one
-kernel producing dQ (grid over Q tiles, streaming K/V) and one producing
-dK/dV (grid over K tiles, streaming Q), per the flash backward recurrence:
+the saved log-sum-exp row statistics (no (s × s) residuals), per the
+flash backward recurrence:
 
     p_ij = exp(q_i·k_j·scale − lse_i)
     dv_j = Σ_i p_ij · do_i
@@ -31,15 +30,32 @@ dK/dV (grid over K tiles, streaming Q), per the flash backward recurrence:
     dq_i = Σ_j ds_ij · k_j · scale
     dk_j = Σ_i ds_ij · q_i · scale
 
+One kernel makes all three (``fused_flash_dkv_bwd_bhsd``): it walks the
+K tiles, streaming Q, computes p and ds once a tile and adds the tile's
+share to dv, dk and to its q block's rows of dq, five matrix products a
+tile. dq's rows come round once for every K tile, so the kernel keeps a
+whole head's dq, dk and dv as (s, d) f32 accumulators in VMEM and asks
+Mosaic for the room (``vmem_limit_bytes``). Where they do not fit
+(:func:`fused_backward_fits`: the sequence, the head size and the dtype
+decide, at trace time; 24 of 32 MiB at s = 8,192, d = 128 in bf16) two
+kernels run instead, one producing dQ (grid over Q tiles, streaming K/V)
+and one dK/dV (grid over K tiles, streaming Q), each recomputing p and
+ds: seven products a tile, with accumulators of one block. The kernels
+are bound by their MXU passes, so time goes by the products: on a v5e
+the fused kernel takes 0.68–0.78 of the two's time (PERF.md, PR 30) and
+its results equal theirs to the bit.
+
 ``causal=True, window=w`` keeps, for query i, the keys i-w < j <= i (a
 sliding window): the innermost grid axis then covers only the k (or q)
 blocks the band touches, so blocks outside it are skipped, not masked.
 Grouped key-value heads: ``k`` and ``v`` may carry fewer heads than ``q``
-(query head h reads key-value head h // group); the dK/dV kernel then
-walks the group's query heads in its innermost axis and sums them in its
-f32 accumulators. The two backward kernels trace the program they traced
-before PR 28 changed the forward's (``tests/test_flash_window.py`` pins
-its hash): they are that change's control.
+(query head h reads key-value head h // group); the fused kernel walks
+a group's query heads outside the K tiles (dq resident a query head, dk
+and dv a key-value head), the dK/dV kernel in its innermost axis; both
+sum them in f32 in the same order. The two backward kernels trace the
+program they traced before PR 28 changed the forward's
+(``tests/test_flash_window.py`` pins its hash): they are that change's
+control, and the fused kernel's.
 
 Plugs in anywhere the model zoo accepts an ``attention_fn``
 (:class:`horovod_tpu.models.TransformerConfig`) and composes with sequence
@@ -296,7 +312,10 @@ def _fwd_bhsd(q, k, v, causal, block_q, block_k, interpret, window=None):
 
 
 # ---------------------------------------------------------------------------
-# Backward: two kernels, both recomputing p from (q, k, lse).
+# Backward. _bwd_bhsd takes the fused kernel (p and ds once a tile, then
+# dv, dk and dq: grid over K tiles, whole-head accumulators in VMEM) where
+# those accumulators fit, else the two kernels below, which each recompute
+# p from (q, k, lse) and which a test pins as they are.
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref, dq_ref,
@@ -376,18 +395,131 @@ def _dkv_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
-                                             "interpret", "window"))
-def _bwd_bhsd(q, k, v, lse, do, out, causal, block_q, block_k, interpret,
-              window=None):
-    bh, s, d = q.shape
-    band = _Band(window, s, block_q, block_k)
-    group = bh // k.shape[0]
-    # Δ_i = do_i · o_i, a cheap row reduction XLA fuses on its own; keeps
-    # the trailing unit lane dim the row-stat BlockSpecs need.
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)
+def _fused_bwd_kernel(q_ref, k_ref, v_ref, lse_ref, delta_ref, do_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                      causal: bool, scale: float, block_q: int,
+                      block_k: int, band: _Band):
+    """One tile of the dK/dV grid, query heads of a group outside the k
+    blocks: p and ds once, then dv, dk and this q block's rows of dq. The
+    three accumulators hold a whole head, (s, d) in f32: dq's rows come
+    round once a k block, dk's and dv's once a head of the group."""
+    g, ki, step = (pl.program_id(axis) for axis in (1, 2, 3))
+    window = band.window
+    qi = step if window is None else band.q_first(ki) + step
+    precision = _precision(q_ref.dtype)
+    first = (ki == 0) & (step == 0)
+    last = (ki == pl.num_programs(2) - 1) & (step == pl.num_programs(3) - 1)
 
+    @pl.when(first)
+    def _init_dq():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(first & (g == 0))
+    def _init_dkv():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    if window is not None:  # the row starts at its first visible block
+        visible = qi <= band.q_last(ki)
+    else:
+        visible = (qi * block_q + block_q > ki * block_k) if causal else True
+
+    @pl.when(visible)
+    def _compute():
+        rows_q = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        rows_k = pl.ds(pl.multiple_of(ki * block_k, block_k), block_k)
+        q = q_ref[0].astype(jnp.float32) * scale
+        kb = k_ref[0].astype(jnp.float32)
+        vb = v_ref[0].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)
+        sblk = _mm(q, kb.T, precision)
+        if causal:
+            sblk = _mask_block(sblk, qi, ki, block_q, block_k, window)
+        p = jnp.exp(sblk - lse_ref[0])  # lse block is (bq, 1)
+        dv_acc[rows_k, :] += _mm(p.T, do, precision)
+        dp = _mm(do, vb.T, precision)
+        ds = p * (dp - delta_ref[0])
+        dk_acc[rows_k, :] += _mm(ds.T, q, precision)  # q carries `scale`
+        dq_acc[rows_q, :] += _mm(ds, kb, precision) * scale
+
+    @pl.when(last)
+    def _finalize_dq():
+        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
+
+    @pl.when(last & (g == pl.num_programs(1) - 1))
+    def _finalize_dkv():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+
+#: Most bytes of whole-head accumulators and output blocks that the fused
+#: backward may keep in VMEM: a quarter of a v5e core's 128 MiB. The
+#: decoder cell's layers (s 8,192, d 128, bf16) hold 24 MiB; twice the
+#: rows, or the same rows in f32, go to the two kernels.
+_FUSED_BWD_VMEM_BYTES = 32 << 20
+#: Mosaic's scoped default, which the fused call's limit adds for its
+#: tiles and temporaries: what the two kernels live in.
+_SCOPED_VMEM_BYTES = 16 << 20
+
+
+def _fused_bwd_bytes(s: int, d: int, dtype) -> int:
+    """VMEM the fused backward keeps for a head: dq, dk and dv as (s, d)
+    f32 accumulators and as output blocks in ``dtype``, two buffers each;
+    the lanes padded to whole registers."""
+    lanes = -(-d // _LANES) * _LANES
+    return 3 * s * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
+
+
+def fused_backward_fits(s: int, d: int, dtype) -> bool:
+    """Whether the backward of (s, d) heads in ``dtype`` is the fused
+    kernel: a head's accumulators and output blocks fit the constant."""
+    return _fused_bwd_bytes(s, d, dtype) <= _FUSED_BWD_VMEM_BYTES
+
+
+def _fused_bwd(q, k, v, lse, delta, do, causal, block_q, block_k, interpret,
+               band: _Band):
+    """dq, dk and dv from one kernel: grid (key-value head, head of its
+    group, k block, q block), five products a tile."""
+    bh, s, d = q.shape
+    group = bh // k.shape[0]
+    q_idx = _q_index(causal, band, 1)
+
+    def q_side(b, g, i, j):
+        return q_idx(b * group + g, i, j)
+
+    q_spec = pl.BlockSpec((1, block_q, d), q_side)
+    row_spec = pl.BlockSpec((1, block_q, 1), q_side)
+    k_spec = pl.BlockSpec((1, block_k, d), lambda b, g, i, j: (b, i, 0))
+    dq_spec = pl.BlockSpec((1, s, d), lambda b, g, i, j: (b * group + g, 0, 0))
+    dkv_spec = pl.BlockSpec((1, s, d), lambda b, g, i, j: (b, 0, 0))
+    head = pltpu.VMEM((s, d), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_fused_bwd_kernel, causal=causal, scale=d ** -0.5,
+                          block_q=block_q, block_k=block_k, band=band),
+        grid=(k.shape[0], group, band.nk, band.n_q),
+        in_specs=[q_spec, k_spec, k_spec, row_spec, row_spec, q_spec],
+        out_specs=[dq_spec, dkv_spec, dkv_spec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
+        scratch_shapes=[head, head, head],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=(_fused_bwd_bytes(s, d, q.dtype)
+                              + _SCOPED_VMEM_BYTES)),
+        interpret=interpret,
+        name="fused_flash_dkv_bwd_bhsd",
+    )(q, k, v, lse, delta, do)
+
+
+def _two_kernel_bwd(q, k, v, lse, delta, do, causal, block_q, block_k,
+                    interpret, band: _Band):
+    """dq from one kernel (grid over q blocks, k innermost) and dk, dv
+    from another (grid over k blocks, the group's heads and their q
+    blocks innermost), seven products a tile: what runs where a head's
+    accumulators do not fit, and the program ``tests/test_flash_window.py``
+    pins."""
+    bh, s, d = q.shape
+    group = bh // k.shape[0]
     q_spec_i = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     k_spec_j = pl.BlockSpec((1, block_k, d), _kv_index(causal, band, group))
     row_spec_i = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
@@ -428,6 +560,27 @@ def _bwd_bhsd(q, k, v, lse, do, out, causal, block_q, block_k, interpret,
         name="flash_dkv_bwd_bhsd",
     )(q, k, v, lse, delta, do)
     return dq, dk, dv
+
+
+def _delta(do, out):
+    """Δ_i = do_i · o_i, a cheap row reduction XLA fuses on its own; keeps
+    the trailing unit lane dim the row-stat BlockSpecs need."""
+    return jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                   axis=-1, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("causal", "block_q", "block_k",
+                                             "interpret", "window"))
+def _bwd_bhsd(q, k, v, lse, do, out, causal, block_q, block_k, interpret,
+              window=None):
+    """The fused kernel where a head's accumulators fit in VMEM, by the
+    shapes and the dtype alone; else the two kernels."""
+    _, s, d = q.shape
+    backward = (_fused_bwd if fused_backward_fits(s, d, q.dtype)
+                else _two_kernel_bwd)
+    return backward(
+        q, k, v, lse, _delta(do, out), do, causal, block_q, block_k,
+        interpret, _Band(window, s, block_q, block_k))
 
 
 # ---------------------------------------------------------------------------
@@ -499,7 +652,7 @@ def flash_attention(q, k, v, bias=None, causal: bool = False,
     attention for biased variants). Block sizes default to
     :func:`_auto_block` (the forward's q block, without a window, to
     twice its cap); explicit block sizes must divide ``seq`` and hold for
-    all three kernels."""
+    every kernel."""
     if bias is not None:
         raise NotImplementedError(
             "flash_attention does not take a bias; use "

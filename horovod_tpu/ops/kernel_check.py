@@ -4,15 +4,15 @@
 
 Compiles every pallas kernel of this package with ``interpret=False`` at
 the shapes the models use and compares it with its plain-XLA reference:
-flash attention forward and both backward kernels against masked softmax
-in f32 (the reference at full f32 matmul precision — the TPU default
-would round it to bf16), with f32 inputs and with bf16 ones, and the
-fused LM-head cross-entropy against the chunked XLA scan. The f32
-tolerances are the ones ``tests/test_flash_attention.py`` uses in
-interpret mode; bf16 inputs are held to a share of each result's own
-scale, as the fused loss is. Exits nonzero on the first mismatch, or off
-a TPU. The CPU tier runs the same checks at toy shapes in interpret mode
-(tests/test_flash_attention.py).
+flash attention forward and backward (the fused kernel and, over its
+byte rule, the two) against masked softmax in f32 (the reference at full
+f32 matmul precision — the TPU default would round it to bf16), with f32
+inputs and with bf16 ones, and the fused LM-head cross-entropy against
+the chunked XLA scan. The f32 tolerances are the ones
+``tests/test_flash_attention.py`` uses in interpret mode; bf16 inputs are
+held to a share of each result's own scale, as the fused loss is. Exits
+nonzero on the first mismatch, or off a TPU. The CPU tier runs the same
+checks at toy shapes in interpret mode (tests/test_flash_attention.py).
 """
 
 from __future__ import annotations
@@ -27,14 +27,21 @@ from horovod_tpu.ops.chunked_loss import (
     chunked_softmax_cross_entropy,
     fused_softmax_cross_entropy,
 )
-from horovod_tpu.ops.flash_attention import flash_attention
+from horovod_tpu.ops.flash_attention import (
+    flash_attention,
+    fused_backward_fits,
+)
 
 # (batch, seq, heads, head_dim, causal, window, key-value heads):
-# BERT-base's attention, a long causal sequence, and a sliding-window
-# layer of the decoder cell (72 query heads over 8).
+# BERT-base's attention, a long causal sequence, and the decoder cell's
+# two kinds of layer: a sliding window under 72 query heads over 8, and
+# full causal attention under 48 over 8. In bf16 every one takes the
+# fused backward kernel; the decoder's in f32 are over its byte rule and
+# take the two kernels (``fused_backward_fits``; each line says which).
 FLASH_SHAPES = ((8, 512, 12, 64, False, None, 12),
                 (2, 2048, 12, 64, True, None, 12),
-                (1, 8192, 72, 128, True, 512, 8))
+                (1, 8192, 72, 128, True, 512, 8),
+                (1, 8192, 48, 128, True, None, 8))
 # Worst error of a bf16 run as a share of the result's largest entry:
 # the output and the probabilities round to eight bits of mantissa.
 BF16_SHARE = 2e-2
@@ -150,9 +157,13 @@ def main() -> int:
         for dtype in (jnp.float32, jnp.bfloat16):
             shares = check_flash(*shape, dtype=dtype)
             worst = ", ".join(f"{n} {e:.1e}" for n, e in shares.items())
+            backward = ("fused" if fused_backward_fits(shape[1], shape[3],
+                                                       dtype)
+                        else "two kernels")
             print(f"flash_attention fwd+bwd {shape} {dtype.__name__}: "
-                  f"compiled, matches the f32 reference (worst error by "
-                  f"its scale: {worst}; {dev.device_kind})", flush=True)
+                  f"compiled, matches the f32 reference (backward: "
+                  f"{backward}; worst error by its scale: {worst}; "
+                  f"{dev.device_kind})", flush=True)
     check_fused_loss(*LOSS_SHAPE)
     print(f"fused_softmax_cross_entropy fwd+bwd {LOSS_SHAPE}: compiled, "
           f"matches the chunked scan ({dev.device_kind})", flush=True)

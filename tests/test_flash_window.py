@@ -1,9 +1,10 @@
 """The flash kernels' window and grouped key-value heads, in interpret
 mode against plain masked softmax: the forward pass (output and row
 log-sum-exp, at the shapes of blocks the benchmark's cells run) and the
-three gradients; and that the two backward kernels still lower to the
-program they lowered to before the forward's row statistics changed
-(``ops/flash_attention.py``)."""
+three gradients; the fused backward kernel against the two it stands in
+for, and the byte rule that chooses between them; and that the two
+backward kernels still lower to the program they lowered to before the
+forward's row statistics changed (``ops/flash_attention.py``)."""
 
 import hashlib
 
@@ -12,11 +13,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from horovod_tpu.ops import flash_attention as flash_module
 from horovod_tpu.ops.flash_attention import (
     _Band,
-    _bwd_bhsd,
+    _delta,
+    _fused_bwd,
     _fwd_bhsd,
+    _two_kernel_bwd,
     flash_attention,
+    fused_backward_fits,
 )
 from horovod_tpu.ops.kernel_check import plain_attention
 
@@ -181,46 +186,169 @@ def test_forward_output_and_lse_match_masked_softmax(case, dtype):
     np.testing.assert_allclose(lse, want_lse, rtol=tol, atol=tol)
 
 
+def _pallas_grids(dtype=jnp.bfloat16, s=2048, heads=2, d=64, kv_heads=None,
+                  causal=True, window=None, **blocks):
+    """``name=`` → grid of every pallas call in the gradient of
+    ``flash_attention`` at a shape, from the jaxpr: nothing runs."""
+    q = jax.ShapeDtypeStruct((1, s, heads, d), dtype)
+    kv = jax.ShapeDtypeStruct((1, s, kv_heads or heads, d), dtype)
+
+    def f(q, k, v):
+        return flash_attention(
+            q, k, v, causal=causal, window=window, interpret=True,
+            **blocks).astype(jnp.float32).sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(q, kv, kv)
+    found = {}
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                name = eqn.params["name"]
+                found[name] = eqn.params["grid_mapping"].grid
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    return found
+
+
 def test_forward_takes_a_wider_q_block_by_default():
     """Without a window and without blocks asked for, the forward's q
     block is up to 1,024 rows and the backward's stay at 512; a window
-    or an explicit block keeps all three kernels on the same blocks."""
-    def grids(window=None, s=2048, **blocks):
-        x = jax.ShapeDtypeStruct((1, s, 2, 64), jnp.bfloat16)
-
-        def f(q, k, v):
-            return flash_attention(
-                q, k, v, causal=True, window=window, interpret=True,
-                **blocks).astype(jnp.float32).sum()
-
-        jaxpr = jax.make_jaxpr(jax.grad(f, argnums=(0, 1, 2)))(x, x, x)
-        found = {}
-
-        def walk(j):
-            for eqn in j.eqns:
-                if eqn.primitive.name == "pallas_call":
-                    name = eqn.params["name"]
-                    found[name] = eqn.params["grid_mapping"].grid
-                for sub in jax.core.jaxprs_in_params(eqn.params):
-                    walk(sub)
-
-        walk(jaxpr.jaxpr)
-        return found
-
-    assert grids() == {"flash_fwd_bhsd": (2, 2, 4),
-                       "flash_dq_bwd_bhsd": (2, 4, 4),
-                       "flash_dkv_bwd_bhsd": (2, 4, 4)}
-    assert grids(window=512)["flash_fwd_bhsd"] == (2, 4, 2)
-    assert grids(block_q=512)["flash_fwd_bhsd"] == (2, 4, 4)
-    assert grids(s=512)["flash_fwd_bhsd"] == (2, 1, 1)
-    assert grids(s=1536)["flash_fwd_bhsd"] == (2, 2, 3)
+    or an explicit block keeps the kernels on the same blocks."""
+    assert _pallas_grids() == {"flash_fwd_bhsd": (2, 2, 4),
+                               "fused_flash_dkv_bwd_bhsd": (2, 1, 4, 4)}
+    assert _pallas_grids(window=512)["flash_fwd_bhsd"] == (2, 4, 2)
+    assert _pallas_grids(block_q=512)["flash_fwd_bhsd"] == (2, 4, 4)
+    assert _pallas_grids(s=512)["flash_fwd_bhsd"] == (2, 1, 1)
+    assert _pallas_grids(s=1536)["flash_fwd_bhsd"] == (2, 2, 3)
 
 
-#: sha256 of the lowering of ``_bwd_bhsd`` alone (the dQ and the dK/dV
-#: kernel; interpret mode, bf16, (2, 256, 64), blocks of 128), by
-#: ``causal``; taken from the commit before the forward's row statistics
-#: changed (PR 27's) with this jax. The forward is free to change; the
-#: two backward kernels, the control of that change, are pinned.
+FUSED, TWO = ({"fused_flash_dkv_bwd_bhsd"},
+              {"flash_dq_bwd_bhsd", "flash_dkv_bwd_bhsd"})
+
+#: (sequence, head size, dtype) → the backward's kernels. A head's dq, dk
+#: and dv as f32 accumulators and as output blocks in two buffers, lanes
+#: padded to 128: 3 s max(d, 128) (4 + 2 itemsize) bytes against 32 MiB.
+BYTE_RULE_CASES = {
+    "bert_s2048_d64_bf16": (2048, 64, jnp.bfloat16, FUSED),       # 6 MiB
+    "decoder_s8192_d128_bf16": (8192, 128, jnp.bfloat16, FUSED),  # 24 MiB
+    "decoder_s8192_d128_f32": (8192, 128, jnp.float32, TWO),      # 36 MiB
+    "s16384_d128_bf16": (16384, 128, jnp.bfloat16, TWO),          # 48 MiB
+    "s16384_d64_bf16_lanes_padded": (16384, 64, jnp.bfloat16, TWO),
+    "s8192_d256_bf16": (8192, 256, jnp.bfloat16, TWO),            # 48 MiB
+    "s8192_d64_f32": (8192, 64, jnp.float32, TWO),                # 36 MiB
+    "s4096_d128_f32": (4096, 128, jnp.float32, FUSED),            # 18 MiB
+}
+
+
+@pytest.mark.parametrize("case", list(BYTE_RULE_CASES))
+def test_the_backward_is_fused_where_a_heads_accumulators_fit(case):
+    """The byte rule, seen in the jaxpr's ``pallas_call`` names: a shape
+    under the constant takes the fused kernel, one over it the two, and
+    nothing but (s, d, dtype) decides."""
+    s, d, dtype, want = BYTE_RULE_CASES[case]
+    assert fused_backward_fits(s, d, dtype) == (want is FUSED)
+    for kw in (dict(heads=1), dict(heads=6, kv_heads=1, window=512),
+               dict(heads=1, causal=False)):
+        found = set(_pallas_grids(dtype, s, d=d, **kw)) - {"flash_fwd_bhsd"}
+        assert found == want, (kw, found)
+
+
+def test_the_fused_grid_walks_a_groups_heads_outside_the_k_blocks():
+    """Grid (key-value head, head of its group, k block, q block): the
+    decoder cell's layers, and a band's two q blocks a k block."""
+    def fused(**kw):
+        return _pallas_grids(s=8192, d=128, **kw)["fused_flash_dkv_bwd_bhsd"]
+
+    assert fused(heads=48, kv_heads=8) == (8, 6, 16, 16)
+    assert fused(heads=72, kv_heads=8, window=512) == (8, 9, 16, 2)
+    assert fused(heads=12, causal=False) == (12, 1, 16, 16)
+
+
+def test_the_fused_calls_vmem_limit_follows_the_bytes(monkeypatch):
+    """The call asks Mosaic for the head's bytes and the scoped default
+    on top: 24 + 16 MiB at the decoder cell's shapes."""
+    seen = []
+    real = flash_module.pltpu.CompilerParams
+
+    def params(**kw):
+        seen.append(kw)
+        return real(**kw)
+
+    monkeypatch.setattr(flash_module.pltpu, "CompilerParams", params)
+    _pallas_grids(s=8192, heads=6, kv_heads=1, d=128)
+    _pallas_grids(s=1024, heads=1, d=64)
+    assert seen == [{"vmem_limit_bytes": (24 + 16) << 20},
+                    {"vmem_limit_bytes": (3 + 16) << 20}]
+
+
+def _bwd_case(causal=True, window=None, group=1, d=64, s=64, blocks=(16, 16)):
+    return dict(causal=causal, window=window, group=group, d=d, s=s,
+                blocks=blocks)
+
+
+#: The fused backward against the two kernels: non-causal, causal and
+#: banded, each with one head a key-value head and with several, at both
+#: head sizes, over a sequence of one block and of several (every pair of
+#: those choices meets in some case); a band under, at and over a block;
+#: blocks that differ; blocks of whole lane tiles.
+BACKWARD_CASES = {
+    "noncausal_group1_d64_one_block": _bwd_case(False, s=16),
+    "noncausal_group3_d128_several_blocks": _bwd_case(False, group=3, d=128),
+    "noncausal_group1_d128_whole_lane_tiles": _bwd_case(
+        False, d=128, s=256, blocks=(128, 128)),
+    "causal_group1_d128_one_block": _bwd_case(d=128, s=16),
+    "causal_group3_d64_one_block": _bwd_case(group=3, s=16),
+    "causal_group1_d64_several_blocks": _bwd_case(),
+    "causal_group3_d128_several_blocks": _bwd_case(group=3, d=128),
+    "causal_group2_unequal_blocks": _bwd_case(group=2, d=8, blocks=(32, 16)),
+    "window_group1_d128_several_blocks": _bwd_case(window=24, d=128),
+    "window_group3_d64_several_blocks": _bwd_case(window=24, group=3),
+    "window_under_a_block_group9": _bwd_case(window=5, group=9, d=8),
+    "window_a_block_group6": _bwd_case(window=16, group=6, d=8),
+    "window_unequal_blocks_wide_q": _bwd_case(window=24, group=6, d=8,
+                                              blocks=(32, 16)),
+    "window_unequal_blocks_wide_k": _bwd_case(window=24, group=6, d=8,
+                                              blocks=(16, 32)),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", list(BACKWARD_CASES))
+def test_fused_backward_equals_the_two_kernels(case, dtype):
+    """Same operands, same f32 accumulators, same order of accumulation
+    (dq over k blocks ascending; dk and dv over the group's heads, then q
+    blocks ascending): dq, dk and dv equal the two kernels' to the bit."""
+    c = BACKWARD_CASES[case]
+    s, d, hk = c["s"], c["d"], 2
+    keys = jax.random.split(jax.random.PRNGKey(len(case)), 4)
+    q, do = (jax.random.normal(key, (hk * c["group"], s, d),
+                               jnp.float32).astype(dtype)
+             for key in keys[:2])
+    k, v = (jax.random.normal(key, (hk, s, d), jnp.float32).astype(dtype)
+            for key in keys[2:])
+    out, lse = plain_forward(q, k, v, c["causal"], c["window"])
+    args = (q, k, v, lse, _delta(do, out), do, c["causal"], *c["blocks"],
+            True, _Band(c["window"], s, *c["blocks"]))
+    fused, two = _fused_bwd(*args), _two_kernel_bwd(*args)
+    for name, a, b in zip(("dq", "dk", "dv"), fused, two):
+        assert a.dtype == b.dtype == dtype and a.shape == b.shape
+        assert np.abs(np.asarray(b, np.float32)).max() > 0
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32),
+                                      err_msg=name)
+
+
+#: sha256 of the lowering of the two-kernel backward alone (``delta``,
+#: the dQ and the dK/dV kernel; interpret mode, bf16, (2, 256, 64),
+#: blocks of 128), by ``causal``; taken from the commit before the
+#: forward's row statistics changed (PR 27's) with this jax, when
+#: ``_bwd_bhsd`` was these two kernels and nothing else. The forward is
+#: free to change and small shapes now take the fused kernel; the two
+#: kernels, the control of both changes, are pinned.
 GOLDEN_JAX = "0.9.0"
 GOLDEN = {
     False: "b79a18fb49eb057d6e0be374c5e20a90d55b409731fee01bbbd8c48ee834b65e",
@@ -234,6 +362,11 @@ def test_without_window_and_groups_the_program_is_the_old_one(causal):
         pytest.skip(f"the recorded lowering is jax {GOLDEN_JAX}'s")
     x = jax.ShapeDtypeStruct((2, 256, 64), jnp.bfloat16)
     row = jax.ShapeDtypeStruct((2, 256, 1), jnp.float32)
-    text = _bwd_bhsd.lower(x, x, x, row, x, x, causal, 128, 128,
-                           True).as_text()
+
+    # The recorded module carries its jitted function's name.
+    def _bwd_bhsd(q, k, v, lse, do, out):
+        return _two_kernel_bwd(q, k, v, lse, _delta(do, out), do, causal,
+                               128, 128, True, _Band(None, 256, 128, 128))
+
+    text = jax.jit(_bwd_bhsd).lower(x, x, x, row, x, x).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == GOLDEN[causal]

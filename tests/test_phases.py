@@ -118,8 +118,11 @@ def test_an_unknown_phase_is_refused():
 def test_kernel_names_keep_what_trace_readers_match():
     # benchmark/metrics/flash_ms_per_step.py goes by these substrings of
     # the custom-call's identifier, which name= replaces.
-    fwd, dq, dkv = phases.KERNELS[:3]
+    fwd, dq, dkv, fused = phases.KERNELS[:4]
     assert "_fwd_bhsd" in fwd and "_bwd_bhsd" in dq and "_bwd_bhsd" in dkv
+    # The fused backward is read as the dK/dV kernel, never as the dQ one
+    # (benchmark/harness/phases.py KERNELS: first pattern that matches).
+    assert dkv in fused and dq not in fused
     from horovod_tpu.ops import chunked_loss, flash_attention
 
     source = inspect.getsource(flash_attention) + inspect.getsource(
