@@ -167,13 +167,19 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     window_steps = int(cell.traffic["window_steps"])
     first_losses = [_window(system, 1, contextlib.nullcontext)
                     for _ in range(check.REFERENCE_STEPS)]
+    # What those steps made of the parameters, at a few thousand seeded
+    # coordinates a leaf: the reference's change is held against it.
+    t_digest = time.perf_counter()
+    after_first = check.digester(system.state[0], seed)(system.state[0])
+    digest_s = time.perf_counter() - t_digest
     _window(system, _calls(system, int(cell.traffic["warmup_steps"])),
             contextlib.nullcontext)
     setup_s = time.perf_counter() - t_start
 
     window = measure(system, window_steps, seconds)
     peak = peak_bytes(devices)
-    log(phase="measured", setup_s=setup_s, peak_bytes=peak,
+    log(phase="measured", setup_s=setup_s, digest_s=digest_s,
+        peak_bytes=peak,
         memory_stats=devices[0].memory_stats(),
         **{k: window[k] for k in ("wall_s", "window_s", "steps",
                                   "failed_steps", "items_per_s_per_chip")},
@@ -209,13 +215,18 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     from horovod_tpu.ops import pallas_mode
 
     log(phase="released", **release(system, devices[0]))
-    ref = check.reference_losses(cell, reference, system, devices[0])
+    ref = check.reference_losses(cell, reference, system, devices[0], seed)
+    update = check.update_gaps(ref["start"], after_first, ref["end"],
+                               ref["gradient_rms"])
     compared = check.verdict(cell, system, first_losses, window["losses"],
-                             ref["losses"], pallas_mode.INTERPRETED,
+                             ref["losses"], update, pallas_mode.INTERPRETED,
                              on_tpu=platform == "tpu")
     hvd.shutdown()
+    by_leaf = update.pop("by_leaf")  # a line of its own, before the last two
+    log(phase="update_by_leaf", **by_leaf)
     log(phase="checked", reference_s=ref["seconds"],
         system_losses=first_losses, reference_losses=ref["losses"],
+        update=update,
         checks={name: c["ok"] for name, c in compared.items()})
     # Each number compared beside its limit: the end of standard error
     # is what the driver keeps of a run that is not correct.

@@ -53,6 +53,10 @@ VOCABULARY = ("hvd_pack", "hvd_allreduce", "hvd_unpack", "hvd_numerics",
 #: (pattern on a ``tpu_custom_call``'s identifier, phase), first match.
 KERNELS = (
     (r"flash_dq_bwd_bhsd", "flash_dq"),
+    # The whole backward in the dK/dV grid's pass (PR 30), where a head's
+    # accumulators fit VMEM; read with the dK/dV kernel it took over, so
+    # ``flash_dq`` then reads 0: the engagement counter.
+    (r"fused_flash_dkv_bwd_bhsd", "flash_dkv"),
     (r"flash_dkv_bwd_bhsd", "flash_dkv"),
     (r"_fwd_bhsd", "flash_fwd"),
     (r"xent_fwd", "xent_fwd"), (r"xent_dx", "xent_dx"),
@@ -342,8 +346,10 @@ FLASH = ("flash_fwd", "flash_dq", "flash_dkv")
 
 
 def flash_ms(context, kernel: str) -> Optional[float]:
-    """Device milliseconds a step spends in one of the three flash
-    kernels; ``None`` where none of the three ran."""
+    """Device milliseconds a step spends under one of the three flash
+    phases (``FLASH``: the forward, the dq kernel of the two-kernel
+    backward, and the fused or the dK/dV backward); ``None`` where no
+    flash kernel ran."""
     got = reading(context)
     if not any(d.get(k) for d in got.phases for k in FLASH):
         return None

@@ -11,6 +11,7 @@ import dataclasses
 import importlib.util
 import json
 import os
+import re
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -59,6 +60,72 @@ class Cell:
         return self.config["family"]
 
 
+#: What a key of ``reduced`` may never be: a width (model-configs guide,
+#: section 4). How many layers, experts, rows of the vocabulary, heads
+#: or groups of heads a chip holds is a count and may be its share; how
+#: wide one is (a hidden, intermediate, latent, state or projection
+#: size, anything ending in ``_dim`` or ``_rank``, a head's size, a
+#: convolution's kernel, a chunk, a window, an expansion factor) and how
+#: many experts a token takes are the model, and no cut touches them.
+WIDTH_RE = re.compile(
+    r"hidden_size|intermediate|latent|state_size|proj|_dim$|_rank$"
+    r"|head_size|expan|per_tok|kernel|chunk|window")
+#: The guide's floors for a share: experts held, and the eighth of the
+#: vocabulary.
+MIN_EXPERTS_HELD = 8
+MIN_VOCAB_SHARE = 8
+
+
+def is_width(key: str) -> bool:
+    return bool(WIDTH_RE.search(key))
+
+
+def check_cuts(config: dict, where: str = "the configuration") -> None:
+    """Hold ``config["reduced"]`` to the guide's rule on cuts: a count
+    may be the chip's share, a width never. Where the file states what
+    was ``published``, every reduced key is there, every share (all but
+    the depth) divides its published count, the floors hold and
+    ``deployment`` says over how many chips each layer is divided.
+    Raises :class:`SpecError` naming the key."""
+    reduced = config.get("reduced", [])
+    widths = [k for k in reduced if is_width(k)]
+    if widths:
+        raise SpecError(
+            f"{where} lists {widths} in 'reduced': a width is never cut "
+            "(how many heads, experts, layers or vocabulary rows a chip "
+            "holds may be; how wide one is may not)")
+    if "published" not in config:
+        return
+    published = config["published"]
+    if not str(config.get("deployment", "")).strip():
+        raise SpecError(f"{where} has 'published' counts and no "
+                        "'deployment' that says which chips share a layer")
+    for key in reduced:
+        if key not in published:
+            raise SpecError(f"{where} reduces {key!r} and 'published' "
+                            "does not give its count")
+        held, whole = config.get(key), published[key]
+        if not (isinstance(held, int) and 1 <= held <= whole):
+            raise SpecError(f"{where} holds {key} = {held!r} of the "
+                            f"published {whole!r}")
+        if "layers" not in key and whole % held:
+            raise SpecError(f"{where}: the published {key} = {whole} is no "
+                            f"whole multiple of the {held} held")
+        if key.endswith("experts") and held < MIN_EXPERTS_HELD:
+            raise SpecError(f"{where} holds {held} of {key}: the floor is "
+                            f"{MIN_EXPERTS_HELD} routed experts a layer")
+        if key == "vocab_size" and held * MIN_VOCAB_SHARE < whole:
+            raise SpecError(f"{where} holds {held} of {whole} vocabulary "
+                            f"rows: the floor is 1/{MIN_VOCAB_SHARE}")
+    heads = {"num_attention_heads", "num_key_value_heads"}
+    if heads & set(reduced) and heads <= set(config):
+        q, kv = config["num_attention_heads"], config["num_key_value_heads"]
+        if kv < 1 or q % kv:
+            raise SpecError(f"{where} holds {q} query heads over {kv} "
+                            "key-value heads: want a whole multiple of at "
+                            "least one key-value head")
+
+
 def load_benchmark() -> dict:
     return _load_json(os.path.join(ROOT, "BENCHMARK.json"))
 
@@ -73,8 +140,10 @@ def _stem(path: str) -> str:
 
 
 def _cell(bench, name, chips, config_name, config_file, traffic_file) -> Cell:
+    config = _load_json(config_file)
+    check_cuts(config, os.path.relpath(config_file, ROOT))
     return Cell(name=name, chips=int(chips), config_name=config_name,
-                config=_load_json(config_file),
+                config=config,
                 traffic_name=_stem(traffic_file),
                 traffic=_load_json(traffic_file),
                 end_to_end=_for_cell(bench["end_to_end"], name),

@@ -1,6 +1,6 @@
 """``attn_band_roofline`` (layer: kernels), in percent: the least time
 the chip could take for the causal and banded attention of one step,
-over the time the three flash kernels took (``flash_ms_per_step``'s
+over the time the flash kernels took (``flash_ms_per_step``'s
 seconds, the recomputed forward included). Per layer the least time is
 the larger of FLOPs over the published bf16 peak and bytes over the
 published HBM bandwidth, by the visible pairs only: s(s+1)/2 in a full

@@ -1,14 +1,15 @@
 """``flash_ms_per_step`` (layer: kernels): device milliseconds a step
-spends in the three pallas flash-attention kernels, mean over the
-devices. Zero in a cell whose traffic uses stock attention.
+spends in the pallas flash-attention kernels, mean over the devices: the
+forward and the fused backward (``flash_fwd_bhsd``,
+``fused_flash_dkv_bwd_bhsd``) and, where a layer fell back to them, the
+dq and dK/dV kernels of the two-kernel backward. Zero in a cell whose
+traffic uses stock attention.
 
-``PATTERNS`` are the kernels' names as the trace prints them on
-``XLA Ops`` (read by hand, PR 22): a pallas call without a ``name=`` is a
-``custom-call`` named after the jitted function round it, so the forward
-kernel is ``%_fwd_bhsd.N`` and the dq and dkv kernels are both
-``%_bwd_bhsd.N`` (12 and 24 events a step for 12 layers). Telling the two
-backward kernels apart needs ``name=`` on the pallas calls of
-``ops/flash_attention.py``: the tracing issue."""
+``PATTERNS`` match the kernels' names as the trace prints them on
+``XLA Ops``, with or without ``name=`` on the pallas calls (without it a
+call is a ``custom-call`` named after the jitted function round it:
+``%_fwd_bhsd.N``, ``%_bwd_bhsd.N``; read by hand, PR 22). Every backward
+kernel's name ends in ``_bwd_bhsd``, the fused one's too."""
 
 from benchmark.harness import xtrace
 

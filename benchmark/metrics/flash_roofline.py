@@ -1,13 +1,14 @@
 """``flash_roofline`` (layer: kernels), in percent: the least time the
-chip could take for the attention of one step, over the time the three
-flash kernels took. The least time is the larger of FLOPs over the
-published bf16 peak and bytes over the published HBM bandwidth; at
-d = 64 and s in the thousands the FLOPs bound it.
+chip could take for the attention of one step, over the time the flash
+kernels took (``flash_ms_per_step``'s). The least time is the larger of
+FLOPs over the published bf16 peak and bytes over the published HBM
+bandwidth; at d = 64 and s in the thousands the FLOPs bound it.
 
 FLOPs are what the algorithm needs: two s x s x d matmuls forward
 (q.k^T, p.v) and five backward (recomputing q.k^T once, dp, dv, dq, dk),
-each 2.s.s.d per head; not the seven the two backward kernels run by
-each recomputing p. A causal mask halves them. Bytes are one read of q,
+each 2.s.s.d per head, which is what the fused backward runs; the
+two-kernel fallback runs seven, each kernel recomputing p, and is held
+to the same five. A causal mask halves them. Bytes are one read of q,
 k, v and one write of o forward; backward reads q, k, v, o, do and
 writes dq, dk, dv; the row statistics are s floats a head and are left
 out. ``None`` where no kernel ran."""

@@ -350,5 +350,30 @@ def test_the_readers_vocabulary_is_the_programs():
         assert any(re.search(p, name) for p in patterns), name
     # The accepted flash_ms_per_step goes by these two substrings.
     accepted = spec.load_module("metrics", "flash_ms_per_step").PATTERNS
-    for name in program.KERNELS[:3]:
+    for name in program.KERNELS[:4]:
         assert any(p in name for p in accepted), name
+
+
+@pytest.mark.parametrize("name,phase", [
+    ("flash_fwd_bhsd", "flash_fwd"),
+    ("flash_dq_bwd_bhsd", "flash_dq"),
+    ("flash_dkv_bwd_bhsd", "flash_dkv"),
+    # PR 30's whole backward in one kernel: under its own name, read with
+    # the dK/dV kernel it took over.
+    ("fused_flash_dkv_bwd_bhsd", "flash_dkv"),
+    ("xent_fwd", "xent_fwd"), ("xent_dx", "xent_dx"), ("xent_dw", "xent_dw"),
+])
+def test_every_kernel_of_the_program_has_a_pattern_of_its_own(name, phase):
+    from horovod_tpu.common import phases as program
+
+    assert name in program.KERNELS
+    event = (f"%{name}.7 = (bf16[48,128,64]{{2,1,0}}, bf16[48,128,64]"
+             f"{{2,1,0}}) custom-call(%q), custom_call_target="
+             "\"tpu_custom_call\"")
+    assert phases.kernel_phase(event) == phase
+    # The fused kernel's name holds the dK/dV kernel's, so its own pattern
+    # comes first; no other kernel's pattern matches two of the program's.
+    first = next(p for p, _ in phases.KERNELS if re.search(p, name))
+    matched = {n for n in program.KERNELS if re.search(first, n)}
+    assert matched == ({name, "fused_flash_dkv_bwd_bhsd"}
+                       if name == "flash_dkv_bwd_bhsd" else {name})

@@ -331,8 +331,11 @@ def test_a_step_whose_all_reduces_are_all_variadic(replica_groups, spans):
     batch = jax.numpy.zeros((4, 2))
     system = types.SimpleNamespace(n_chips=4, mean_rank=1.5, hlo_text=text,
                                    batch=(batch,))
-    cell = types.SimpleNamespace(config={"loss_tolerance": {"abs": 0.002}})
+    cell = types.SimpleNamespace(config={
+        "loss_tolerance": {"abs": 0.002},
+        "update_tolerance": {"rel": 0.3, "pooled_rel": 0.2}})
     got = check.verdict(cell, system, [3.0, 2.9, 2.8], [2.0], [3.0] * 3,
-                        set(), on_tpu=False)
+                        {"update_gap": 0.1, "update_pooled_gap": 0.05}, set(),
+                        on_tpu=False)
     assert got["all_reduce_spans_world"] == {
         "value": 0 if spans else 2, "limit": 0, "ok": spans}

@@ -75,7 +75,14 @@ def test_cell_resolves_to_its_files(name):
     # per-layer metric.
     assert "setup_s" in {m["name"] for m in cell.end_to_end}
     assert len(cell.end_to_end) >= 2 and cell.per_layer
+    # Each limit of ``correct`` that the file sets says what it was set
+    # from.
     assert cell.config["loss_tolerance"]["why"]
+    # (``null`` for a number that is said and not compared.)
+    update = cell.config["update_tolerance"]
+    assert update["why"] and {"rel", "pooled_rel"} <= set(update)
+    assert all(limit is None or 0 < limit < 1
+               for limit in (update["rel"], update["pooled_rel"]))
 
 
 @pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
@@ -87,10 +94,108 @@ def test_config_file_is_under_paths_and_used(name):
     assert config["reduced"] == entry["reduced"]
     assert entry["source"].startswith("https://")
     assert name in {w["config"] for w in BENCH["workloads"]}
-    # No width is ever cut.
-    assert not [k for k in entry["reduced"]
-                if re.search(r"hidden_size|intermediate|_dim$|_rank$|head",
-                             k)]
+    # No width is ever cut; a count may be the chip's share, and where
+    # the file states what was published the share is held to it.
+    assert not [k for k in entry["reduced"] if spec.is_width(k)]
+    spec.check_cuts(config, entry["file"])
+
+
+COUNTS = ["num_hidden_layers", "num_experts", "n_routed_experts",
+          "vocab_size", "num_attention_heads", "num_key_value_heads",
+          "mamba_num_heads", "n_groups", "hidden_dropout_prob",
+          "attention_probs_dropout_prob", "type_vocab_size"]
+WIDTHS = ["hidden_size", "intermediate_size", "moe_intermediate_size",
+          "shared_expert_intermediate_size", "head_dim", "v_head_dim",
+          "qk_rope_head_dim", "mamba_head_dim", "kv_lora_rank",
+          "ssm_state_size", "moe_latent_size", "conv_kernel", "chunk_size",
+          "sliding_window", "num_experts_per_tok"]
+
+
+@pytest.mark.parametrize("key", COUNTS)
+def test_a_count_may_be_listed_in_reduced(key):
+    """Layers, experts, vocabulary rows, heads of every kind and their
+    groups are counts: a chip may hold its share (model-configs guide,
+    section 4). ``bert_base``'s three are switches, not sizes."""
+    assert not spec.is_width(key)
+    spec.check_cuts({"reduced": [key], key: 4})
+
+
+@pytest.mark.parametrize("key", WIDTHS)
+def test_a_width_is_never_listed_in_reduced(key):
+    """How wide a head, an expert, a latent, a state, a convolution, a
+    chunk or a window is, and how many experts a token takes, are the
+    model: no cut touches them, with ``published`` stated or without."""
+    assert spec.is_width(key)
+    for config in ({"reduced": [key], key: 64},
+                   {"reduced": ["num_hidden_layers", key], key: 64,
+                    "num_hidden_layers": 4, "deployment": "4 chips a layer",
+                    "published": {key: 128, "num_hidden_layers": 48}}):
+        with pytest.raises(spec.SpecError, match="a width is never cut"):
+            spec.check_cuts(config)
+
+
+def _share(**changes):
+    """A configuration that holds a fourth of its heads, groups and
+    experts and an eighth of its vocabulary, with ``changes`` applied
+    (``None`` deletes a key; ``published.x`` reaches into the group)."""
+    config = {
+        "num_hidden_layers": 5, "num_experts": 128, "vocab_size": 16384,
+        "num_attention_heads": 8, "num_key_value_heads": 2,
+        "mamba_num_heads": 32, "n_groups": 2, "head_dim": 128,
+        "published": {"num_hidden_layers": 88, "num_experts": 512,
+                      "vocab_size": 131072, "num_attention_heads": 32,
+                      "num_key_value_heads": 8, "mamba_num_heads": 128,
+                      "n_groups": 8},
+        "deployment": "4 chips share each mixer by heads, 4 the experts",
+    }
+    config["reduced"] = list(config["published"])
+    for key, value in changes.items():
+        group, _, inner = key.rpartition(".")
+        target = config[group] if group else config
+        if value is None:
+            del target[inner]
+        else:
+            target[inner] = value
+    return config
+
+
+@pytest.mark.parametrize("changes,refusal", [
+    ({}, None),
+    # the depth is a cut, not a share: 88 is no multiple of 5
+    ({"num_hidden_layers": 7}, None),
+    ({"num_experts": 8, "vocab_size": 131072 // 8}, None),
+    ({"num_key_value_heads": 1, "num_attention_heads": 4}, None),
+    ({"deployment": None}, "no 'deployment'"),
+    ({"deployment": "  "}, "no 'deployment'"),
+    ({"published.mamba_num_heads": None}, "does not give its count"),
+    ({"mamba_num_heads": 48}, "no whole multiple"),
+    ({"n_groups": 3}, "no whole multiple"),
+    ({"vocab_size": 131072 // 3}, "no whole multiple"),
+    ({"num_attention_heads": 64}, "of the published 32"),
+    ({"num_key_value_heads": 0}, "of the published 8"),
+    ({"num_experts": 4}, "the floor is 8 routed experts"),
+    ({"vocab_size": 131072 // 16}, "the floor is 1/8"),
+    ({"num_attention_heads": 2, "num_key_value_heads": 4},
+     "a whole multiple of at least one key-value head"),
+])
+def test_a_share_is_held_to_what_was_published(changes, refusal):
+    config = _share(**changes)
+    if refusal is None:
+        spec.check_cuts(config)
+    else:
+        with pytest.raises(spec.SpecError, match=refusal):
+            spec.check_cuts(config, "scratch.json")
+
+
+def test_a_configuration_without_published_counts_states_no_share():
+    """``bert_base`` and ``resnet50`` cut no count: nothing to hold them
+    to but the rule on widths."""
+    for name in ("bert_base", "resnet50"):
+        with open(os.path.join(REPO, "benchmark", "configs",
+                               name + ".json")) as f:
+            config = json.load(f)
+        assert "published" not in config
+        spec.check_cuts(config)
 
 
 @pytest.mark.parametrize("name", [m["name"] for m in BENCH["per_layer"]])
@@ -187,15 +292,29 @@ def test_unknown_workload_is_an_error_that_lists_the_cells():
     assert not proc.stdout.strip()
 
 
+def _copy_of_the_benchmark(tmp_path, bench=BENCH):
+    """``benchmark/`` and a ``BENCHMARK.json`` in a temporary root."""
+    root = str(tmp_path / "copy")
+    shutil.copytree(os.path.join(REPO, "benchmark"),
+                    os.path.join(root, "benchmark"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(bench, f)
+    return root
+
+
 def test_a_fifth_cell_is_one_json_file_and_one_entry(tmp_path):
     """In a temporary copy: a new traffic file from an existing one, one
     new entry in ``workloads``, no other edit. The copy's command then
     finds the cell (it gets as far as looking for the chip), and a cell
     whose traffic file is missing is refused by name."""
-    root = str(tmp_path / "copy")
-    shutil.copytree(os.path.join(REPO, "benchmark"),
-                    os.path.join(root, "benchmark"),
-                    ignore=shutil.ignore_patterns("__pycache__"))
+    bench = json.loads(json.dumps(BENCH))
+    bench["workloads"] += [
+        {"name": "fifth", "config": "bert_base",
+         "traffic": "seq512_bs16_int8_sharded", "chips": 1, "why": "test"},
+        {"name": "sixth", "config": "bert_base",
+         "traffic": "never_written", "chips": 1, "why": "test"}]
+    root = _copy_of_the_benchmark(tmp_path, bench)
     traffic = os.path.join(root, "benchmark", "traffic")
     with open(os.path.join(traffic, "seq512_bs16.json")) as f:
         mix = json.load(f)
@@ -203,14 +322,6 @@ def test_a_fifth_cell_is_one_json_file_and_one_entry(tmp_path):
     with open(os.path.join(traffic, "seq512_bs16_int8_sharded.json"),
               "w") as f:
         json.dump(mix, f)
-    bench = json.loads(json.dumps(BENCH))
-    bench["workloads"] += [
-        {"name": "fifth", "config": "bert_base",
-         "traffic": "seq512_bs16_int8_sharded", "chips": 1, "why": "test"},
-        {"name": "sixth", "config": "bert_base",
-         "traffic": "never_written", "chips": 1, "why": "test"}]
-    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
-        json.dump(bench, f)
 
     args = ("--seed", "0", "--seconds", "1", "--trace", "0")
     found = _run(root, "--workload", "fifth", *args)
@@ -219,3 +330,54 @@ def test_a_fifth_cell_is_one_json_file_and_one_entry(tmp_path):
     missing = _run(root, "--workload", "sixth", *args)
     assert missing.returncode == 2
     assert "benchmark/traffic/never_written.json" in missing.stderr
+
+
+def _scratch_cell(tmp_path, reduced, **config_changes):
+    """``bert_base``'s file with ``reduced`` and the changes, beside an
+    existing traffic file: a cell by its two files."""
+    with open(os.path.join(REPO, "benchmark/configs/bert_base.json")) as f:
+        config = json.load(f)
+    config.update(config_changes, reduced=reduced)
+    path = tmp_path / "scratch.json"
+    path.write_text(json.dumps(config))
+    return str(path), os.path.join(REPO,
+                                   "benchmark/traffic/seq512_bs16.json")
+
+
+def test_a_scratch_configuration_may_hold_its_share_of_heads(tmp_path):
+    files = _scratch_cell(tmp_path, ["num_attention_heads"],
+                          num_attention_heads=3,
+                          published={"num_attention_heads": 12})
+    cell = spec.cell_from_files(*files, 1)
+    assert cell.config["num_attention_heads"] == 3
+    # The same with a head's size is refused, by the rule and by name.
+    files = _scratch_cell(tmp_path, ["head_dim"], head_dim=32,
+                          published={"head_dim": 64})
+    with pytest.raises(spec.SpecError, match=r"\['head_dim'\].*never cut"):
+        spec.cell_from_files(*files, 1)
+
+
+@pytest.mark.parametrize("key,held,published,resolves", [
+    ("num_attention_heads", 3, 12, True), ("head_dim", 32, 64, False)])
+def test_the_command_takes_a_share_of_heads_and_refuses_a_width(
+        tmp_path, key, held, published, resolves):
+    """In a temporary copy whose ``bert_base`` lists ``key`` in
+    ``reduced``: a head count gets as far as looking for the chip, a
+    head's size is refused before anything is imported."""
+    root = _copy_of_the_benchmark(tmp_path)
+    path = os.path.join(root, "benchmark", "configs", "bert_base.json")
+    with open(path) as f:
+        config = json.load(f)
+    config.update({key: held, "published": {key: published},
+                   "reduced": [key]})
+    with open(path, "w") as f:
+        json.dump(config, f)
+    proc = _run(root, "--workload", "bert_base_s512", "--seed", "0",
+                "--seconds", "1", "--trace", "0")
+    assert '"correct"' not in proc.stdout
+    if resolves:
+        assert proc.returncode not in (0, 2), proc.stderr[-2000:]
+        assert "no TPU" in proc.stderr
+    else:
+        assert proc.returncode == 2, proc.stderr[-2000:]
+        assert "a width is never cut" in proc.stderr and key in proc.stderr

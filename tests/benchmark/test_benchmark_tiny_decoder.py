@@ -34,11 +34,14 @@ def test_a_sound_run_reads_correct():
                                       "setup_s"}
     phases = {x["phase"]: x for x in lines[:-1]}
     assert list(phases) == ["built", "measured", "released",
-                            "reference_entered", "checked"]
+                            "reference_entered", "update_by_leaf", "checked"]
     assert phases["built"]["item"] == "tokens"
     assert phases["reference_entered"]["state_deleted"]
     value, limit = result["compared"]["reference"]
     assert value <= limit == 0.02
+    value, limit = result["compared"]["update_gap"]
+    assert 0 < value <= limit == 0.5
+    assert not phases["checked"]["update"]["dead_leaves"]  # no bias here
     assert "flash_attention runs in interpret mode" in stderr
 
 
@@ -51,6 +54,27 @@ def test_without_the_routed_experts_output_it_reads_not_correct():
     value, limit = result["compared"]["reference"]
     assert value > 3 * limit  # by a wide margin, not by rounding
     assert f"compared reference: {value!r} limit {limit!r} FAILED" in stderr
+
+
+def test_parameters_in_float8_are_read_by_the_parameter_change_alone():
+    """The precision below the configuration's, on the timed path: the
+    loss computed on parameters rounded to float8_e4m3fn. The losses
+    stay within their limit of the reference's (a loss at seeded weights
+    is blind to them); the parameter change, coordinate by coordinate,
+    is not."""
+    lines, stderr = _run("fp8_params")
+    result = lines[-1]
+    assert result["correct"] is False
+    checks = lines[-1 - 1]["checks"]
+    assert {name for name, ok in checks.items() if not ok} == {
+        "update_gap", "update_pooled_gap"}
+    value, limit = result["compared"]["reference"]
+    assert value <= limit
+    value, limit = result["compared"]["update_gap"]
+    assert value > 1.4 * limit
+    assert f"compared update_gap: {value!r} limit {limit!r} FAILED" in stderr
+    value, limit = result["compared"]["update_pooled_gap"]
+    assert value > 1.8 * limit
 
 
 def test_the_precision_controls_lower_what_they_say():
@@ -79,13 +103,26 @@ def test_the_precision_controls_lower_what_they_say():
 
     w = jnp.asarray([0.3, 1.7, -2.9], jnp.float32)
     lowered = cell._lowered(
-        lambda p, extra, batch, config: (p["w"] * batch).sum(),
-        jnp.float8_e4m3fn)
+        lambda p, extra, batch, config: (p["w"] * batch).sum(), True)
     value, grad = jax.value_and_grad(lowered)(
         {"w": w}, None, jnp.asarray([1.0, 2.0, 4.0]), None)
     rounded = w.astype(jnp.float8_e4m3fn).astype(jnp.float32)
     assert not np.array_equal(rounded, w)
     assert float(value) == float((rounded * jnp.asarray([1., 2., 4.])).sum())
     np.testing.assert_array_equal(grad["w"], [1.0, 2.0, 4.0])
+    # The rounding is by arithmetic (the TPU's compiler elides a cast
+    # down and back up), and is the cast's to the bit: over normals,
+    # subnormals (under 2**-6), ties, the largest value and beyond it.
+    x = jnp.concatenate([
+        jax.random.normal(kx, (4096,)) * 0.02,
+        jax.random.normal(kw, (4096,)) * 30.0,
+        jnp.asarray([0.0, 1.0, -1.0, 2.0 ** -6, 2.0 ** -9, 2.0 ** -10,
+                     1.5 * 2.0 ** -9, 0.0146484375, 1.0625, 1.1875, 448.0,
+                     -448.0, 464.0, 1e4, -1e4, 3e-5])])
+    cast = x.astype(jnp.float8_e4m3fn).astype(jnp.float32)
+    np.testing.assert_array_equal(
+        cell.to_e4m3(x), jnp.where(jnp.abs(x) > 448, jnp.sign(x) * 448, cast))
+    assert float(jnp.abs(cell.to_e4m3(x) - x).max()) > 0
     assert set(cell.CONTROLS) == {"no_routed", "no_window", "bf16_scores",
-                                  "bf16_reference", "fp8_reference"}
+                                  "fp8_params", "bf16_reference",
+                                  "fp8_reference"}

@@ -15,6 +15,9 @@ import pytest
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(os.path.dirname(HERE))
+sys.path.insert(0, HERE)
+
+import tiny_cells  # noqa: E402  (the tiny cells' configurations)
 
 
 def _run(which, chips, *fault):
@@ -58,7 +61,7 @@ def test_tiny_cell_runs_and_prints_the_result_line_last(which, chips):
     # reference reads it). XLA:CPU keeps no memory statistics, so the
     # ``released`` line carries no bytes here.
     assert list(phases) == ["built", "measured", "released",
-                            "reference_entered", "checked"]
+                            "reference_entered", "update_by_leaf", "checked"]
     assert phases["released"] == {"phase": "released"}
     entered = phases["reference_entered"]
     assert entered["state_leaves"] > 0 and entered["state_deleted"]
@@ -81,6 +84,27 @@ def test_tiny_cell_runs_and_prints_the_result_line_last(which, chips):
                                             result["compared"].items()):
         assert line == f"compared {name}: {value!r} limit {limit!r} ok"
     assert result["compared"]["reference"][1] == 0.02  # the cell's own
+    # The parameter change of the three checked steps (six, scan-fused)
+    # against the reference's, on the digest: the worst leaf under the
+    # cell's limits, by a leaf that the table by leaf holds; the digest
+    # is taken in set-up and its seconds are said.
+    limits = tiny_cells.CELLS[which][0]["update_tolerance"]
+    update, by_leaf = checked["update"], phases["update_by_leaf"]
+    assert result["compared"]["update_gap"] == [update["update_gap"],
+                                                limits["rel"]]
+    assert result["compared"]["update_pooled_gap"] == [
+        update["update_pooled_gap"], limits["pooled_rel"]]
+    assert 0 < update["update_gap"] == by_leaf[update["update_gap_leaf"]][
+        "gap"]
+    assert 0 < update["median_leaf_gap"] <= update["update_gap"]
+    assert 0 < update["update_pooled_gap"] <= update["update_gap"]
+    assert update["leaves"] == len(by_leaf) - 1 + len(update["dead_leaves"])
+    # A key's bias under softmax has no gradient but rounding: left out
+    # by the reference's gradient, in the transformer's cells only.
+    assert all("['key']['bias']" in leaf for leaf in update["dead_leaves"])
+    assert len(update["dead_leaves"]) == (0 if which == "resnet" else 2)
+    assert 0 < phases["measured"]["digest_s"] < \
+        result["metrics"]["setup_s"]["value"]
     # Whole windows of 4 steps were counted.
     assert result["attempted"] % 4 == 0
     if which == "flash":
@@ -88,12 +112,15 @@ def test_tiny_cell_runs_and_prints_the_result_line_last(which, chips):
 
 
 @pytest.mark.parametrize("fault,chips,failed", [
-    # the losses repeat: none falls, and the reference's do
-    ("state_unchanged", 1, {"loss_fell", "reference"}),
-    # the mean over half of each chip's rows is another loss
-    ("half_batch", 4, {"reference"}),
+    # the losses repeat: none falls, and the reference's do; nothing
+    # moved, which reads 1 by the worst leaf and over all leaves
+    ("state_unchanged", 1, {"loss_fell", "reference", "update_gap",
+                            "update_pooled_gap"}),
+    # the mean over half of each chip's rows is another loss, and its
+    # gradient moves the parameters another way
+    ("half_batch", 4, {"reference", "update_gap", "update_pooled_gap"}),
     # every chip follows its own gradient: steps 2 and 3 fall too fast
-    ("no_exchange", 4, {"reference"}),
+    ("no_exchange", 4, {"reference", "update_gap", "update_pooled_gap"}),
 ])
 def test_a_broken_timed_path_reads_not_correct(fault, chips, failed):
     """The harness's look for a chip skipped, the rest of a run driven
@@ -110,6 +137,7 @@ def test_a_broken_timed_path_reads_not_correct(fault, chips, failed):
         assert f"compared {name}: {value!r} limit {limit!r} FAILED" \
             in stderr.splitlines()[-len(checks):]
     # By a wide margin at this size, not by rounding.
-    if "reference" in failed:
-        value, limit = result["compared"]["reference"]
-        assert value > 3 * limit
+    for name, times in (("reference", 3), ("update_gap", 2.5),
+                        ("update_pooled_gap", 4)):
+        value, limit = result["compared"][name]
+        assert value >= times * limit

@@ -29,13 +29,20 @@ RESNET = dict(
     family="resnet", stage_sizes=[2, 2], num_filters=8, num_classes=10,
     compute_dtype="bfloat16",
     optimizer=dict(name="sgd", learning_rate=0.01, momentum=0.9),
-    loss_tolerance=dict(abs=0.02))
+    loss_tolerance=dict(abs=0.02),
+    # bf16 convolutions under batch norm over 4 images of 16 x 16: the
+    # worst leaf reads 0.56 here and all leaves together 0.33; a state
+    # left unchanged reads 1 by both
+    update_tolerance=dict(rel=0.9, pooled_rel=0.6))
 BERT = dict(
     family="transformer_lm", hidden_size=32, num_hidden_layers=2,
     num_attention_heads=2, intermediate_size=64, vocab_size=64,
     max_position_embeddings=16, compute_dtype="bfloat16",
     optimizer=dict(name="adamw", learning_rate=1e-3, weight_decay=0.01),
-    loss_tolerance=dict(abs=0.02))
+    loss_tolerance=dict(abs=0.02),
+    # sound tiny runs read at most 0.15 by the worst leaf and 0.073 over
+    # all leaves; the three faults 1.0 to 1.43 and 0.98 to 1.10
+    update_tolerance=dict(rel=0.4, pooled_rel=0.2))
 CELLS = {
     "resnet": (RESNET, dict(TRAFFIC, per_chip_batch=4, image_size=16)),
     "bert": (BERT, dict(TRAFFIC, per_chip_batch=2, seq_len=16,
@@ -91,13 +98,13 @@ def main(which: str, chips: int, fault: str = "", seed: int = 0,
 
     reference_losses = check.reference_losses
 
-    def spy(cell, reference, system, device):
+    def spy(cell, reference, system, *rest):
         leaves = jax.tree.leaves(system.state)
         loop.log(phase="reference_entered", state_leaves=len(leaves),
                  state_deleted=all(x.is_deleted() for x in leaves),
                  compiled_dropped=system.compiled is None,
                  batch_deleted=any(x.is_deleted() for x in system.batch))
-        return reference_losses(cell, reference, system, device)
+        return reference_losses(cell, reference, system, *rest)
 
     check.reference_losses = spy
     if fault == "state_unchanged":

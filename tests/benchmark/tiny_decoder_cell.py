@@ -13,7 +13,10 @@ under that cell at its own size, on the chip only: how ``PERF.md``'s
 readings of what ``correct`` can see were taken. Controls of the timed
 path: ``no_routed``; ``no_window`` (the banded layers attend over every
 earlier position); ``bf16_scores`` (the router wholly in bfloat16: its
-input, weights, logits, softmax, top-k and the weights it hands on).
+input, weights, logits, softmax, top-k and the weights it hands on);
+``fp8_params`` (the loss computed on parameters rounded to float8_e4m3fn,
+gradients straight through: the precision below the stated one, which the
+loss cannot see and the parameter change has to).
 Controls of the reference, which the sound timed path is then compared
 with: ``bf16_reference`` (its products in one bfloat16 pass, as the
 configuration's compute type) and ``fp8_reference`` (besides, every
@@ -47,13 +50,16 @@ DECODER = dict(
     shared_expert_intermediate_size=16, moe_routed_scaling_factor=2.5,
     compute_dtype="bfloat16",
     optimizer=dict(name="adamw", learning_rate=1e-3, weight_decay=0.01),
-    loss_tolerance=dict(abs=0.02))
+    loss_tolerance=dict(abs=0.02),
+    # a sound tiny run reads 0.29 by the worst leaf and 0.15 over all
+    # leaves, ``fp8_params`` 0.77 and 0.60, ``no_routed`` 1.32 and 1.02
+    update_tolerance=dict(rel=0.5, pooled_rel=0.3))
 tiny_cells.CELLS["decoder"] = (DECODER, dict(
     tiny_cells.TRAFFIC, per_chip_batch=2, seq_len=64, attention="flash",
     remat=True))
 
-CONTROLS = ("no_routed", "no_window", "bf16_scores", "bf16_reference",
-            "fp8_reference")
+CONTROLS = ("no_routed", "no_window", "bf16_scores", "fp8_params",
+            "bf16_reference", "fp8_reference")
 
 
 def _bf16_route(x, router_w, top_k, scaling):
@@ -68,19 +74,38 @@ def _bf16_route(x, router_w, top_k, scaling):
     return top_e, weight.astype(jnp.float32)
 
 
-def _lowered(loss, weights_dtype):
-    """The reference's loss with its products in one bfloat16 pass and,
-    given a type, its parameters rounded to it (gradients pass straight
-    through the rounding)."""
+def to_e4m3(x):
+    """``x`` rounded to the nearest value of float8_e4m3fn (4 bits of
+    exponent, 3 of mantissa, subnormals below 2**-6, largest 448), in
+    ``x``'s own type and by arithmetic. Not ``x.astype(float8_e4m3fn)
+    .astype(x.dtype)``: the TPU's compiler takes a cast down and back up
+    for excess precision it may keep, and elides the pair (my chip runs,
+    PR 31: with the casts, the reference's first loss on "float8"
+    parameters equalled the unrounded one to the bit)."""
+    import jax.numpy as jnp
+
+    _, exponent = jnp.frexp(x)  # |x| = m 2**exponent, m in [0.5, 1)
+    step = jnp.ldexp(jnp.ones_like(x), jnp.maximum(exponent - 1, -6) - 3)
+    return jnp.clip(jnp.round(x / step) * step, -448.0, 448.0)
+
+
+def _rounded(params):
+    """``params`` rounded to float8_e4m3fn; gradients pass straight
+    through the rounding."""
     import jax
 
-    def rounded(p):
-        return p + jax.lax.stop_gradient(
-            p.astype(weights_dtype).astype(p.dtype) - p)
+    return jax.tree.map(
+        lambda p: p + jax.lax.stop_gradient(to_e4m3(p) - p), params)
+
+
+def _lowered(loss, float8_weights: bool):
+    """The reference's loss with its products in one bfloat16 pass and,
+    asked so, its parameters rounded to float8_e4m3fn."""
+    import jax
 
     def lowered(params, extra, batch, config):
-        if weights_dtype is not None:
-            params = jax.tree.map(rounded, params)
+        if float8_weights:
+            params = _rounded(params)
         with jax.default_matmul_precision("bfloat16"):
             return loss(params, extra, batch, config)
 
@@ -112,14 +137,26 @@ def plant(control: str):
             q, k, v, causal=causal)
     elif control == "bf16_scores":
         moe._route = _bf16_route
+    elif control == "fp8_params":
+        load_module = spec.load_module
+
+        def load_rounded(kind, name):
+            module = load_module(kind, name)
+            if kind == "families":
+                loss_fn = module.loss_fn
+                module.loss_fn = lambda model, params, *rest: loss_fn(
+                    model, _rounded(params), *rest)
+            return module
+
+        spec.load_module = load_rounded
     elif control in ("bf16_reference", "fp8_reference"):
         load_module = spec.load_module
-        dtype = jnp.float8_e4m3fn if control == "fp8_reference" else None
 
         def load_lowered(kind, name):
             module = load_module(kind, name)
             if kind == "reference":
-                module.loss = _lowered(module.loss, dtype)
+                module.loss = _lowered(module.loss,
+                                       control == "fp8_reference")
             return module
 
         spec.load_module = load_lowered
