@@ -43,12 +43,17 @@ KERNELS = ("flash_fwd_bhsd", "flash_dq_bwd_bhsd", "flash_dkv_bwd_bhsd",
 #: out by expert and gathering the rows; ``moe_experts``: the grouped
 #: products over the experts held, forward and backward; ``moe_combine``:
 #: weighting the rows and adding them back; ``moe_shared``: the shared
-#: expert) and the attention of ``models/decoder.py`` (``attn_rope``: the
-#: rotary positions; ``attn_gate``: the gate on the heads' output). Kept
-#: apart from ``PHASES``, which is the framework's own vocabulary and
-#: which readers hold a copy of.
+#: expert), the attention of ``models/decoder.py`` (``attn_rope``: the
+#: rotary positions; ``attn_gate``: the gate on the heads' output) and the
+#: blocks of ``models/hybrid.py``: its state-space mixer (``ssm_conv``:
+#: the causal depthwise convolution and its activation; ``ssm_scan``:
+#: everything of ``ops/ssd.py``, forward and backward; ``ssm_norm``: the
+#: gated group norm) and the two latent projections round its routed
+#: experts (``moe_latent``). Kept apart from ``PHASES``, which is the
+#: framework's own vocabulary and which readers hold a copy of.
 MODEL_SCOPES = ("moe_route", "moe_dispatch", "moe_experts", "moe_combine",
-                "moe_shared", "attn_rope", "attn_gate")
+                "moe_shared", "attn_rope", "attn_gate", "ssm_conv",
+                "ssm_scan", "ssm_norm", "moe_latent")
 
 #: Stamped on every op of a ``hvd.jax.jit`` step as the frontend
 #: attribute ``hvd_phases``. jax's persistent compile cache keys on the
@@ -56,7 +61,7 @@ MODEL_SCOPES = ("moe_route", "moe_dispatch", "moe_experts", "moe_combine",
 #: cached one only in names would be served the cached executable, old
 #: names and all; the attribute is in the key. Bump it with ``PHASES``,
 #: ``KERNELS``, ``MODEL_SCOPES`` or a move of where a name is emitted.
-VOCABULARY_VERSION = "3"
+VOCABULARY_VERSION = "4"
 
 
 def phase(name: str):

@@ -14,7 +14,9 @@ tensor-fusion stress config of BASELINE.json) with pluggable attention so the
 long-context paths in :mod:`horovod_tpu.parallel` can drop in; and a causal
 decoder built from per-layer specs (:mod:`horovod_tpu.models.decoder`: full
 and window attention over grouped key-value heads, head counts by layer,
-rotary positions, gated heads, dense and sparse-expert SwiGLU MLPs).
+rotary positions, gated heads, dense and sparse-expert SwiGLU MLPs); and a
+hybrid whose blocks are one mixer each (:mod:`horovod_tpu.models.hybrid`:
+state-space mixers with a chunked scan, attention, latent sparse experts).
 
 All models default to bfloat16 compute with float32 parameters — the MXU's
 native mixed precision.
@@ -43,6 +45,7 @@ from horovod_tpu.models.decoder import (  # noqa: F401
     LayerSpec,
     RopeSpec,
 )
+from horovod_tpu.models.hybrid import HybridConfig, HybridLM  # noqa: F401
 
 _REGISTRY = {
     "resnet18": ResNet18,

@@ -68,8 +68,8 @@ class DecoderConfig:
     layers: Tuple[LayerSpec, ...]
     mlp_dim: int                 # the dense layers' SwiGLU width
     window: int
-    rope_full: RopeSpec
-    rope_window: RopeSpec
+    rope_full: Optional[RopeSpec]    # None: no rotary positions
+    rope_window: Optional[RopeSpec]
     num_experts: int = 0         # the router's outputs: all experts
     experts_held: int = 0        # of them, held on this chip
     first_expert: int = 0
@@ -80,6 +80,7 @@ class DecoderConfig:
     rms_eps: float = 1e-6
     dtype: Any = jnp.bfloat16
     remat: bool = False
+    head_gate: bool = True       # the sigmoid gate a head on the output
 
 
 def rope_inv_freq(spec: RopeSpec):
@@ -130,6 +131,16 @@ def _swiglu(x, width, cfg, prefix):
     return _dense(cfg.hidden_dim, cfg, f"{prefix}down")(nn.silu(gate) * up)
 
 
+def stack_counters(kept, elsewhere, held: int):
+    """The routing counters of a call, stacked over its expert layers in
+    order: ``expert_kept`` int32 (layers, held) and ``expert_elsewhere``
+    int32 (layers,); empty where the model has no such layer."""
+    return {"expert_kept": (jnp.stack(kept) if kept
+                            else jnp.zeros((0, max(held, 1)), jnp.int32)),
+            "expert_elsewhere": (jnp.stack(elsewhere) if elsewhere
+                                 else jnp.zeros((0,), jnp.int32))}
+
+
 class GroupedAttention(nn.Module):
     cfg: DecoderConfig
     spec: LayerSpec
@@ -145,15 +156,17 @@ class GroupedAttention(nn.Module):
         v = proj(cfg.num_kv_heads, "value")(h)
         windowed = spec.attention == "window"
         rope = cfg.rope_window if windowed else cfg.rope_full
-        q, k = apply_rope(q, rope), apply_rope(k, rope)
+        if rope is not None:
+            q, k = apply_rope(q, rope), apply_rope(k, rope)
         # pallas loads with the first decoder traced, not with the zoo
         from horovod_tpu.ops import flash_attention as fa
 
         out = fa.flash_attention(
             q, k, v, causal=True, window=cfg.window if windowed else None)
-        with scope("attn_gate"):
-            gate = nn.sigmoid(_dense(spec.num_heads, cfg, "gate")(h))
-            out = out * gate[..., None]
+        if cfg.head_gate:
+            with scope("attn_gate"):
+                gate = nn.sigmoid(_dense(spec.num_heads, cfg, "gate")(h))
+                out = out * gate[..., None]
         return nn.DenseGeneral(cfg.hidden_dim, axis=(-2, -1), use_bias=False,
                                dtype=cfg.dtype, name="out")(out)
 
@@ -231,9 +244,4 @@ class Decoder(nn.Module):
                           name="lm_head")(x)
         if not return_counters:
             return logits
-        held = max(cfg.experts_held, 1)
-        return logits, {
-            "expert_kept": (jnp.stack(kept) if kept
-                            else jnp.zeros((0, held), jnp.int32)),
-            "expert_elsewhere": (jnp.stack(elsewhere) if elsewhere
-                                 else jnp.zeros((0,), jnp.int32))}
+        return logits, stack_counters(kept, elsewhere, cfg.experts_held)

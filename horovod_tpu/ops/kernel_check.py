@@ -1,4 +1,5 @@
-"""Do the pallas kernels compile for the chip, and are they right there?
+"""Do the pallas kernels compile for the chip, and are they and the
+chunked state-space scan right there?
 
     chiprun -- python -m horovod_tpu.ops.kernel_check
 
@@ -8,7 +9,10 @@ flash attention forward and backward (the fused kernel and, over its
 byte rule, the two) against masked softmax in f32 (the reference at full
 f32 matmul precision — the TPU default would round it to bf16), with f32
 inputs and with bf16 ones, and the fused LM-head cross-entropy against
-the chunked XLA scan. The f32 tolerances are the ones
+the chunked XLA scan; and the chunked state-space scan of ``ops/ssd.py``
+(plain XLA, its backward pass written by hand) against the recurrence one
+position after the other in f32, values and all six gradients, at the
+hybrid cell's shape. The f32 tolerances are the ones
 ``tests/test_flash_attention.py`` uses in interpret mode; bf16 inputs are
 held to a share of each result's own scale, as the fused loss is. Exits
 nonzero on the first mismatch, or off a TPU. The CPU tier runs the same
@@ -31,6 +35,7 @@ from horovod_tpu.ops.flash_attention import (
     flash_attention,
     fused_backward_fits,
 )
+from horovod_tpu.ops.ssd import ssd_scan
 
 # (batch, seq, heads, head_dim, causal, window, key-value heads):
 # BERT-base's attention, a long causal sequence, and the decoder cell's
@@ -47,6 +52,13 @@ FLASH_SHAPES = ((8, 512, 12, 64, False, None, 12),
 BF16_SHARE = 2e-2
 # (tokens, hidden, vocab): BERT-base bs8 x seq512 into its LM head.
 LOSS_SHAPE = (4096, 768, 30522)
+# (batch, positions, heads, head size, groups, state size, chunk): one
+# chip's share of the hybrid cell's state-space mixer.
+SSD_SHAPE = (1, 8192, 32, 64, 2, 128, 128)
+# Worst error of the scan in f32 as a share of the result's largest
+# entry: both sides are f32 throughout and differ by the order of sums
+# over up to 8,192 positions and by the TPU's f32 ``exp``.
+SSD_F32_SHARE = 1e-4
 
 
 def _value_and_grads(fn, **kw):
@@ -147,6 +159,75 @@ def check_fused_loss(n, hidden, vocab, interpret=False, **blocks):
         assert worst < 1e-2, f"{name}: max error {worst:.2e} of its scale"
 
 
+def sequential_scan(x, dt, a, b, c, d, segment=128):
+    """h_t = exp(dt_t A) h_{t-1} + dt_t B_t (x) x_t, y_t = C_t . h_t + D x_t
+    one position after the other in f32, head h with group h // (heads /
+    groups); the positions in checkpointed segments, so that the backward
+    pass keeps a segment's states and not all 8,192."""
+    x, dt, b, c = (t.astype(jnp.float32) for t in (x, dt, b, c))
+    bsz, t, heads, p = x.shape
+    groups = b.shape[2]
+    x = x.reshape(bsz, t, groups, heads // groups, p)
+    dt = dt.reshape(bsz, t, groups, -1)
+    a, d = a.reshape(groups, -1), d.reshape(groups, -1)
+
+    def position(h, at):  # h (batch, G, K, P, N)
+        x_t, dt_t, b_t, c_t = at
+        h = (jnp.exp(dt_t * a)[..., None, None] * h
+             + (dt_t[..., None] * x_t)[..., None] * b_t[:, :, None, None])
+        return h, (h * c_t[:, :, None, None]).sum(-1) + d[..., None] * x_t
+
+    @jax.checkpoint
+    def run(h, part):
+        return jax.lax.scan(position, h, part)
+
+    segment = min(segment, t)
+    parts = jax.tree.map(
+        lambda v: jnp.moveaxis(v, 1, 0).reshape(
+            t // segment, segment, bsz, *v.shape[2:]), (x, dt, b, c))
+    h0 = jnp.zeros((bsz, groups, heads // groups, p, b.shape[3]))
+    _, y = jax.lax.scan(run, h0, parts)
+    return jnp.moveaxis(y.reshape(t, bsz, heads, p), 0, 1)
+
+
+def check_ssd(bsz, t, heads, p, groups, n, chunk, dtype=jnp.float32):
+    """``ssd_scan`` and its six gradients against :func:`sequential_scan`
+    on the same (rounded) inputs; returns each result's worst error as a
+    share of its largest entry."""
+    ks = jax.random.split(jax.random.PRNGKey(0), 7)
+    args = (jax.random.normal(ks[0], (bsz, t, heads, p), dtype),
+            # step sizes and decay rates over the model's initial ranges
+            jnp.exp(jax.random.uniform(ks[1], (bsz, t, heads),
+                                       minval=np.log(1e-3),
+                                       maxval=np.log(1e-1))),
+            -jax.random.uniform(ks[2], (heads,), minval=1.0, maxval=16.0),
+            jax.random.normal(ks[3], (bsz, t, groups, n), dtype),
+            jax.random.normal(ks[4], (bsz, t, groups, n), dtype),
+            jax.random.normal(ks[5], (heads,)))
+    mix = jax.random.normal(ks[6], (bsz, t, heads, p))
+
+    def total(fn):
+        def loss(*a):  # the six arguments, then the mix
+            y = fn(*a[:6]).astype(jnp.float32)
+            return jnp.sum(y * a[6]), y
+        return jax.jit(jax.value_and_grad(loss, argnums=range(6),
+                                          has_aux=True))
+
+    (_, out), grads = total(
+        lambda *a: ssd_scan(*a, chunk=chunk))(*args, mix)
+    (_, out_ref), grads_ref = total(sequential_scan)(*args, mix)
+    limit = SSD_F32_SHARE if dtype == jnp.float32 else BF16_SHARE
+    shares = {}
+    for name, got, want in zip(("y", "dx", "ddt", "da", "db", "dc", "dd"),
+                               (out,) + grads, (out_ref,) + grads_ref):
+        got, want = (np.asarray(v, np.float32) for v in (got, want))
+        shares[name] = float(np.abs(got - want).max() / np.abs(want).max())
+        assert shares[name] < limit, (
+            f"{name}: max error {shares[name]:.2e} of its scale, "
+            f"limit {limit:.0e}")
+    return shares
+
+
 def main() -> int:
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -164,6 +245,12 @@ def main() -> int:
                   f"compiled, matches the f32 reference (backward: "
                   f"{backward}; worst error by its scale: {worst}; "
                   f"{dev.device_kind})", flush=True)
+    for dtype in (jnp.float32, jnp.bfloat16):
+        shares = check_ssd(*SSD_SHAPE, dtype=dtype)
+        worst = ", ".join(f"{n} {e:.1e}" for n, e in shares.items())
+        print(f"ssd_scan fwd+bwd {SSD_SHAPE} {dtype.__name__}: matches the "
+              f"sequential f32 recurrence (worst error by its scale: "
+              f"{worst}; {dev.device_kind})", flush=True)
     check_fused_loss(*LOSS_SHAPE)
     print(f"fused_softmax_cross_entropy fwd+bwd {LOSS_SHAPE}: compiled, "
           f"matches the chunked scan ({dev.device_kind})", flush=True)

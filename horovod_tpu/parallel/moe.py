@@ -116,12 +116,38 @@ def moe_layer(
 # One chip's share of a top-k expert layer: no capacity, nothing dropped
 # ---------------------------------------------------------------------------
 
-def _swiglu_block(xb, w_gate, w_up):
-    """(a, u) of one block of rows through one expert's two input
-    products, f32 accumulation."""
+def _hidden(xb, w_gate, w_up):
+    """One block of rows through one expert's input products, f32
+    accumulation: ``(h, saved)``, h the hidden activation in float32.
+    With a gate matrix the expert is SwiGLU, ``silu(x Wg) * (x Wu)``;
+    without one (``w_gate`` None) it is ``relu(x Wu)^2``."""
+    if w_gate is None:
+        r = jax.nn.relu(
+            jnp.dot(xb, w_up, preferred_element_type=jnp.float32))
+        return r * r, (r,)
     a = jnp.dot(xb, w_gate, preferred_element_type=jnp.float32)
     u = jnp.dot(xb, w_up, preferred_element_type=jnp.float32)
-    return a, u
+    s = jax.nn.sigmoid(a)
+    return a * s * u, (a, s, u)
+
+
+def _hidden_grads(dh, xb, w_gate, w_up, saved):
+    """``(dxb, dWg, dWu)`` of one block from ``dh`` (float32); ``dWg`` is
+    None for an expert without a gate."""
+    dtype = xb.dtype
+    if w_gate is None:
+        (r,) = saved
+        du = (dh * 2.0 * r).astype(dtype)
+        return (jnp.dot(du, w_up.T, preferred_element_type=jnp.float32),
+                None,
+                jnp.dot(xb.T, du, preferred_element_type=jnp.float32))
+    a, s, u = saved
+    da = (dh * u * (s * (1.0 + a * (1.0 - s)))).astype(dtype)
+    du = (dh * (a * s)).astype(dtype)
+    dxb = (jnp.dot(da, w_gate.T, preferred_element_type=jnp.float32)
+           + jnp.dot(du, w_up.T, preferred_element_type=jnp.float32))
+    return (dxb, jnp.dot(xb.T, da, preferred_element_type=jnp.float32),
+            jnp.dot(xb.T, du, preferred_element_type=jnp.float32))
 
 
 def _block_operands(b, x, w_gate, w_up, w_down, rows, block_expert):
@@ -130,7 +156,8 @@ def _block_operands(b, x, w_gate, w_up, w_down, rows, block_expert):
         r = rows[b]
         xb = x[r]
     g = block_expert[b]
-    return r, xb, g, w_gate[g], w_up[g], w_down[g]
+    return (r, xb, g, None if w_gate is None else w_gate[g], w_up[g],
+            w_down[g])
 
 
 @jax.custom_vjp
@@ -140,14 +167,15 @@ def _expert_blocks(x, w_gate, w_up, w_down, slot_weight, rows, block_expert,
     (tokens, hidden) f32. The assignments lie sorted by expert in blocks
     of ``rows.shape[1]`` rows, each block one expert's (``block_expert``);
     only the first ``n_blocks`` hold any, and only those are computed: the
-    work follows what the router sent, not the most it could send."""
+    work follows what the router sent, not the most it could send.
+    ``w_gate`` None: the experts have no gate (``_hidden``)."""
     def body(b, y):
         r, xb, _, wg, wu, wd = _block_operands(b, x, w_gate, w_up, w_down,
                                                rows, block_expert)
         with scope("moe_experts"):
-            a, u = _swiglu_block(xb, wg, wu)
-            h = (jax.nn.silu(a) * u).astype(x.dtype)
-            ob = jnp.dot(h, wd, preferred_element_type=jnp.float32)
+            h, _ = _hidden(xb, wg, wu)
+            ob = jnp.dot(h.astype(x.dtype), wd,
+                         preferred_element_type=jnp.float32)
         with scope("moe_combine"):
             return y.at[r].add(slot_weight[b][:, None] * ob)
 
@@ -176,35 +204,31 @@ def _expert_blocks_bwd(res, dy):
             dyb = dy[r]
             dob = (slot_weight[b][:, None] * dyb).astype(x.dtype)
         with scope("moe_experts"):
-            a, u = _swiglu_block(xb, wg, wu)
-            s = jax.nn.sigmoid(a)
-            silu = a * s
-            h = silu * u
+            h, saved = _hidden(xb, wg, wu)
             # d(weight) = dy . E(x) = (dy Wd^T) . h, with no product more
             dh_unweighted = jnp.dot(dyb.astype(x.dtype), wd.T,
                                     preferred_element_type=jnp.float32)
             dweight = dweight.at[b].set((dh_unweighted * h).sum(-1))
-            dh = slot_weight[b][:, None] * dh_unweighted
-            da = (dh * u * (s * (1.0 + a * (1.0 - s)))).astype(x.dtype)
-            du = (dh * silu).astype(x.dtype)
-            hb = h.astype(x.dtype)
+            dxb, dwg_b, dwu_b = _hidden_grads(
+                slot_weight[b][:, None] * dh_unweighted, xb, wg, wu, saved)
             dwd = dwd.at[g].add(jnp.dot(
-                hb.T, dob, preferred_element_type=jnp.float32))
-            dwg = dwg.at[g].add(jnp.dot(
-                xb.T, da, preferred_element_type=jnp.float32))
-            dwu = dwu.at[g].add(jnp.dot(
-                xb.T, du, preferred_element_type=jnp.float32))
-            dxb = (jnp.dot(da, wg.T, preferred_element_type=jnp.float32)
-                   + jnp.dot(du, wu.T, preferred_element_type=jnp.float32))
+                h.astype(x.dtype).T, dob,
+                preferred_element_type=jnp.float32))
+            if dwg is not None:
+                dwg = dwg.at[g].add(dwg_b)
+            dwu = dwu.at[g].add(dwu_b)
         with scope("moe_dispatch"):
             dx = dx.at[r].add(dxb)
         return dx, dwg, dwu, dwd, dweight
 
-    zeros = lambda a: jnp.zeros(a.shape, jnp.float32)  # noqa: E731
+    def zeros(a):
+        return None if a is None else jnp.zeros(a.shape, jnp.float32)
+
     dx, dwg, dwu, dwd, dweight = lax.fori_loop(
         0, n_blocks, body, (zeros(x), zeros(w_gate), zeros(w_up),
                             zeros(w_down), zeros(slot_weight)))
-    return (dx.astype(x.dtype), dwg.astype(w_gate.dtype),
+    return (dx.astype(x.dtype),
+            None if dwg is None else dwg.astype(w_gate.dtype),
             dwu.astype(w_up.dtype), dwd.astype(w_down.dtype),
             dweight.astype(slot_weight.dtype), None, None, None)
 
@@ -222,22 +246,47 @@ def _route(x, router_w, top_k: int, scaling: float):
     return top_e, scaling * top_p / top_p.sum(-1, keepdims=True)
 
 
+def sigmoid_route(bias):
+    """A router for :func:`expert_share_layer`'s ``route``: float32
+    sigmoid scores over ALL experts; a token's top-k are chosen by score
+    plus ``bias`` (n_experts,), a correction that only moves the choice
+    (no gradient reaches it); the weights are the scores of the chosen,
+    without the bias, renormalised to ``scaling``."""
+    def route(x, router_w, top_k: int, scaling: float):
+        logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        scores = jax.nn.sigmoid(logits)
+        _, top_e = lax.top_k(
+            scores + lax.stop_gradient(bias.astype(jnp.float32)), top_k)
+        top_s = jnp.take_along_axis(scores, top_e, axis=-1)
+        return top_e, scaling * top_s / top_s.sum(-1, keepdims=True)
+
+    return route
+
+
 def expert_share_layer(x, router_w, w_gate, w_up, w_down, *,
                        first_expert: int, top_k: int, scaling: float = 1.0,
-                       block_rows: int = 256):
-    """One chip's share of a top-k softmax-routed expert layer under
-    expert parallelism: it is told which experts it holds, routes over all
-    of them, keeps every assignment to an expert it holds (no capacity, no
+                       block_rows: int = 256, route=None, router_x=None):
+    """One chip's share of a top-k routed expert layer under expert
+    parallelism: it is told which experts it holds, routes over all of
+    them, keeps every assignment to an expert it holds (no capacity, no
     drop) and returns the part of the layer's result its experts give.
 
     Args:
       x: (tokens, hidden) this chip's tokens, in the compute dtype.
       router_w: (hidden, n_experts) router over ALL experts (float32).
       w_gate, w_up: (held, hidden, ff); w_down: (held, ff, hidden): the
-        SwiGLU experts ``first_expert .. first_expert + held - 1``, in the
-        compute dtype.
-      top_k: experts a token is sent to; its weights are the top-k
-        softmax probabilities renormalised to ``scaling``.
+        experts ``first_expert .. first_expert + held - 1``, in the
+        compute dtype: SwiGLU, or with ``w_gate`` None the two-matrix
+        ``Wd relu(Wu x)^2``.
+      top_k: experts a token is sent to.
+      route: ``(x, router_w, top_k, scaling) -> (experts (tokens, k)
+        int32, weights (tokens, k) float32)``. None is the softmax
+        router: the top-k softmax probabilities renormalised to
+        ``scaling``; :func:`sigmoid_route` makes the other.
+      router_x: (tokens, router width) what the router scores, where that
+        is not ``x``: experts that work in a latent are routed on the
+        layer's full-width input.
 
     Returns ``(y, (kept, elsewhere))``: y (tokens, hidden) in x's dtype,
     the sum over the token's top-k experts held here of weight x expert;
@@ -258,9 +307,10 @@ def expert_share_layer(x, router_w, w_gate, w_up, w_down, *,
     2.9 GB of temporaries against 0.4 (PERF.md, PR 27).
     """
     t, _ = x.shape
-    held = w_gate.shape[0]
+    held = w_up.shape[0]
     with scope("moe_route"):
-        top_e, weight = _route(x, router_w, top_k, scaling)
+        top_e, weight = (route or _route)(
+            x if router_x is None else router_x, router_w, top_k, scaling)
 
     with scope("moe_dispatch"):
         local = (top_e - first_expert).reshape(-1)        # (t*k,)
