@@ -264,6 +264,32 @@ def sigmoid_route(bias):
     return route
 
 
+@jax.custom_vjp
+def _held_first(mine, w):
+    """Each held expert's tokens first, in token order, by one sort along
+    the tokens of (held, tokens) ``mine`` and weights ``w``: the rows'
+    tokens (0 past the expert's own) and their weights."""
+    return _held_first_fwd(mine, w)[0]
+
+
+def _held_first_fwd(mine, w):
+    n = mine.shape[1]
+    token = lax.broadcasted_iota(jnp.int32, mine.shape, 1)
+    key, sorted_w = lax.sort((jnp.where(mine, token, n + token), w),
+                             dimension=1, is_stable=False, num_keys=1)
+    return (jnp.where(key < n, key, 0), sorted_w), key % n
+
+
+def _held_first_bwd(order, cotangents):
+    # A row of ``order`` is a permutation of the tokens: sorting by it puts
+    # d(w) back in token order, where the sort's transpose would scatter.
+    return None, lax.sort((order, cotangents[1]), dimension=1,
+                          is_stable=False, num_keys=1)[1]
+
+
+_held_first.defvjp(_held_first_fwd, _held_first_bwd)
+
+
 def expert_share_layer(x, router_w, w_gate, w_up, w_down, *,
                        first_expert: int, top_k: int, scaling: float = 1.0,
                        block_rows: int = 256, route=None, router_x=None):
@@ -295,16 +321,17 @@ def expert_share_layer(x, router_w, w_gate, w_up, w_down, *,
     experts would add is another chip's to compute: no exchange is traced
     and nothing stands in for it.
 
-    The kept assignments are laid out by expert, each expert's rows padded
-    to whole blocks of ``block_rows``, and a loop over the blocks in use
-    multiplies each by its expert's matrices (a sort-and-segment grouped
-    product; the loop's length follows the routing, so a skewed router
-    costs time, never tokens). ``jax.lax.ragged_dot`` on the same sorted
-    rows needs a buffer for the most any routing can keep, tokens x
-    min(top_k, held) rows, 25 times what a uniform router sends at 8 of
-    256 experts, and leaves the rows past the groups unwritten in every
-    product of its backward pass: 3.5 times this loop's time on a v5e and
-    2.9 GB of temporaries against 0.4 (PERF.md, PR 27).
+    The kept assignments are laid out in a (held, tokens) table (a token's
+    top-k experts are distinct, so it loses nothing): one sort along the tokens
+    puts each expert's own first, in whole blocks of ``block_rows``
+    (:func:`_held_first`; no scatter, nothing of tokens x top_k but compares),
+    and a loop over the blocks in use multiplies each by its expert's matrices:
+    its length follows the routing, so a skewed router costs time, never
+    tokens. ``jax.lax.ragged_dot`` on the same rows needs a buffer for the most
+    any routing can keep, tokens x min(top_k, held) rows, 25 times what a
+    uniform router sends at 8 of 256 experts, and leaves the rows past the
+    groups unwritten in every product of its backward pass: 3.5 times this
+    loop's time on a v5e, 2.9 GB of temporaries against 0.4 (PERF.md, PR 27).
     """
     t, _ = x.shape
     held = w_up.shape[0]
@@ -313,34 +340,27 @@ def expert_share_layer(x, router_w, w_gate, w_up, w_down, *,
             x if router_x is None else router_x, router_w, top_k, scaling)
 
     with scope("moe_dispatch"):
-        local = (top_e - first_expert).reshape(-1)        # (t*k,)
-        mine = (local >= 0) & (local < held)
-        onehot = (local[:, None] == jnp.arange(held)) & mine[:, None]
-        onehot = onehot.astype(jnp.int32)                 # (t*k, held)
-        kept = onehot.sum(0)
+        # An expert's segment of the table is whole blocks of tokens long.
+        pad = ((0, -t % block_rows), (0, 0))
+        hit = (jnp.pad(top_e - first_expert, pad, constant_values=-1)
+               == jnp.arange(held)[:, None, None])        # (held, t, k)
+        mine = hit.any(-1)
+        kept = mine.sum(-1, dtype=jnp.int32)
         elsewhere = t * top_k - kept.sum()
-        # Each expert's rows start on a block boundary.
+        rows, slot_weight = _held_first(
+            mine, jnp.where(hit, jnp.pad(weight, pad), 0.0).sum(-1))
         blocks_of = (kept + block_rows - 1) // block_rows
         ends = jnp.cumsum(blocks_of)
-        n_blocks = ends[-1]
-        # The most blocks any routing can need: a token reaches an expert
-        # once, and each expert's last block may be part empty.
-        max_blocks = (t * min(top_k, held)) // block_rows + held
-        position = ((jnp.cumsum(onehot, axis=0) - 1) * onehot).sum(-1)
-        start = (ends - blocks_of) * block_rows
-        slot = jnp.where(mine, start[jnp.clip(local, 0, held - 1)] + position,
-                         max_blocks * block_rows)         # out of range
-        token = jnp.arange(t * top_k, dtype=jnp.int32) // top_k
-        # Empty places read token 0 with weight 0.
-        rows = jnp.zeros((max_blocks * block_rows,), jnp.int32).at[slot].set(
-            token, mode="drop").reshape(max_blocks, block_rows)
-        slot_weight = jnp.zeros((max_blocks * block_rows,), jnp.float32).at[
-            slot].set(weight.reshape(-1), mode="drop").reshape(
-                max_blocks, block_rows)
-        block_expert = jnp.minimum(
-            jnp.searchsorted(ends, jnp.arange(max_blocks), side="right"),
-            held - 1).astype(jnp.int32)
+        # Block b is block b - (the blocks before its expert) of its
+        # expert's segment; the blocks past ends[-1] are never read.
+        b = jnp.arange(mine.size // block_rows)
+        block_expert = jnp.minimum(jnp.searchsorted(ends, b, side="right"),
+                                   held - 1).astype(jnp.int32)
+        at = jnp.minimum(block_expert * (b.size // held) + b
+                         - (ends - blocks_of)[block_expert], b[-1])
+        rows = rows.reshape(-1, block_rows)[at]
+        slot_weight = slot_weight.reshape(-1, block_rows)[at]
 
     y = _expert_blocks(x, w_gate, w_up, w_down, slot_weight, rows,
-                       block_expert, n_blocks)
+                       block_expert, ends[-1])
     return y.astype(x.dtype), (kept, elsewhere)

@@ -15,7 +15,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 from benchmark.harness import spec  # noqa: E402
-from horovod_tpu.parallel.moe import expert_share_layer  # noqa: E402
+from horovod_tpu.parallel.moe import (  # noqa: E402
+    expert_share_layer, sigmoid_route)
 
 reference = spec.load_module("reference", "decoder_lm")
 
@@ -137,3 +138,134 @@ def test_gradients_flow_to_every_argument(weights):
     for a, b in zip(got, want):
         assert float(jnp.abs(b).max()) > 0
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# The layout of the kept assignments: tokens x held, one sort
+# ---------------------------------------------------------------------------
+
+def _plain_share(x, router, gate, up, down, *, first, top_k, scaling,
+                 bias=None, router_x=None):
+    """Token by token the sum over its chosen experts held here of weight
+    x expert, with no layout: every held expert on every token, times a
+    dense (tokens, experts) table of weights that is zero off the top-k.
+    ``bias`` None is the softmax router, else the sigmoid one."""
+    logits = (x if router_x is None else router_x) @ router
+    scores = (jax.nn.softmax(logits, axis=-1) if bias is None
+              else jax.nn.sigmoid(logits))
+    _, top_e = jax.lax.top_k(scores if bias is None else scores + bias,
+                             top_k)
+    chosen = jax.nn.one_hot(top_e, router.shape[1]).sum(1) * scores
+    weight = scaling * chosen / chosen.sum(-1, keepdims=True)
+    y = jnp.zeros_like(x)
+    for e in range(up.shape[0]):
+        h = (jax.nn.relu(x @ up[e]) ** 2 if gate is None
+             else jax.nn.silu(x @ gate[e]) * (x @ up[e]))
+        y = y + weight[:, first + e, None] * (h @ down[e])
+    return y, top_e
+
+
+# tokens, block_rows, first_expert, and the router bent: (an expert, the
+# weight of a constant feature on its column)
+LAYOUTS = {
+    "tokens_not_a_multiple_of_the_block": (100, 8, 4, None),
+    "fewer_tokens_than_one_block": (50, 64, 0, None),
+    "a_held_expert_nobody_chose": (96, 8, 4, (6, -30.0)),
+    "every_token_on_one_held_expert": (96, 8, 4, (5, 30.0)),
+    "the_last_share": (96, 16, 12, None),
+}
+FORMS = {"softmax_swiglu": (False, True), "sigmoid_relu2": (True, False),
+         "sigmoid_swiglu": (True, True)}
+
+
+@pytest.mark.parametrize("form", FORMS)
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_the_layout_gives_the_plain_sum_and_its_gradients(layout, form):
+    t, block_rows, first, bent = LAYOUTS[layout]
+    sigmoid, gated = FORMS[form]
+    ks = jax.random.split(jax.random.PRNGKey(11), 8)
+    width = 24 if sigmoid else H  # the sigmoid router scores another input
+    x = jax.random.normal(ks[0], (t, H))
+    router = jax.random.normal(ks[1], (width, EXPERTS))
+    router_x = jax.random.normal(ks[2], (t, width)) if sigmoid else None
+    bias = jax.random.normal(ks[3], (EXPERTS,)) * 0.1 if sigmoid else None
+    if bent:
+        if sigmoid:
+            router_x = router_x.at[:, -1].set(1.0)
+        else:
+            x = x.at[:, -1].set(1.0)
+        router = router.at[-1, bent[0]].set(bent[1])
+    gate = (jax.random.normal(ks[4], (HELD, H, F)) * 0.3 if gated else None)
+    up = jax.random.normal(ks[5], (HELD, H, F)) * 0.3
+    down = jax.random.normal(ks[6], (HELD, F, H)) * 0.3
+    cot = jax.random.normal(ks[7], (t, H))
+
+    def ours(x, router, gate, up, down, router_x):
+        y, counts = expert_share_layer(
+            x, router, gate, up, down, first_expert=first, top_k=TOP_K,
+            scaling=SCALING, block_rows=block_rows, router_x=router_x,
+            route=sigmoid_route(bias) if sigmoid else None)
+        return (y * cot).sum(), (y, counts)
+
+    def plain(x, router, gate, up, down, router_x):
+        y, top_e = _plain_share(x, router, gate, up, down, first=first,
+                                top_k=TOP_K, scaling=SCALING, bias=bias,
+                                router_x=router_x)
+        return (y * cot).sum(), (y, top_e)
+
+    args = (x, router, gate, up, down, router_x)
+    wrt = [i for i, a in enumerate(args) if a is not None]
+    with jax.default_matmul_precision("highest"):
+        got, (y, (kept, elsewhere)) = jax.grad(
+            ours, argnums=wrt, has_aux=True)(*args)
+        want, (y_plain, top_e) = jax.grad(
+            plain, argnums=wrt, has_aux=True)(*args)
+    count = np.bincount(np.asarray(top_e).reshape(-1), minlength=EXPERTS)
+    np.testing.assert_array_equal(kept, count[first:first + HELD])
+    assert int(elsewhere) == t * TOP_K - int(kept.sum())
+    if bent:
+        assert int(kept[bent[0] - first]) == (t if bent[1] > 0 else 0)
+    np.testing.assert_allclose(y, y_plain, rtol=1e-4, atol=1e-4)
+    for i, a, b in zip(wrt, got, want):
+        assert float(jnp.abs(b).max()) > 0, i  # every case keeps something
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
+def _index_counts(jaxpr, found):
+    """(primitive, number of indices) of every scatter and gather of a
+    jaxpr and the jaxprs under it, but a ``while``'s: the loop over the
+    blocks is the one loop whose length the data sets."""
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if name == "gather" or name.startswith("scatter"):
+            found.append((name, int(np.prod(eqn.invars[1].aval.shape[:-1]))))
+        if name == "while":
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _index_counts(sub, found)
+    return found
+
+
+def test_no_scatter_or_gather_outside_the_block_loop_is_tokens_by_top_k():
+    """The layout works on tokens x held: with the router's choice handed
+    in (so that every indexed op left is the layout's) no scatter or
+    gather of the gradient's program has tokens x top_k indices."""
+    t, held, top_k, block_rows = 64, 2, 6, 8
+    ks = jax.random.split(jax.random.PRNGKey(3), 5)
+    x = jax.random.normal(ks[0], (t, H))
+    _, top_e = jax.lax.top_k(jax.random.normal(ks[1], (t, EXPERTS)), top_k)
+    weight = jax.random.uniform(ks[2], (t, top_k))
+    up = jax.random.normal(ks[3], (held, H, F))
+    down = jax.random.normal(ks[4], (held, F, H))
+
+    def loss(x, weight, up, down):
+        y, _ = expert_share_layer(
+            x, None, None, up, down, first_expert=2, top_k=top_k,
+            block_rows=block_rows, route=lambda *_: (top_e, weight))
+        return y.sum()
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2, 3)))(
+        x, weight, up, down)
+    found = _index_counts(jaxpr.jaxpr, [])
+    assert found, "the rows of a block are gathered somewhere"
+    assert max(n for _, n in found) < t * held < t * top_k, found
