@@ -49,11 +49,20 @@ KERNELS = ("flash_fwd_bhsd", "flash_dq_bwd_bhsd", "flash_dkv_bwd_bhsd",
 #: the causal depthwise convolution and its activation; ``ssm_scan``:
 #: everything of ``ops/ssd.py``, forward and backward; ``ssm_norm``: the
 #: gated group norm) and the two latent projections round its routed
-#: experts (``moe_latent``). Kept apart from ``PHASES``, which is the
-#: framework's own vocabulary and which readers hold a copy of.
+#: experts (``moe_latent``), and the mixers of ``models/sambay.py``
+#: (``sel_scan``: everything of ``ops/selective_scan.py``, forward and
+#: backward; ``attn_diff``: what differential attention does outside its
+#: kernels and projections, the pairing of heads, lambda, the difference
+#: and the norm over a pair; ``gmu``: the whole gated memory unit, its two
+#: projections and the gate ``silu(.) * memory`` between them, which the
+#: compiler fuses into them; its Mamba mixer's convolution goes under
+#: ``ssm_conv``). Kept apart from ``PHASES``,
+#: which is the framework's own vocabulary and which readers hold a copy
+#: of.
 MODEL_SCOPES = ("moe_route", "moe_dispatch", "moe_experts", "moe_combine",
                 "moe_shared", "attn_rope", "attn_gate", "ssm_conv",
-                "ssm_scan", "ssm_norm", "moe_latent")
+                "ssm_scan", "ssm_norm", "moe_latent", "sel_scan",
+                "attn_diff", "gmu")
 
 #: Stamped on every op of a ``hvd.jax.jit`` step as the frontend
 #: attribute ``hvd_phases``. jax's persistent compile cache keys on the
@@ -61,7 +70,7 @@ MODEL_SCOPES = ("moe_route", "moe_dispatch", "moe_experts", "moe_combine",
 #: cached one only in names would be served the cached executable, old
 #: names and all; the attribute is in the key. Bump it with ``PHASES``,
 #: ``KERNELS``, ``MODEL_SCOPES`` or a move of where a name is emitted.
-VOCABULARY_VERSION = "4"
+VOCABULARY_VERSION = "6"
 
 
 def phase(name: str):

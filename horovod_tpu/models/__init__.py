@@ -46,6 +46,7 @@ from horovod_tpu.models.decoder import (  # noqa: F401
     RopeSpec,
 )
 from horovod_tpu.models.hybrid import HybridConfig, HybridLM  # noqa: F401
+from horovod_tpu.models.sambay import SambaYConfig, SambaYLM  # noqa: F401
 
 _REGISTRY = {
     "resnet18": ResNet18,

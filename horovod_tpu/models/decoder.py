@@ -141,6 +141,17 @@ def stack_counters(kept, elsewhere, held: int):
                                  else jnp.zeros((0,), jnp.int32))}
 
 
+def causal_attention(q, k, v, window=None):
+    """Causal attention of (batch, seq, heads, head size) through the
+    flash kernels, over the last ``window`` keys where one is given;
+    ``k`` and ``v`` may carry a divisor of ``q``'s heads (query head h
+    reads key-value head h // group)."""
+    # pallas loads with the first decoder traced, not with the zoo
+    from horovod_tpu.ops import flash_attention as fa
+
+    return fa.flash_attention(q, k, v, causal=True, window=window)
+
+
 class GroupedAttention(nn.Module):
     cfg: DecoderConfig
     spec: LayerSpec
@@ -158,11 +169,7 @@ class GroupedAttention(nn.Module):
         rope = cfg.rope_window if windowed else cfg.rope_full
         if rope is not None:
             q, k = apply_rope(q, rope), apply_rope(k, rope)
-        # pallas loads with the first decoder traced, not with the zoo
-        from horovod_tpu.ops import flash_attention as fa
-
-        out = fa.flash_attention(
-            q, k, v, causal=True, window=cfg.window if windowed else None)
+        out = causal_attention(q, k, v, cfg.window if windowed else None)
         if cfg.head_gate:
             with scope("attn_gate"):
                 gate = nn.sigmoid(_dense(spec.num_heads, cfg, "gate")(h))

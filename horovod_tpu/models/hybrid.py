@@ -111,6 +111,19 @@ def _a_log_init(key, shape, dtype=jnp.float32):
                                       16.0)).astype(dtype)
 
 
+def causal_conv_silu(x, taps, bias, dtype):
+    """A depthwise causal convolution over (batch, t, channels) and its
+    SiLU, under ``ssm_conv``: tap k of ``taps`` (kernel, channels) reads
+    position t - (kernel - 1 - k). Float32 inside, ``dtype`` out."""
+    with scope("ssm_conv"):
+        kernel, t = taps.shape[0], x.shape[1]
+        padded = jnp.pad(x.astype(jnp.float32),
+                         ((0, 0), (kernel - 1, 0), (0, 0)))
+        conv = bias + sum(taps[k] * padded[:, k:k + t]
+                          for k in range(kernel))
+        return nn.silu(conv).astype(dtype)
+
+
 class SSMMixer(nn.Module):
     """The state-space mixer over the heads and groups held."""
 
@@ -129,14 +142,7 @@ class SSMMixer(nn.Module):
                             (cfg.conv_kernel, inner + 2 * bc), jnp.float32)
         conv_b = self.param("conv_bias", nn.initializers.zeros,
                             (inner + 2 * bc,), jnp.float32)
-        with scope("ssm_conv"):
-            # depthwise and causal: tap k reads position t - (kernel-1-k)
-            t = xbc.shape[1]
-            padded = jnp.pad(xbc.astype(jnp.float32),
-                             ((0, 0), (cfg.conv_kernel - 1, 0), (0, 0)))
-            conv = conv_b + sum(conv_w[k] * padded[:, k:k + t]
-                                for k in range(cfg.conv_kernel))
-            xbc = nn.silu(conv).astype(cfg.dtype)
+        xbc = causal_conv_silu(xbc, conv_w, conv_b, cfg.dtype)
         x, b, c = jnp.split(xbc, [inner, inner + bc], axis=-1)
 
         dt_bias = self.param("dt_bias", _dt_bias_init(cfg), (heads,),

@@ -157,6 +157,10 @@ FORWARD_CASES = {
                                          blocks=(32, 16)),
     "narrow_k_block_wide_head": _case(64, 64, 1, blocks=(16, 16)),
     "odd_block_wider_than_a_tile": _case(400, 128, 1, blocks=(200, 200)),
+    # differential attention's banded layers: head size 64, the window of
+    # 512 over blocks of 512, two query heads a key-value head
+    "window512_d64_group2": _case(1024, 64, 1, group=2, window=512,
+                                  blocks=(512, 512)),
 }
 
 
@@ -312,6 +316,9 @@ BACKWARD_CASES = {
                                               blocks=(32, 16)),
     "window_unequal_blocks_wide_k": _bwd_case(window=24, group=6, d=8,
                                               blocks=(16, 32)),
+    # differential attention's banded layers, scaled to the window of a
+    # block: head size 64, two query heads a key-value head
+    "window_a_block_group2_d64": _bwd_case(window=16, group=2),
 }
 
 
