@@ -15,6 +15,7 @@ everything statically.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable
 
 import jax
@@ -236,13 +237,70 @@ def _expert_blocks_bwd(res, dy):
 _expert_blocks.defvjp(_expert_blocks_fwd, _expert_blocks_bwd)
 
 
+def _pick(top_e, values, n_experts: int, over: int):
+    """The sum over axis ``over`` of ``values`` (broadcast to (tokens, k,
+    n_experts)) where expert ``top_e[t, j]`` is the column's own, 0.0
+    elsewhere: the compare is fused into the reduction, nothing of
+    tokens x k x n_experts is stored."""
+    hit = top_e[:, :, None] == lax.broadcasted_iota(
+        jnp.int32, (1, 1, n_experts), 2)
+    return jnp.where(hit, values, 0.0).sum(over)
+
+
+@jax.custom_vjp
+def _chosen(scores, top_e):
+    """``scores[t, top_e[t, j]]``, (tokens, k), with no gather, and no
+    scatter backward: a sum over the experts in which every term but one
+    is an exact 0.0, so ``jnp.take_along_axis``'s values bit for bit; its
+    transpose is the same compare summed over k (a token's experts are
+    distinct, so at most one term there too)."""
+    return _pick(top_e, scores[:, None, :], scores.shape[1], 2)
+
+
+def _chosen_fwd(scores, top_e):
+    return _chosen(scores, top_e), (top_e, scores.shape[1])
+
+
+def _chosen_bwd(res, d):
+    top_e, n_experts = res
+    return _pick(top_e, d[:, :, None], n_experts, 1), None
+
+
+_chosen.defvjp(_chosen_fwd, _chosen_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _top_k(scores, k: int):
+    """``lax.top_k`` whose values transpose as :func:`_chosen`'s do, by a
+    compare and a sum over k, where autodiff's rule scatters tokens x k
+    updates into (tokens, n_experts)."""
+    return tuple(lax.top_k(scores, k))
+
+
+def _top_k_fwd(scores, k):
+    values, experts = _top_k(scores, k)
+    return (values, experts), (experts, scores.shape[1])
+
+
+def _top_k_bwd(k, res, cotangents):
+    experts, n_experts = res
+    return (_pick(experts, cotangents[0][:, :, None], n_experts, 1),)
+
+
+_top_k.defvjp(_top_k_fwd, _top_k_bwd)
+
+
 def _route(x, router_w, top_k: int, scaling: float):
     """Each token's top-k experts of ALL the router scores, (tokens, k)
     int32, and their weights: the float32 softmax probabilities of the
-    chosen, renormalised to ``scaling``."""
+    chosen, renormalised to ``scaling``. ``top_k``'s values are the
+    weights as they come, so there is no gather, and no scatter backward
+    (:func:`_top_k`)."""
+    # On a widened bfloat16 x the v5e's compiler leaves out the passes
+    # over x's zero low parts by itself (PERF.md, PR 35).
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=lax.Precision.HIGHEST)
-    top_p, top_e = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    top_p, top_e = _top_k(jax.nn.softmax(logits, axis=-1), top_k)
     return top_e, scaling * top_p / top_p.sum(-1, keepdims=True)
 
 
@@ -251,14 +309,16 @@ def sigmoid_route(bias):
     sigmoid scores over ALL experts; a token's top-k are chosen by score
     plus ``bias`` (n_experts,), a correction that only moves the choice
     (no gradient reaches it); the weights are the scores of the chosen,
-    without the bias, renormalised to ``scaling``."""
+    without the bias, renormalised to ``scaling``. They are picked by
+    compares (:func:`_chosen`): no gather of tokens x k scores, no
+    scatter of as many backward."""
     def route(x, router_w, top_k: int, scaling: float):
         logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
         scores = jax.nn.sigmoid(logits)
         _, top_e = lax.top_k(
             scores + lax.stop_gradient(bias.astype(jnp.float32)), top_k)
-        top_s = jnp.take_along_axis(scores, top_e, axis=-1)
+        top_s = _chosen(scores, top_e)
         return top_e, scaling * top_s / top_s.sum(-1, keepdims=True)
 
     return route

@@ -4,6 +4,7 @@ benchmark's plain reference, nothing is dropped under a skewed router,
 and the counters equal a plain count."""
 
 import os
+import re
 import sys
 
 import jax
@@ -15,6 +16,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
 from benchmark.harness import spec  # noqa: E402
+from horovod_tpu.parallel import moe  # noqa: E402
 from horovod_tpu.parallel.moe import (  # noqa: E402
     expert_share_layer, sigmoid_route)
 
@@ -269,3 +271,161 @@ def test_no_scatter_or_gather_outside_the_block_loop_is_tokens_by_top_k():
     found = _index_counts(jaxpr.jaxpr, [])
     assert found, "the rows of a block are gathered somewhere"
     assert max(n for _, n in found) < t * held < t * top_k, found
+
+
+# ---------------------------------------------------------------------------
+# The routers: the chosen scores and their transpose by compares
+# ---------------------------------------------------------------------------
+
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _router_inputs(t, h, experts, dtype, seed=5):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return (jax.random.normal(ks[0], (t, h), dtype),
+            jax.random.normal(ks[1], (h, experts)) * h ** -0.5,
+            jax.random.normal(ks[2], (experts,)) * 0.1)
+
+
+def _primitives(jaxpr, found):
+    for eqn in jaxpr.eqns:
+        found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, found)
+    return found
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("router", ["softmax", "sigmoid"])
+def test_a_routers_logits_are_one_float32_product_at_highest(router, dtype):
+    """Whatever the input's dtype: it is widened, and the product is left
+    to the compiler, which skips the passes over a bfloat16 input's zero
+    low parts by itself on the chip (PERF.md, PR 35)."""
+    x, w, bias = _router_inputs(32, 16, 8, dtype)
+    route = moe._route if router == "softmax" else sigmoid_route(bias)
+    eqns = _primitives(
+        jax.make_jaxpr(lambda x, w: route(x, w, 3, SCALING))(x, w).jaxpr, [])
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == 1
+    assert dots[0].params["precision"] == (HIGHEST, HIGHEST)
+    assert [v.aval.dtype for v in dots[0].invars] == [jnp.float32] * 2
+    assert [v.aval.shape for v in dots[0].invars] == [(32, 16), (16, 8)]
+
+
+def _plain_route(x, router_w, bias, top_k, scaling):
+    """Both routers as they are written down: a plain product at
+    ``HIGHEST`` on the widened input, ``take_along_axis``."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w, precision=HIGHEST)
+    if bias is None:
+        scores = jax.nn.softmax(logits, axis=-1)
+        _, top_e = jax.lax.top_k(scores, top_k)
+    else:
+        scores = jax.nn.sigmoid(logits)
+        _, top_e = jax.lax.top_k(scores + jax.lax.stop_gradient(bias), top_k)
+    top_s = jnp.take_along_axis(scores, top_e, axis=-1)
+    return top_e, scaling * top_s / top_s.sum(-1, keepdims=True)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bfloat16", "float32"])
+@pytest.mark.parametrize("router", ["softmax", "sigmoid"])
+def test_a_router_equals_its_plain_formulation_and_so_do_its_gradients(
+        router, dtype):
+    t, h, experts, top_k = 96, 40, 32, 6
+    x, w, bias = _router_inputs(t, h, experts, dtype)
+    cot = jax.random.normal(jax.random.PRNGKey(8), (t, top_k))
+
+    def ours(x, w, bias):
+        route = moe._route if router == "softmax" else sigmoid_route(bias)
+        top_e, weight = route(x, w, top_k, SCALING)
+        return (weight * cot).sum(), (top_e, weight)
+
+    def plain(x, w, bias):
+        top_e, weight = _plain_route(
+            x, w, None if router == "softmax" else bias, top_k, SCALING)
+        return (weight * cot).sum(), (top_e, weight)
+
+    # under remat, as the models run it
+    (dx, dw, dbias), (top_e, weight) = jax.grad(
+        jax.checkpoint(ours), argnums=(0, 1, 2), has_aux=True)(x, w, bias)
+    (dx_p, dw_p, dbias_p), (top_e_p, weight_p) = jax.grad(
+        plain, argnums=(0, 1, 2), has_aux=True)(x, w, bias)
+    np.testing.assert_array_equal(top_e, top_e_p)
+    assert top_e.dtype == jnp.int32 and weight.dtype == jnp.float32
+    np.testing.assert_allclose(weight, weight_p, rtol=2e-6, atol=1e-7)
+    np.testing.assert_allclose(weight.sum(-1), SCALING, rtol=1e-6)
+    assert dx.dtype == dtype and dw.dtype == jnp.float32
+    assert float(jnp.abs(dw_p).max()) > 0 and float(
+        jnp.abs(dx_p.astype(jnp.float32)).max()) > 0
+    np.testing.assert_allclose(dw, dw_p, rtol=2e-5,
+                               atol=2e-6 * float(jnp.abs(dw_p).max()))
+    # dx leaves in x's dtype: in bfloat16 both sides round the same
+    # float32 number, to one step of 2^-8 where they fall on two sides
+    np.testing.assert_allclose(
+        dx.astype(jnp.float32), dx_p.astype(jnp.float32),
+        rtol=2e-5 if dtype == jnp.float32 else 2 ** -7,
+        atol=2e-6 * float(jnp.abs(dx_p.astype(jnp.float32)).max()))
+    assert not np.asarray(dbias).any() and not np.asarray(dbias_p).any()
+
+
+@pytest.mark.parametrize("t,experts,top_k", [(70, 48, 9), (33, 7, 7),
+                                             (64, 512, 22), (16, 16, 1)])
+def test_the_chosen_scores_are_take_along_axis_bit_for_bit(t, experts, top_k):
+    ks = jax.random.split(jax.random.PRNGKey(t), 3)
+    scores = jax.nn.sigmoid(jax.random.normal(ks[0], (t, experts)) * 3)
+    _, top_e = jax.lax.top_k(
+        scores + jax.random.normal(ks[1], (experts,)), top_k)
+    cot = jax.random.normal(ks[2], (t, top_k))
+    got, vjp = jax.vjp(lambda s: moe._chosen(s, top_e), scores)
+    want, vjp_plain = jax.vjp(
+        lambda s: jnp.take_along_axis(s, top_e, axis=-1), scores)
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(vjp(cot)[0], vjp_plain(cot)[0])
+
+
+@pytest.mark.parametrize("router", ["softmax", "sigmoid"])
+def test_a_routers_gradient_has_no_gather_no_scatter_and_stores_nothing_of_tokens_by_top_k_by_experts(router):  # noqa: E501
+    """In the style of the layout's test below ``_index_counts``: no
+    indexed op of the gradient's program has tokens x top_k indices (none
+    at all, here: autodiff's ``take_along_axis`` and ``top_k`` each
+    scatter as many backward), and in the compiled program everything of
+    tokens x top_k x experts elements lies inside a fusion with the
+    reduction and no fusion writes it: the compare is fused into the
+    sum."""
+    t, h, experts, top_k = 64, 24, 16, 6
+    x, w, bias = _router_inputs(t, h, experts, jnp.bfloat16)
+    cot = jax.random.normal(jax.random.PRNGKey(8), (t, top_k))
+
+    def loss(x, w, bias):
+        route = moe._route if router == "softmax" else sigmoid_route(bias)
+        _, weight = route(x, w, top_k, SCALING)
+        return (weight * cot).sum()
+
+    grad = jax.grad(loss, argnums=(0, 1, 2))
+    found = _index_counts(jax.make_jaxpr(grad)(x, w, bias).jaxpr, [])
+    assert not found, found
+
+    text = jax.jit(grad).lower(x, w, bias).compile().as_text()
+    computation, large, stored, reduces = None, set(), set(), set()
+    for line in text.splitlines():
+        if not line.startswith(" "):
+            computation = line.split(" ")[0].lstrip("%")
+            continue
+        _, found_result, rest = line.partition(" = ")
+        if not found_result:
+            continue
+        if " reduce(" in rest:
+            reduces.add(computation)
+        result = rest if rest.startswith("(") else rest.split("(")[0]
+        for dims in re.findall(r"\w+\[([\d,]+)\]", result[:200]):
+            if np.prod([int(d) for d in dims.split(",")]) >= (
+                    t * top_k * experts):
+                large.add(computation)
+                if line.lstrip().startswith("ROOT"):  # what a fusion writes
+                    stored.add(computation)
+    assert large, "the compare against the experts' numbers is somewhere"
+    assert not stored, stored
+    for computation in large:
+        assert computation.startswith("fused_computation"), computation
+        assert computation in reduces, computation
