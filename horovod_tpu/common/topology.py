@@ -233,7 +233,23 @@ def init(ranks: Optional[Sequence[int]] = None, devices: Optional[Sequence] = No
 
     Idempotent like the reference's ``InitializeHorovodOnce``
     (reference: horovod/common/operations.cc:2176-2194).
+
+    Runs inside the host span ``hvd.init`` of the compile log
+    (core/compile_log.py), whose listeners it installs, once a process:
+    what the process compiles from here on has a record, and what this
+    call compiles names it as its cause.
     """
+    if _state.initialized:
+        return
+    from horovod_tpu.core import compile_log
+
+    compile_log.install()
+    with compile_log.LOG.span("hvd.init"):
+        _init(ranks, devices)
+
+
+def _init(ranks, devices):
+    """:func:`init`, inside its span."""
     with _state.lock:
         if _state.initialized:
             return

@@ -14,8 +14,10 @@ when measurement is continuous, arxiv 1909.09756 §3):
   ``hvd.jax.jit`` wrapper's dispatch latency. A step exceeding the
   anomaly threshold fires ONCE (cooldown, no re-trigger storm): flight
   recorder dump, a bounded profiler capture of the next few steps, and
-  an attributed verdict — recompile (jax compile events fired during
-  the step) vs straggler rank (the telemetry straggler report gained
+  an attributed verdict — recompile (the compile log, core/compile_log.py,
+  counted a backend compile during the step; where a ``hvd.jax.jit``
+  call past its first compiled it, the verdict names the program and
+  the dispatch) vs straggler rank (the telemetry straggler report gained
   imposed wait) vs engine stall (both engines' stall paths call
   :func:`note_stall`) vs HBM-traffic jump (the post-anomaly capture's
   measured bytes/step vs the previous capture).
@@ -58,6 +60,7 @@ import time
 from collections import deque
 from typing import Dict, List, Optional
 
+from horovod_tpu.core import compile_log
 from horovod_tpu.core import telemetry as tele
 from horovod_tpu.core import timeline as tl
 
@@ -76,45 +79,6 @@ def _env_int(name: str, default: int) -> int:
         return int(os.environ.get(name, "") or default)
     except ValueError:
         return default
-
-
-# ---------------------------------------------------------------------------
-# Recompile detection: jax monitoring events
-# ---------------------------------------------------------------------------
-
-_compile_lock = threading.Lock()
-_compile_count = 0
-_compile_listener_installed = False
-
-
-def _on_compile_event(name: str, *args, **kwargs):
-    global _compile_count
-    if "backend_compile" in name:
-        with _compile_lock:
-            _compile_count += 1
-        tele.REGISTRY.counter("jax.compiles").inc()
-
-
-def install_compile_listener():
-    """Count XLA compiles through jax's monitoring events (best-effort:
-    the listener API is semi-public — a jax without it just means the
-    'recompile' verdict is never produced). Idempotent."""
-    global _compile_listener_installed
-    with _compile_lock:
-        if _compile_listener_installed:
-            return
-        _compile_listener_installed = True
-    try:
-        import jax.monitoring as _mon
-
-        _mon.register_event_duration_secs_listener(_on_compile_event)
-    except Exception:  # pragma: no cover - jax drift
-        pass
-
-
-def compile_count() -> int:
-    with _compile_lock:
-        return _compile_count
 
 
 # ---------------------------------------------------------------------------
@@ -161,7 +125,8 @@ class StepWatchdog:
         self._since_p99 = 0
         # Attribution context captured at the END of the previous step:
         # a delta over the anomalous step is evidence about THAT step.
-        self._prev_compiles = compile_count()
+        self._prev_compiles = compile_log.LOG.compiled
+        self._prev_recompiles = compile_log.LOG.recompiles
         self._prev_strag_us = 0
 
     _P99_REFRESH = 16
@@ -233,13 +198,19 @@ class StepWatchdog:
                     self._p99_cache = self._p99_locked()
         # Attribution deltas over THIS step (read outside the lock; the
         # counters are process-global and monotonic).
-        comp = compile_count()
+        comp = compile_log.LOG.compiled
+        recomp = compile_log.LOG.recompiles
         strag = self._strag_total_us()
         if fired is not None:
             fired["p99_s"] = self.p99()
             fired["compiles"] = comp - self._prev_compiles
+            if recomp > self._prev_recompiles:
+                # Which hvd.jax.jit function, at which of its dispatches,
+                # how long the backend took and whether the cache hit.
+                fired["recompile"] = compile_log.LOG.last_recompile()
             fired["straggler_wait_us"] = strag - self._prev_strag_us
         self._prev_compiles = comp
+        self._prev_recompiles = recomp
         self._prev_strag_us = strag
         return fired
 
@@ -528,8 +499,6 @@ class Sentinel:
         # same excursion for a short wall window.
         self._capture_origin: Optional[str] = None
         self._last_fire_wall: Optional[float] = None
-        if self.enabled:
-            install_compile_listener()
 
     #: Wall seconds after a firing during which OTHER origins' anomalies
     #: are suppressed (the same slow step seen through two lenses).

@@ -19,7 +19,10 @@ one-chip benchmark into a scalable system, arxiv 1909.09756):
   the negotiation round tables (the RANK_READY data) into the straggler
   report;
 - :func:`horovod_tpu.jax.jit` and the keras Trainer record dispatch /
-  step-time ring buffers for the compiled path.
+  step-time ring buffers for the compiled path;
+- :mod:`horovod_tpu.core.compile_log` records every program jax
+  compiles (by function, stage, cache result and the span that caused
+  it), ``hvd.init`` and the parameter broadcast.
 
 Four surfaces:
 
@@ -572,7 +575,16 @@ def telemetry() -> dict:
     world = _world_lines(as_dict=True)
     if world:
         out["world"] = world
+    out["compile_log"] = _compile_log().snapshot()
     return out
+
+
+def _compile_log():
+    """The process's compile log (core/compile_log.py feeds this
+    registry, so it is imported from here only when read)."""
+    from horovod_tpu.core import compile_log
+
+    return compile_log.LOG
 
 
 def _world_lines(as_dict: bool = False):
@@ -604,7 +616,8 @@ def _world_lines(as_dict: bool = False):
 def report() -> str:
     """Human-readable table — the ``hvd.telemetry_report()`` surface."""
     out = REGISTRY.report()
-    lines = _world_lines() + STRAGGLERS.report_lines()
+    lines = (_world_lines() + STRAGGLERS.report_lines()
+             + _compile_log().report_lines())
     return out + ("\n" + "\n".join(lines) if lines else "")
 
 

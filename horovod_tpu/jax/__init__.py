@@ -68,6 +68,7 @@ from horovod_tpu.jax.sharded import (  # noqa: F401
 )
 from horovod_tpu.jax import mpi_ops  # noqa: F401  — engine-path async
 # verbs (allreduce_async/synchronize/... with zero-copy donate=True)
+from horovod_tpu.core import compile_log as _clog
 from horovod_tpu.core import numerics as _num
 from horovod_tpu.core import sentinel as _sentinel
 from horovod_tpu.core import telemetry as _tele
@@ -227,7 +228,11 @@ def broadcast_parameters(params, root_rank: int = 0):
     """Broadcast a parameter pytree from ``root_rank`` (reference:
     horovod/tensorflow/__init__.py:96-115 broadcast_global_variables,
     horovod/torch/__init__.py:185-214)."""
-    return broadcast_pytree(params, root_rank=root_rank)
+    # The span is the host's seconds: packing, the collective's compile on
+    # a first call and its dispatch. The device finishes behind it. The
+    # bytes are ``eager.broadcast.bytes``.
+    with _clog.LOG.span("hvd.broadcast_parameters"):
+        return broadcast_pytree(params, root_rank=root_rank)
 
 
 # TF-compat alias: in JAX variables are explicit, so this takes the pytree.
@@ -552,23 +557,48 @@ DistributedGradientTape = value_and_grad
 class _InstrumentedJit:
     """Thin wrapper around the jitted step: each ``__call__`` records the
     dispatch latency (time to hand the program to the runtime — execution
-    itself is async) into the telemetry ring buffer for the compiled path.
-    Everything else (``lower``, ``trace``, AOT compilation, ...) delegates
-    to the wrapped ``jax.jit`` object, so the perf-critical AOT path
-    (``fn.lower(...).compile()`` — ``benchmark/run.py``) bypasses
-    instrumentation entirely. Overhead: two clock reads + a few deque appends/compares
-    per dispatch (ring + the sentinel watchdog), ~1-2 µs against a
-    ≥50 µs dispatch."""
+    itself is async) into the telemetry ring buffer for the compiled path,
+    and ``lower`` and ``__call__`` run inside the compile log's host span
+    ``hvd.jax.jit:<fn>`` (core/compile_log.py), so that what they compile
+    names them as its cause. ``lower`` returns jax's own ``Lowered``:
+    everything past it (``compile()``, the compiled call) and everything
+    else (``trace``, ``eval_shape``, ...) is the wrapped ``jax.jit``
+    object's, so the perf-critical AOT call path
+    (``fn.lower(...).compile()(...)`` — ``benchmark/run.py``) bypasses
+    instrumentation entirely. Overhead of ``__call__``: two clock reads,
+    a push and a pop of the span's name, one integer compare and a few
+    deque appends/compares per dispatch (ring + the sentinel watchdog):
+    13-15 µs a call on this repository's sandbox host, with the span as
+    without it (PERF.md, PR 36), against a ≥50 µs dispatch."""
 
-    __slots__ = ("_jitted",)
+    __slots__ = ("_jitted", "_name", "_span", "_calls")
 
-    def __init__(self, jitted):
+    def __init__(self, jitted, name: str):
         self._jitted = jitted
+        self._name = name
+        self._span = _clog.JIT_SPAN + name
+        self._calls = 0
+
+    def lower(self, *args, **kwargs):
+        with _clog.LOG.span(self._span):
+            return self._jitted.lower(*args, **kwargs)
 
     def __call__(self, *args, **kwargs):
+        log = _clog.LOG
+        spans, compiled = log.open_spans(), log.compiled
+        spans.append(self._span)
         t0 = _time.perf_counter()
-        out = self._jitted(*args, **kwargs)
+        try:
+            out = self._jitted(*args, **kwargs)
+        finally:
+            spans.pop()
         dt = _time.perf_counter() - t0
+        if log.compiled != compiled:
+            # Something compiled meanwhile: past the first call that is a
+            # recompile, by function and dispatch (before the watchdog
+            # looks, whose 'recompile' verdict reads it).
+            log.compiled_in_call(self._name, dt, compiled, self._calls)
+        self._calls += 1
         _tele.REGISTRY.counter("jax.dispatches").inc()
         _tele.REGISTRY.ring("jax.dispatch_s").push(dt)
         # Performance sentinel: the per-call dispatch boundary is the
@@ -635,6 +665,7 @@ def jit(fn: Callable = None, *, in_specs, out_specs, static_argnums=(),
             )
         return _InstrumentedJit(
             _jax.jit(sm, static_argnums=static_argnums,
-                     donate_argnums=donate_argnums))
+                     donate_argnums=donate_argnums),
+            getattr(f, "__name__", "step"))
 
     return wrap if fn is None else wrap(fn)

@@ -76,18 +76,70 @@ def test_watchdog_warmup_fire_once_and_cooldown(fresh_sentinel, tmp_path,
 
 
 def test_watchdog_recompile_attribution(fresh_sentinel, tmp_path,
-                                        monkeypatch):
+                                        monkeypatch, hvd):
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    import horovod_tpu.jax as hvd_jax
+    from horovod_tpu.core import compile_log
+
     monkeypatch.setenv("HVD_FLIGHT_DIR", str(tmp_path))
     s = fresh_sentinel(HVD_WATCHDOG=1, HVD_WATCHDOG_MIN_STEPS=4,
                        HVD_PROFILE_DIR=None)
+
+    @hvd_jax.jit(in_specs=(P("hvd"),), out_specs=P("hvd"))
+    def watched_step(x):
+        return x * 3
+
+    watched_step(jnp.ones((hvd.size(), 2)))  # the first compile: no news
     for _ in range(10):
         s.observe_step(0.010, origin="d")
-    # A compile event lands DURING the anomalous step.
-    with sen._compile_lock:
-        sen._compile_count += 1
+    # A new shape recompiles the function DURING the anomalous step: the
+    # compile log notes it, and the watchdog asks the log.
+    before = compile_log.LOG.compiled
+    watched_step(jnp.ones((2 * hvd.size(), 2)))
+    compiled = compile_log.LOG.compiled - before
     v = s.observe_step(0.300, origin="d")
     assert v is not None and v["verdict"] == "recompile"
-    assert v["compiles"] == 1
+    assert v["compiles"] == compiled >= 1
+    assert v["recompile"]["name"] == "watched_step"
+    assert v["recompile"]["dispatch"] == 1
+    assert v["recompile"]["backend_s"] > 0
+    assert v["recompile"]["cache"] in ("off", "miss", "hit")
+    assert v["recompile"] == hvd.telemetry()["compile_log"]["recompiles"][-1]
+    # A slow step during which nothing compiled carries neither.
+    quiet = sen.StepWatchdog("quiet", min_steps=4)
+    for _ in range(10):
+        quiet.observe(0.010)
+    fired = quiet.observe(0.300)
+    assert fired is not None
+    assert fired["compiles"] == 0 and "recompile" not in fired
+
+
+def test_watchdog_counts_backend_compiles_not_lowerings(hvd):
+    """A program lowered during a slow step and not compiled (a bare
+    ``lower()``) is no recompile: the verdict's count is the backend's."""
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    import horovod_tpu.jax as hvd_jax
+    from horovod_tpu.core import compile_log
+
+    @hvd_jax.jit(in_specs=(P("hvd"),), out_specs=P("hvd"))
+    def only_lowered(x):
+        return x - 2
+
+    dog = sen.StepWatchdog("lowering", min_steps=4)
+    for _ in range(10):
+        dog.observe(0.010)
+    x = jnp.ones((hvd.size(), 2))
+    programs, compiled = compile_log.LOG.programs, compile_log.LOG.compiled
+    lowered = only_lowered.lower(x)
+    assert compile_log.LOG.programs == programs + 1
+    fired = dog.observe(0.300)
+    assert fired is not None and fired["compiles"] == 0
+    lowered.compile()
+    assert compile_log.LOG.compiled == compiled + 1
 
 
 def test_watchdog_straggler_attribution(fresh_sentinel, tmp_path,
