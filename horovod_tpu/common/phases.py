@@ -56,13 +56,17 @@ KERNELS = ("flash_fwd_bhsd", "flash_dq_bwd_bhsd", "flash_dkv_bwd_bhsd",
 #: and the norm over a pair; ``gmu``: the whole gated memory unit, its two
 #: projections and the gate ``silu(.) * memory`` between them, which the
 #: compiler fuses into them; its Mamba mixer's convolution goes under
-#: ``ssm_conv``). Kept apart from ``PHASES``,
+#: ``ssm_conv``), and the decoder's gated short convolution and query-key
+#: norm (``short_conv``: what lies between the operator's two projections,
+#: the product of ``B`` and ``x``, the causal sum of the taps and the
+#: product with ``C``; ``attn_qk_norm``: the per-head RMSNorm of the
+#: queries and the keys). Kept apart from ``PHASES``,
 #: which is the framework's own vocabulary and which readers hold a copy
 #: of.
 MODEL_SCOPES = ("moe_route", "moe_dispatch", "moe_experts", "moe_combine",
                 "moe_shared", "attn_rope", "attn_gate", "ssm_conv",
                 "ssm_scan", "ssm_norm", "moe_latent", "sel_scan",
-                "attn_diff", "gmu")
+                "attn_diff", "gmu", "short_conv", "attn_qk_norm")
 
 #: Stamped on every op of a ``hvd.jax.jit`` step as the frontend
 #: attribute ``hvd_phases``. jax's persistent compile cache keys on the
@@ -70,7 +74,7 @@ MODEL_SCOPES = ("moe_route", "moe_dispatch", "moe_experts", "moe_combine",
 #: cached one only in names would be served the cached executable, old
 #: names and all; the attribute is in the key. Bump it with ``PHASES``,
 #: ``KERNELS``, ``MODEL_SCOPES`` or a move of where a name is emitted.
-VOCABULARY_VERSION = "6"
+VOCABULARY_VERSION = "7"
 
 
 def phase(name: str):

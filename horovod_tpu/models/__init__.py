@@ -14,7 +14,9 @@ tensor-fusion stress config of BASELINE.json) with pluggable attention so the
 long-context paths in :mod:`horovod_tpu.parallel` can drop in; and a causal
 decoder built from per-layer specs (:mod:`horovod_tpu.models.decoder`: full
 and window attention over grouped key-value heads, head counts by layer,
-rotary positions, gated heads, dense and sparse-expert SwiGLU MLPs); and a
+rotary positions, gated heads, normed queries and keys, gated short
+convolutions, dense and sparse-expert SwiGLU MLPs under a softmax or a
+sigmoid router, an untied or a tied head); and a
 hybrid whose blocks are one mixer each (:mod:`horovod_tpu.models.hybrid`:
 state-space mixers with a chunked scan, attention, latent sparse experts).
 
@@ -44,6 +46,8 @@ from horovod_tpu.models.decoder import (  # noqa: F401
     DecoderConfig,
     LayerSpec,
     RopeSpec,
+    ShortConv,
+    TiedHead,
 )
 from horovod_tpu.models.hybrid import HybridConfig, HybridLM  # noqa: F401
 from horovod_tpu.models.sambay import SambaYConfig, SambaYLM  # noqa: F401

@@ -48,7 +48,8 @@ import jax.numpy as jnp
 
 from horovod_tpu.common.phases import scope
 from horovod_tpu.models.decoder import (DecoderConfig, GroupedAttention,
-                                        LayerSpec, _dense, stack_counters)
+                                        LayerSpec, _dense, causal_conv,
+                                        stack_counters)
 from horovod_tpu.ops.ssd import ssd_scan
 from horovod_tpu.parallel.moe import expert_share_layer, sigmoid_route
 
@@ -112,16 +113,11 @@ def _a_log_init(key, shape, dtype=jnp.float32):
 
 
 def causal_conv_silu(x, taps, bias, dtype):
-    """A depthwise causal convolution over (batch, t, channels) and its
-    SiLU, under ``ssm_conv``: tap k of ``taps`` (kernel, channels) reads
-    position t - (kernel - 1 - k). Float32 inside, ``dtype`` out."""
+    """A depthwise causal convolution over (batch, t, channels)
+    (:func:`horovod_tpu.models.decoder.causal_conv`), plus ``bias``, and
+    its SiLU, under ``ssm_conv``. Float32 inside, ``dtype`` out."""
     with scope("ssm_conv"):
-        kernel, t = taps.shape[0], x.shape[1]
-        padded = jnp.pad(x.astype(jnp.float32),
-                         ((0, 0), (kernel - 1, 0), (0, 0)))
-        conv = bias + sum(taps[k] * padded[:, k:k + t]
-                          for k in range(kernel))
-        return nn.silu(conv).astype(dtype)
+        return nn.silu(bias + causal_conv(x, taps)).astype(dtype)
 
 
 class SSMMixer(nn.Module):

@@ -66,7 +66,8 @@ import jax
 import jax.numpy as jnp
 
 from horovod_tpu.common.phases import scope
-from horovod_tpu.models.decoder import _dense, _swiglu, causal_attention
+from horovod_tpu.models.decoder import (TiedHead, _dense, _swiglu,
+                                        causal_attention)
 from horovod_tpu.models.hybrid import _dt_bias_init, causal_conv_silu
 from horovod_tpu.ops.selective_scan import selective_scan
 
@@ -276,16 +277,6 @@ class SambaYLayer(nn.Module):
         x = x + out
         x = x + _swiglu(norm("mlp_norm")(x), cfg.mlp_dim, cfg, "mlp_")
         return x, memory, shared_kv
-
-
-class TiedHead(nn.Module):
-    """Float32 logits against the embedding's rows: the tied head's
-    product under the module name ``lm_head``, no parameter of its own."""
-
-    @nn.compact
-    def __call__(self, x, embedding):
-        return jnp.einsum("bsh,vh->bsv", x.astype(jnp.float32),
-                          embedding.astype(jnp.float32))
 
 
 class SambaYLM(nn.Module):

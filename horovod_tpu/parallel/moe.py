@@ -304,14 +304,14 @@ def _route(x, router_w, top_k: int, scaling: float):
     return top_e, scaling * top_p / top_p.sum(-1, keepdims=True)
 
 
-def sigmoid_route(bias):
+def sigmoid_route(bias, eps: float = 0.0):
     """A router for :func:`expert_share_layer`'s ``route``: float32
     sigmoid scores over ALL experts; a token's top-k are chosen by score
     plus ``bias`` (n_experts,), a correction that only moves the choice
     (no gradient reaches it); the weights are the scores of the chosen,
-    without the bias, renormalised to ``scaling``. They are picked by
-    compares (:func:`_chosen`): no gather of tokens x k scores, no
-    scatter of as many backward."""
+    without the bias, times ``scaling`` over their sum plus ``eps``. They
+    are picked by compares (:func:`_chosen`): no gather of tokens x k
+    scores, no scatter of as many backward."""
     def route(x, router_w, top_k: int, scaling: float):
         logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                          precision=lax.Precision.HIGHEST)
@@ -319,7 +319,13 @@ def sigmoid_route(bias):
         _, top_e = lax.top_k(
             scores + lax.stop_gradient(bias.astype(jnp.float32)), top_k)
         top_s = _chosen(scores, top_e)
-        return top_e, scaling * top_s / top_s.sum(-1, keepdims=True)
+        # the product, the sum, then the quotient: with eps 0 the ops of
+        # the one-line form, in its order, and no add of 0.0
+        weights = scaling * top_s
+        total = top_s.sum(-1, keepdims=True)
+        if eps:
+            total = total + eps
+        return top_e, weights / total
 
     return route
 

@@ -515,7 +515,7 @@ def test_chaos_sigkill_shrink_and_rejoin(engine, tmp_path):
               f"its first attempt — retrying once in-process; a second "
               f"failure is a real regression. First failure: "
               f"{str(exc)[:500]}", file=sys.stderr, flush=True)
-        _reap_stray_world_children()
+        _reap_stray_world_children(str(tmp_path / "try1"))
         _sigkill_shrink_scenario(engine, str(tmp_path / "try2"))
 
 
@@ -638,7 +638,7 @@ def test_chaos_rank0_sigkill_kv_failover(engine, tmp_path):
               f"first attempt — retrying once in-process; a second "
               f"failure is a real regression. First failure: "
               f"{str(exc)[:500]}", file=sys.stderr, flush=True)
-        _reap_stray_world_children()
+        _reap_stray_world_children(str(tmp_path / "try1"))
         _rank0_failover_scenario(engine, str(tmp_path / "try2"))
 
 
@@ -740,19 +740,32 @@ def test_chaos_frozen_heartbeat(engine, tmp_path):
         # workers keep training and would starve the retry's 5 s leases
         # on this one-core host (the stale-world hazard conftest guards
         # against). Reap them before going again.
-        _reap_stray_world_children()
+        _reap_stray_world_children(str(tmp_path / "try1"))
         _frozen_heartbeat_scenario(engine, str(tmp_path / "try2"))
 
 
-def _reap_stray_world_children():
-    """SIGKILL leftover rank/launcher processes from a failed chaos
-    attempt (cmdline-marked, never an ancestor of this process), then
-    give the scheduler a beat. Best-effort: /proc races are fine."""
+def _reap_stray_world_children(base_dir):
+    """SIGKILL leftover rank/launcher processes of the failed chaos
+    attempt rooted at ``base_dir``, then give the scheduler a beat.
+
+    Only processes of THAT attempt go: the launcher names the elastic
+    dir (under ``base_dir``) on its command line and hands it to every
+    rank as HVD_ELASTIC_DIR. Under pytest-xdist other files' worlds
+    (tests/test_multiprocess.py, tests/test_preempt.py) run beside this
+    one and carry the same world markers; killing them would fail those
+    tests with rc=-9. Best-effort: /proc races are fine."""
     import conftest
 
+    tag = os.path.join(os.path.abspath(base_dir), "").encode()
     for pid, _cmd in conftest._stale_world_processes():
         try:
-            os.kill(pid, signal.SIGKILL)
+            with open(f"/proc/{pid}/cmdline", "rb") as fh:
+                ours = tag in fh.read()
+            if not ours:
+                with open(f"/proc/{pid}/environ", "rb") as fh:
+                    ours = tag in fh.read()
+            if ours:
+                os.kill(pid, signal.SIGKILL)
         except OSError:
             pass
     time.sleep(1.0)
